@@ -178,7 +178,7 @@ class _TaintWalk:
                     sub = _sub_jaxpr(br)
                     if sub is not None:
                         self.run(sub[0], [read(v) for v in eqn.invars[1:]])
-            elif prim in ("scan", "while", "pjit", "closed_call",
+            elif prim in ("scan", "while", "jit", "pjit", "closed_call",
                           "custom_vjp_call_jaxpr", "custom_jvp_call",
                           "custom_vjp_call", "remat", "remat2"):
                 for key in ("jaxpr", "body_jaxpr", "cond_jaxpr",

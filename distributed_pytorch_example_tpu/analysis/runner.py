@@ -11,9 +11,7 @@ Glues the three analysis layers to the dryrun mesh-config table
   dropped donations, and large replicated params.
 
 Configs the toolchain cannot compile produce ``{"error": ...}`` records:
-the committed budget file documents the gap (e.g. jax 0.4.x cannot
-compile partial-auto ``shard_map`` pipelines — ``axis_index`` lowers to
-a PartitionId op its SPMD partitioner rejects), and an error matching the
+the committed budget file documents the gap, and an error matching the
 committed error is a note, not a violation. Budget comparisons degrade to
 warnings entirely when the runtime jax differs from the budget file's
 ``_meta.jax`` (collective counts are only stable within one toolchain).

@@ -343,7 +343,7 @@ class _Flow:
             return [canon_spec(None, len(getattr(v.aval, "shape", ())))
                     for v in eqn.outvars], 0
 
-        if prim == "pjit":
+        if prim in ("jit", "pjit"):  # the primitive is "jit" on jax 0.9
             sub = _sub_jaxpr(eqn.params.get("jaxpr"))
             if sub is None:
                 return self._fallback(eqn, in_specs, manual_axes)
@@ -658,7 +658,7 @@ def flow_for_case(case) -> FlowReport:
 
     Requires the case to be initialized (``collectives.compile_case`` or
     ``trainer.init``); traces only — works even where XLA cannot compile
-    the config (the pipe schedules' PartitionId limit on pre-0.9 jax).
+    the config.
     """
     import jax
 

@@ -45,8 +45,11 @@ DEFAULT_UPCAST_ALLOWLIST: Tuple[str, ...] = (
     # cast to bf16 compute, so AD emits a bf16->f32 convert per kernel
     # GRADIENT (master-weight accumulation), and LayerNorm statistics
     # upcast inside the module __call__ — both attributed by jax's source
-    # summary to the CALLER line in models/, not the flax frame
-    r"models/\S+\.py:\d+ \(__call__\)",
+    # summary to the CALLER line in models/ ("file.py:line:col
+    # (Class.__call__)") or, for the kernel casts, to flax's own
+    # promote_dtype frame
+    r"models/\S+\.py:\d+(:\d+)? \((\w+\.)?__call__\)",
+    r"flax/linen/dtypes\.py:\d+(:\d+)? \(promote_dtype\)",
 )
 
 # arrays smaller than this are metric/statistic sums, not activations —

@@ -187,10 +187,9 @@ def fused_layout_eligible(
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialize raises here: it must not read as
+    # "not a TPU" and quietly send every attention call to XLA
+    return jax.devices()[0].platform == "tpu"
 
 
 def _flash_unsupported_reason(q, k, v, mask, causal) -> Optional[str]:

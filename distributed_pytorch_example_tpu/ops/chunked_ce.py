@@ -204,10 +204,8 @@ def _local_token_count(hidden, n: int) -> int:
     except Exception:
         sharding = None
     if sharding is None:
-        from distributed_pytorch_example_tpu.runtime.jax_compat import typeof
-
         try:
-            sharding = getattr(typeof(hidden), "sharding", None)
+            sharding = getattr(jax.typeof(hidden), "sharding", None)
         except Exception:
             sharding = None
     if sharding is not None and hasattr(sharding, "shard_shape"):

@@ -19,12 +19,12 @@ surrounding compute:
   runs behind the reduce-add of the current one. This is the compute
   overlap the wire layer buys inside the grad-accum scan.
 
-``lax.axis_index`` is safe HERE (unlike train/step.py's data-manual
-body): these kernels only lower on the TPU backend, where PartitionId
-exists; ``ring_supported()`` gates every caller, and the 8-device fake
-CPU mesh the tests run on always takes the XLA-collective fallback with
-identical numerics (tests/test_wire.py compares the two wherever the
-kernel lowers).
+These kernels only lower on the TPU backend: ``ring_supported()`` gates
+every caller, and the 8-device virtual CPU mesh the tests run on always
+takes the XLA collective with identical numerics. The TPU compiler's
+acceptance of both kernels is pinned without a chip by
+tests/test_chip_compile.py (AOT for a described v5e:2x2); their numerics
+on four real chips by ``chip_smoke.py --chips 4``.
 
 Scope notes:
 
@@ -33,12 +33,16 @@ Scope notes:
   quantized REDUCE cannot: int8 partial sums overflow and every hop
   would need a requantize, so the compressed reduce-scatter stays on the
   XLA all-to-all decomposition (see parallel/wire.py).
-- Neighbor addressing uses mesh coordinates along ``axis_name``
-  (``DeviceIdType.MESH``), i.e. the kernels assume they are shard_mapped
-  over a single mesh axis — the wire layer's gather call sites. Any
-  shape/backend the kernels do not cover falls back to the XLA
-  collective; ``WireConfig(ring="off")`` is the unconditional escape
-  hatch.
+- Neighbor addressing is by mesh coordinate along ``axis_name`` only
+  (``DeviceIdType.MESH`` with a ``{axis_name: index}`` dict: every other
+  mesh axis keeps this device's own coordinate), so the ring runs inside
+  whatever multi-axis mesh the partitioner built. A payload shape the
+  kernels do not cover takes the XLA collective;
+  ``WireConfig(ring="off")`` is the unconditional escape hatch.
+- The reduce-scatter walks its payload in row tiles (``_RS_TILE_ROWS``)
+  on a sequential grid, one full ring pass per tile, so VMEM use is
+  bounded by the tile and not by the bucket (a bucket holding GPT-2's
+  embedding is 154 MB).
 """
 
 from __future__ import annotations
@@ -49,31 +53,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas TPU lowering is present in the pinned jax; guard anyway
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover - import guard for stripped builds
-    _PALLAS_OK = False
+from ...runtime.mesh import free_mesh_axes
 
 _LANES = 128  # VREG lane width: work buffers are shaped (rows, 128)
+# reduce-scatter row tile: (D, 2, 512, 128) f32 in + two (2, 2, 512, 128)
+# work buffers is ~7 MiB at D=4 with the pipeline's double buffering —
+# inside the 16 MiB scoped-VMEM default of a v5e
+_RS_TILE_ROWS = 512
 
 
 def ring_supported() -> bool:
     """True when the async ring kernels can lower on this backend."""
-    if not _PALLAS_OK:
-        return False
-    try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - uninitialized backend
-        return False
-    return backend == "tpu" and len(jax.devices()) > 1
-
-
-def _axis_size(axis_name: str) -> int:
-    # concrete: psum of a python scalar folds to the static axis size
-    return int(lax.psum(1, axis_name))
+    return jax.default_backend() == "tpu" and len(jax.devices()) > 1
 
 
 def _half_rows(n: int):
@@ -82,6 +77,41 @@ def _half_rows(n: int):
     if n and n % (2 * _LANES) == 0:
         return n // 2 // _LANES
     return None
+
+
+def _fully_manual(kernel):
+    """``kernel`` under a shard_map over the mesh axes that are not manual
+    yet (operands replicated over them): the wire layer calls these
+    kernels from regions manual over the gradient-sync axis ONLY, and the
+    TPU lowering refuses a Mosaic kernel anywhere short of fully manual."""
+    mesh, free = free_mesh_axes()
+    if not free:
+        return kernel
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+        axis_names=set(free), check_vma=False,
+    )
+
+
+def _neighbors(axis_name: str, num_devices: int):
+    """(my index, right id, left id) along ``axis_name``; ids are MESH
+    dicts, so the other mesh axes keep this device's coordinates."""
+    me = lax.axis_index(axis_name)
+    right = lax.rem(me + 1, num_devices)
+    left = lax.rem(me - 1 + num_devices, num_devices)
+    return me, {axis_name: right}, {axis_name: left}
+
+
+def _neighbor_barrier(right, left):
+    """Local barrier with both neighbors: nobody DMAs into a peer that
+    has not entered the kernel yet (pallas guide, RDMA section)."""
+    barrier = pltpu.get_barrier_semaphore()
+    for peer in (right, left):
+        pltpu.semaphore_signal(
+            barrier, device_id=peer,
+            device_id_type=pltpu.DeviceIdType.MESH,
+        )
+    pltpu.semaphore_wait(barrier, 2)
 
 
 # -- all-gather -------------------------------------------------------------
@@ -100,22 +130,8 @@ def _ag_kernel(x_ref, out_ref, send_sems, recv_sems, *, axis_name,
     buffer); the two directions' DMAs are both in flight before either
     is waited on, keeping both ICI directions busy.
     """
-    me = lax.axis_index(axis_name)
-    right = lax.rem(me + 1, num_devices)
-    left = lax.rem(me - 1 + num_devices, num_devices)
-
-    # local barrier with both neighbors: nobody DMAs into a peer that
-    # has not entered the kernel yet (pallas guide, RDMA section)
-    barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(
-        barrier, device_id=(right,),
-        device_id_type=pltpu.DeviceIdType.MESH,
-    )
-    pltpu.semaphore_signal(
-        barrier, device_id=(left,),
-        device_id_type=pltpu.DeviceIdType.MESH,
-    )
-    pltpu.semaphore_wait(barrier, 2)
+    me, right, left = _neighbors(axis_name, num_devices)
+    _neighbor_barrier(right, left)
 
     # seed my own slot with my shard
     seed = pltpu.make_async_copy(x_ref, out_ref.at[me], recv_sems.at[0, 0])
@@ -131,7 +147,7 @@ def _ag_kernel(x_ref, out_ref, send_sems, recv_sems, *, axis_name,
             dst_ref=out_ref.at[c_cw, 0],
             send_sem=send_sems.at[0, slot],
             recv_sem=recv_sems.at[0, slot],
-            device_id=(right,),
+            device_id=right,
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         ccw = pltpu.make_async_remote_copy(
@@ -139,7 +155,7 @@ def _ag_kernel(x_ref, out_ref, send_sems, recv_sems, *, axis_name,
             dst_ref=out_ref.at[c_ccw, 1],
             send_sem=send_sems.at[1, slot],
             recv_sem=recv_sems.at[1, slot],
-            device_id=(left,),
+            device_id=left,
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         cw.start()
@@ -171,15 +187,26 @@ def ring_all_gather(x, axis_name: str, *, stream: int = 0):
 
 
 def _ring_all_gather(x, axis_name: str, stream: int = 0):
-    d = _axis_size(axis_name)
+    d = lax.axis_size(axis_name)
     rows = _half_rows(x.size)
     if d == 1 or rows is None or not ring_supported():
         return lax.all_gather(x, axis_name, axis=0, tiled=True)
+    return _fully_manual(
+        lambda x: all_gather_kernel(x, axis_name, d, rows, stream)
+    )(x)
+
+
+def all_gather_kernel(x, axis_name: str, d: int, rows: int, stream: int = 0,
+                      interpret=False):
+    """The ring all-gather ``pallas_call`` itself (no backend dispatch —
+    tests/test_chip_compile.py compiles this for a described TPU, and
+    tests/test_wire.py runs it on the CPU mesh under the Pallas TPU
+    interpreter, ``interpret=pltpu.InterpretParams()``)."""
     halves = x.reshape(2, rows, _LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.SemaphoreType.DMA((2, 2)),  # send: [direction, slot]
             pltpu.SemaphoreType.DMA((2, 2)),  # recv
@@ -191,9 +218,10 @@ def _ring_all_gather(x, axis_name: str, stream: int = 0):
         ),
         out_shape=jax.ShapeDtypeStruct((d,) + halves.shape, x.dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.TPUCompilerParams(
-            collective_id=2 * int(stream)
+        compiler_params=pltpu.CompilerParams(
+            collective_id=2 * int(stream), has_side_effects=True,
         ),
+        interpret=interpret,
     )(halves)
     return stacked.reshape((d * x.shape[0],) + x.shape[1:])
 
@@ -203,47 +231,44 @@ def _ring_all_gather(x, axis_name: str, stream: int = 0):
 
 def _rs_kernel(parts_ref, out_ref, acc_ref, recv_ref, send_sems,
                recv_sems, *, axis_name, num_devices):
-    """Bidirectional ring reduce-scatter body.
+    """Bidirectional ring reduce-scatter body, one row tile per grid step.
 
-    ``parts_ref``: (D, 2, rows, 128) f32, destination-major — chunk d is
+    ``parts_ref``: (D, 2, tile, 128) f32, destination-major — chunk d is
     bound for device d, split into two direction-halves. Classic ring
     RS run twice at half payload: clockwise the partial for chunk
     (me - 1 - h) departs at hop h and each receiver folds in its own
     contribution, so after D-1 hops device me holds the full sum of its
     own chunk's low half; counter-clockwise mirrors for the high half.
-    ``acc_ref``/``recv_ref`` are (2, 2, rows, 128) VMEM [direction,
-    slot]: the hop-h DMA lands in slot h%2 while the reduce-add that
-    prepares hop h+1 writes slot (h+1)%2 — the double buffer that lets
-    the adds overlap the in-flight DMAs.
+    ``acc_ref``/``recv_ref`` are (2, 2, tile, 128) VMEM [direction,
+    slot]: the hop-g DMA lands in slot g%2 while the reduce-add that
+    prepares hop g+1 writes slot (g+1)%2 — the double buffer that lets
+    the adds overlap the in-flight DMAs. ``g`` counts hops ACROSS grid
+    steps, so a slot is always reused two hops after its last use; each
+    hop waits on both neighbors' sends, which a neighbor only issues
+    after folding the hop before, so by then the slot has been read.
     """
-    me = lax.axis_index(axis_name)
-    right = lax.rem(me + 1, num_devices)
-    left = lax.rem(me - 1 + num_devices, num_devices)
+    step = pl.program_id(0)
+    me, right, left = _neighbors(axis_name, num_devices)
 
-    barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(
-        barrier, device_id=(right,),
-        device_id_type=pltpu.DeviceIdType.MESH,
-    )
-    pltpu.semaphore_signal(
-        barrier, device_id=(left,),
-        device_id_type=pltpu.DeviceIdType.MESH,
-    )
-    pltpu.semaphore_wait(barrier, 2)
+    @pl.when(step == 0)
+    def _enter():
+        _neighbor_barrier(right, left)
 
+    g0 = step * (num_devices - 1)
     # seed: the first chunk each stream pushes is the pure local partial
-    acc_ref[0, 0] = parts_ref[lax.rem(me - 1 + num_devices, num_devices), 0]
-    acc_ref[1, 0] = parts_ref[lax.rem(me + 1, num_devices), 1]
+    s0 = lax.rem(g0, 2)
+    acc_ref[0, s0] = parts_ref[lax.rem(me - 1 + num_devices, num_devices), 0]
+    acc_ref[1, s0] = parts_ref[lax.rem(me + 1, num_devices), 1]
 
     for h in range(num_devices - 1):
-        slot = h % 2
-        nxt = (h + 1) % 2
+        slot = lax.rem(g0 + h, 2)
+        nxt = 1 - slot
         cw = pltpu.make_async_remote_copy(
             src_ref=acc_ref.at[0, slot],
             dst_ref=recv_ref.at[0, slot],
             send_sem=send_sems.at[0, slot],
             recv_sem=recv_sems.at[0, slot],
-            device_id=(right,),
+            device_id=right,
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         ccw = pltpu.make_async_remote_copy(
@@ -251,7 +276,7 @@ def _rs_kernel(parts_ref, out_ref, acc_ref, recv_ref, send_sems,
             dst_ref=recv_ref.at[1, slot],
             send_sem=send_sems.at[1, slot],
             recv_sem=recv_sems.at[1, slot],
-            device_id=(left,),
+            device_id=left,
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         cw.start()
@@ -262,12 +287,12 @@ def _rs_kernel(parts_ref, out_ref, acc_ref, recv_ref, send_sems,
         # final hop the received chunk IS mine, so this add completes it
         c_cw = lax.rem(me - 2 - h + 2 * num_devices, num_devices)
         c_ccw = lax.rem(me + 2 + h, num_devices)
-        acc_ref[0, nxt] = recv_ref[0, slot] + parts_ref[c_cw, 0]
-        acc_ref[1, nxt] = recv_ref[1, slot] + parts_ref[c_ccw, 1]
-
-    last = (num_devices - 1) % 2
-    out_ref[0] = acc_ref[0, last]
-    out_ref[1] = acc_ref[1, last]
+        if h == num_devices - 2:
+            out_ref[0] = recv_ref[0, slot] + parts_ref[c_cw, 0]
+            out_ref[1] = recv_ref[1, slot] + parts_ref[c_ccw, 1]
+        else:
+            acc_ref[0, nxt] = recv_ref[0, slot] + parts_ref[c_cw, 0]
+            acc_ref[1, nxt] = recv_ref[1, slot] + parts_ref[c_ccw, 1]
 
 
 def ring_reduce_scatter(x, axis_name: str, *, scatter_dimension: int = 0,
@@ -290,7 +315,7 @@ def ring_reduce_scatter(x, axis_name: str, *, scatter_dimension: int = 0,
 
 def _ring_reduce_scatter(x, axis_name: str, scatter_dimension: int = 0,
                          stream: int = 0):
-    d = _axis_size(axis_name)
+    d = lax.axis_size(axis_name)
     if (
         d == 1
         or not ring_supported()
@@ -299,6 +324,20 @@ def _ring_reduce_scatter(x, axis_name: str, scatter_dimension: int = 0,
         return lax.psum_scatter(
             x, axis_name, scatter_dimension=scatter_dimension, tiled=True
         )
+    return _fully_manual(
+        lambda x: reduce_scatter_kernel(
+            x, axis_name, d, scatter_dimension, stream
+        )
+    )(x)
+
+
+def reduce_scatter_kernel(x, axis_name: str, d: int,
+                          scatter_dimension: int = 0, stream: int = 0,
+                          interpret=False):
+    """The ring reduce-scatter ``pallas_call`` itself (no backend
+    dispatch; compiled and interpreted by the same tests as
+    :func:`all_gather_kernel`). Each destination chunk is zero-padded to
+    whole row tiles."""
     dim = scatter_dimension
     chunk = x.shape[dim] // d
     parts = jnp.moveaxis(
@@ -308,19 +347,24 @@ def _ring_reduce_scatter(x, axis_name: str, scatter_dimension: int = 0,
     n = 1
     for s in chunk_shape:
         n *= int(s)
-    rows = _half_rows(n)
-    if rows is None:
-        return lax.psum_scatter(
-            x, axis_name, scatter_dimension=dim, tiled=True
-        )
-    halves = parts.astype(jnp.float32).reshape(d, 2, rows, _LANES)
-    work = (2, 2, rows, _LANES)  # [direction, slot] double buffers
-    # in/out in VMEM (not ANY/HBM): the body reduce-adds directly on the
-    # refs, and the per-chunk halves are small by construction
+    # rows of one direction-half, rounded up to the (8, 128) f32 tile and,
+    # past one row tile, to whole tiles
+    rows = -(-n // (2 * _LANES))
+    tile = min(_RS_TILE_ROWS, -(-rows // 8) * 8)
+    rows = -(-rows // tile) * tile
+    flat = parts.astype(jnp.float32).reshape(d, n)
+    pad = 2 * rows * _LANES - n
+    if pad:
+        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    halves = flat.reshape(d, 2, rows, _LANES)
+    work = (2, 2, tile, _LANES)  # [direction, slot] double buffers
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        grid=(rows // tile,),
+        in_specs=[
+            pl.BlockSpec((d, 2, tile, _LANES), lambda i: (0, 0, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((2, tile, _LANES), lambda i: (0, i, 0)),
         scratch_shapes=[
             pltpu.VMEM(work, jnp.float32),     # acc
             pltpu.VMEM(work, jnp.float32),     # recv
@@ -334,8 +378,10 @@ def _ring_reduce_scatter(x, axis_name: str, scatter_dimension: int = 0,
         ),
         out_shape=jax.ShapeDtypeStruct((2, rows, _LANES), jnp.float32),
         grid_spec=grid_spec,
-        compiler_params=pltpu.TPUCompilerParams(
-            collective_id=2 * int(stream) + 1
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            collective_id=2 * int(stream) + 1, has_side_effects=True,
         ),
+        interpret=interpret,
     )(halves)
-    return out.reshape(chunk_shape).astype(x.dtype)
+    return out.reshape(-1)[:n].reshape(chunk_shape).astype(x.dtype)

@@ -278,9 +278,7 @@ def _fwd_single(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying ``like``'s varying-manual-axes set, so the
     kernels compose with shard_map manual axes (ring attention's folds)."""
-    from distributed_pytorch_example_tpu.runtime.jax_compat import typeof
-
-    vma = getattr(typeof(like), "vma", None)
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -896,6 +894,54 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, residuals, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _flash_over_mesh(q, k, v, kv_mask, *static):
+    """:func:`_flash` on (B, N, S, H) operands, inside a region that is
+    manual over every mesh axis.
+
+    The TPU lowering refuses a Mosaic kernel that XLA would have to
+    partition (any multi-device jit, or a shard_map manual over only some
+    axes — the ZeRO-1 step's data-manual body). So on a mesh the kernel is
+    wrapped in a shard_map over the axes that are still automatic, under
+    the repo's placement convention: batch over the data axes, heads over
+    ``tensor`` where both head counts divide, everything else replicated.
+    Attention is independent per (batch row, head), so each shard runs
+    the unmodified kernel on its slice. Callers already manual over the
+    whole mesh (ring / Ulysses attention) pass straight through.
+    """
+    import math
+
+    from jax.sharding import PartitionSpec as P
+
+    from ...runtime.mesh import data_axes, free_mesh_axes
+
+    mesh, free = free_mesh_axes()
+    if not free:
+        return _flash(q, k, v, kv_mask, *static)
+    ctx = jax.sharding.get_abstract_mesh() if mesh is None else mesh
+    shape = ctx.shape
+    batch = tuple(a for a in data_axes(ctx) if a in free and shape[a] > 1)
+    if q.shape[0] % math.prod(shape[a] for a in batch):
+        batch = ()
+    tp = shape.get("tensor", 1)
+    heads = (
+        "tensor"
+        if "tensor" in free and tp > 1
+        and q.shape[1] % tp == 0 and k.shape[1] % tp == 0
+        else None
+    )
+    qkv = P(batch or None, heads, None, None)
+    operands, specs = (q, k, v), (qkv, qkv, qkv)
+    if kv_mask is not None:  # (B, 1, S_k): follows the batch rows
+        operands += (kv_mask,)
+        specs += (P(batch or None, None, None),)
+    mapped = jax.shard_map(
+        lambda q, k, v, m=None: _flash(q, k, v, m, *static),
+        mesh=mesh, in_specs=specs, out_specs=qkv,
+        axis_names=set(free), check_vma=False,
+    )
+    return mapped(*operands)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -938,7 +984,7 @@ def flash_attention(
         kv_mask = kv_mask.astype(jnp.float32)[:, None, :]  # (B, 1, S_k): TPU tile-rule-friendly block shape
     # (B, S, N, H) -> (B, N, S, H)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = _flash(
+    out = _flash_over_mesh(
         qt, kt, vt, kv_mask, causal, float(softmax_scale), block_q, block_k,
         interpret,
     )
@@ -971,7 +1017,7 @@ def flash_attention_bnsh(
     block_q, block_k = _validate_flash_shapes(
         q.shape[1], k.shape[1], q.shape[2], k.shape[2], block_q, block_k
     )
-    return _flash(
+    return _flash_over_mesh(
         q, k, v, None, causal, float(softmax_scale), block_q, block_k,
         interpret,
     )

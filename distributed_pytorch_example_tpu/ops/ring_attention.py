@@ -40,10 +40,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_pytorch_example_tpu.runtime.jax_compat import (
-    axis_size as _axis_size,
-    shard_map as _compat_shard_map,
-)
 
 NEG_INF = -1e30
 
@@ -257,7 +253,7 @@ def _merge(o, lse, o_i, lse_i):
 
 def _ring_fwd_impl(q, k, v, kv_mask, axis_name, causal, scale, flash,
                    interpret):
-    n_chunks = _axis_size(axis_name)
+    n_chunks = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     batch, s_loc, heads, head_dim = q.shape
     shift = [(i, (i + 1) % n_chunks) for i in range(n_chunks)]
@@ -329,7 +325,7 @@ def _ring_fwd_impl(q, k, v, kv_mask, axis_name, causal, scale, flash,
 
 def _ring_bwd_impl(q, k, v, kv_mask, out, lse, g, axis_name, causal, scale,
                    flash, interpret):
-    n_chunks = _axis_size(axis_name)
+    n_chunks = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     shift = [(i, (i + 1) % n_chunks) for i in range(n_chunks)]
     has_mask = kv_mask is not None
@@ -545,12 +541,12 @@ def ring_attention_sharded(
         use_flash=use_flash,
     )
     if kv_mask is None:
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
         )
         return fn(q, k, v)
     mask_spec = P(batch_axes, seq_axis)
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, m: kernel(q, k, v, kv_mask=m),
         mesh=mesh,
         in_specs=(spec, spec, spec, mask_spec),

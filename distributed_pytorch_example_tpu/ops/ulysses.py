@@ -42,10 +42,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_pytorch_example_tpu.ops.attention import dot_product_attention
-from distributed_pytorch_example_tpu.runtime.jax_compat import (
-    axis_size as _axis_size,
-    shard_map as _compat_shard_map,
-)
 
 # (kv_heads, axis_size) pairs already warned about use_flash on the grouped
 # GQA path — without this the warning fires once per attention layer per trace
@@ -82,7 +78,7 @@ def ulysses_attention(
     """
     import jax.numpy as jnp
 
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if q.shape[2] % p:
         raise ValueError(
             f"ulysses needs q heads ({q.shape[2]}) divisible by the "
@@ -220,7 +216,7 @@ def _grouped_fwd_impl(qt, ks, vs, mask_full, axis_name, causal, scale, rep):
     import jax.numpy as jnp
 
     B, nq, S, H = qt.shape
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     Sp, c = S // p, S // p // rep
     r0 = lax.axis_index(axis_name) % rep
     shift = _grouped_in_group_shift(p // rep, rep)
@@ -268,7 +264,7 @@ def _grouped_bwd_impl(qt, ks, vs, mask_full, out, lse, g, axis_name, causal,
     import jax.numpy as jnp
 
     B, nq, S, H = qt.shape
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     Sp, c = S // p, S // p // rep
     r0 = lax.axis_index(axis_name) % rep
     shift = _grouped_in_group_shift(p // rep, rep)
@@ -373,7 +369,7 @@ def _ulysses_gqa_grouped(
     """
     import jax.numpy as jnp
 
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     B, Sp, N, H = q.shape
     kv = k.shape[2]
     rep = p // kv
@@ -460,12 +456,12 @@ def ulysses_attention_sharded(
         use_flash=use_flash,
     )
     if kv_mask is None:
-        fn = _compat_shard_map(
+        fn = jax.shard_map(
             kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
         )
         return fn(q, k, v)
     mask_spec = P(batch, seq_axis)
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, m: kernel(q, k, v, kv_mask=m),
         mesh=mesh,
         in_specs=(spec, spec, spec, mask_spec),

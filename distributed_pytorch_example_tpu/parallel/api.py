@@ -60,23 +60,13 @@ def pvary_like(tree: Any, like: jax.Array, extra_axes: Sequence[str] = ()) -> An
     """
     from jax import lax
 
-    from distributed_pytorch_example_tpu.runtime.jax_compat import (
-        has_vma_types, typeof,
-    )
-
-    if not has_vma_types():
-        return tree  # pre-vma jax: nothing to stamp
-
-    target = set(typeof(like).vma) | set(extra_axes)
-    pcast = getattr(lax, "pcast", None)
+    target = set(jax.typeof(like).vma) | set(extra_axes)
 
     def mark(x):
-        missing = tuple(target - set(typeof(x).vma))
+        missing = tuple(target - set(jax.typeof(x).vma))
         if not missing:
             return x
-        if pcast is not None:
-            return pcast(x, missing, to="varying")
-        return lax.pvary(x, missing)  # older jax
+        return lax.pcast(x, missing, to="varying")
 
     return jax.tree_util.tree_map(mark, tree)
 

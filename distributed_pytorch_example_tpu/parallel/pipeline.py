@@ -47,10 +47,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_pytorch_example_tpu.parallel.api import pvary_like
-from distributed_pytorch_example_tpu.runtime.jax_compat import (
-    axis_size as _axis_size,
-    shard_map,
-)
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
 
@@ -103,7 +99,7 @@ def _gpipe_local(stage_params, in_buf, *, stage_fn: StageFn, axis_name: str,
     axis, so the returned aux is the total over all (layer, microbatch)
     contributions.
     """
-    n_stages = _axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     m = in_buf.shape[0]
     params = jax.tree_util.tree_map(lambda p: p[0], stage_params)
@@ -270,7 +266,7 @@ def gpipe(
         x_stack, NamedSharding(mesh, queue_spec)
     )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _gpipe_local, stage_fn=stage_fn, axis_name=pipe_axis,
             n_micro=n_micro, aux_init=aux_init,
@@ -505,7 +501,7 @@ def _1f1b_local(stage_params, last_params, in_buf, last_args, *,
     dx_buf) — loss/metrics/aux psum'd over pipe (and seq); d_stage/dx stay
     sharded over pipe (d_stage seq-reduced, dx seq-chunked).
     """
-    n_stages = _axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     is_last = stage == n_stages - 1
     is_first = stage == 0
@@ -588,39 +584,47 @@ def _1f1b_local(stage_params, last_params, in_buf, last_args, *,
     if recompute:
         res_src = res_structs = None
     else:
-        # Classify the stage vjp's residual leaves ONCE (abstract trace —
-        # nothing executes): a leaf that is literally a stage param (the
-        # transpose's weight operand) is restored at B time from the LIVE
-        # params (constant within a step); the stage-input leaf rides the
-        # existing input ring; every other leaf — the true forward
-        # intermediates — gets its own K-slot ring in the scan carry. The
-        # classification is trace-deterministic: same stage_fn + same
-        # avals => same residual list in the schedule's own trace below.
+        # Trace the stage forward + vjp ONCE into a jaxpr whose outputs are
+        # (stage outputs, vjp residual leaves), and run THAT jaxpr at every
+        # F sub-tick: the residual list's order then cannot depend on the
+        # tracing context (jax.vjp called directly inside the scan body
+        # orders closed-over params differently than a standalone trace
+        # does). The jaxpr also classifies the leaves exactly: a residual
+        # outvar that IS an invar is a forwarded input — a stage param
+        # (restored at B time from the LIVE params, constant within a
+        # step) or the stage input (rides the existing input ring); every
+        # other leaf — the true forward intermediates — gets its own
+        # K-slot ring in the scan carry. Traced on a REAL (pipe-varying)
+        # microbatch, not an abstract prototype, so the avals carry the
+        # schedule's varying-axes types.
+        from jax.extend import core as jex_core
+
         probe: dict = {}
 
-        def _probe(p, x_):
-            _, vjp_fn = jax.vjp(stage_fn, p, x_)
-            leaves, _ = jax.tree_util.tree_flatten(vjp_fn)
-            pids = {
-                id(l): i
-                for i, l in enumerate(jax.tree_util.tree_leaves(p))
-            }
-            probe["src"] = tuple(
-                ("param", pids[id(l)]) if id(l) in pids
-                else ("x", None) if l is x_
-                else ("ring", None)
-                for l in leaves
-            )
-            probe["structs"] = tuple(
-                jax.ShapeDtypeStruct(l.shape, l.dtype) for l in leaves
-            )
-            return jnp.zeros(())
+        def _fwd_res(p, x_):
+            out, vjp_fn = jax.vjp(stage_fn, p, x_)
+            leaves, probe["vjp_treedef"] = jax.tree_util.tree_flatten(vjp_fn)
+            outs, probe["out_treedef"] = jax.tree_util.tree_flatten(out)
+            probe["n_out"] = len(outs)
+            return outs + leaves
 
-        jax.eval_shape(_probe, pick(0), y_proto)
-        res_src = probe["src"]
+        p0 = pick(0)
+        fwd_res = jax.make_jaxpr(_fwd_res)(p0, in_buf[0])
+        n_out, vjp_treedef = probe["n_out"], probe["vjp_treedef"]
+        n_params = len(jax.tree_util.tree_leaves(p0))
+        in_pos = {v: i for i, v in enumerate(fwd_res.jaxpr.invars)}
+        res_vars = fwd_res.jaxpr.outvars[n_out:]
+
+        def _src(v):
+            i = in_pos.get(v) if isinstance(v, jex_core.Var) else None
+            if i is None:
+                return ("ring", None)
+            return ("param", i) if i < n_params else ("x", None)
+
+        res_src = tuple(_src(v) for v in res_vars)
         res_structs = tuple(
-            s for s, (kind, _) in zip(probe["structs"], res_src)
-            if kind == "ring"
+            jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
+            for v, (kind, _) in zip(res_vars, res_src) if kind == "ring"
         )
 
     def cycle(carry, t):
@@ -649,7 +653,7 @@ def _1f1b_local(stage_params, last_params, in_buf, last_args, *,
         x_in = jnp.where(first_chunk_f, head, incoming)
         stash = _store(stash, x_in, jnp.mod(t, K), active_f)
         params_f = pick(j_f)
-        aux_tick = vjp_treedef = None
+        aux_tick = None
         if recompute:
             if aux_desc is None:
                 y = stage_fn(params_f, x_in)
@@ -658,11 +662,15 @@ def _1f1b_local(stage_params, last_params, in_buf, last_args, *,
         else:
             # capture this forward's vjp; its residual intermediates ride
             # per-leaf rings to the matching B sub-tick (no stage replay)
-            if aux_desc is None:
-                y, vjp_f = jax.vjp(stage_fn, params_f, x_in)
-            else:
-                (y, aux_tick), vjp_f = jax.vjp(stage_fn, params_f, x_in)
-            leaves_f, vjp_treedef = jax.tree_util.tree_flatten(vjp_f)
+            flat = jax.core.eval_jaxpr(
+                fwd_res.jaxpr, fwd_res.consts,
+                *jax.tree_util.tree_leaves(params_f), x_in,
+            )
+            out = jax.tree_util.tree_unflatten(
+                probe["out_treedef"], flat[:n_out]
+            )
+            y, aux_tick = out if aux_desc is not None else (out, None)
+            leaves_f = flat[n_out:]
             ringed_f = tuple(
                 l for l, (kind, _) in zip(leaves_f, res_src)
                 if kind == "ring"
@@ -685,22 +693,27 @@ def _1f1b_local(stage_params, last_params, in_buf, last_args, *,
         # results/pipeline_1f1b/head_cost.json.
         keep = last_chunk_f & active_f
 
-        def _head_eval(y_):
+        def _head_eval(y_, args_u):
             return jax.value_and_grad(
                 last_loss, argnums=(0, 1), has_aux=True
-            )(y_, last_params, slice_args(u_f))
+            )(y_, last_params, args_u)
 
+        # the microbatch's last_args are sliced OUTSIDE the cond: under a
+        # partial-auto shard_map GSPMD may have to re-lay them out over
+        # the auto (data) axes, and a collective-permute inside a branch
+        # that only the last stage takes never completes its rendezvous
+        args_u = slice_args(u_f)
         if predicate_head:
             (loss_u, mets_u), (dy_u, dlast_u) = lax.cond(
                 keep,
                 _head_eval,
-                lambda y_: jax.tree_util.tree_map(
+                lambda y_, _: jax.tree_util.tree_map(
                     lambda s: pv(jnp.zeros(s.shape, s.dtype)), head_struct
                 ),
-                y,
+                y, args_u,
             )
         else:
-            (loss_u, mets_u), (dy_u, dlast_u) = _head_eval(y)
+            (loss_u, mets_u), (dy_u, dlast_u) = _head_eval(y, args_u)
         loss_acc = loss_acc + jnp.where(keep, loss_u, 0.0)
         mets_acc = _tree_add(
             mets_acc, _tree_where(keep, mets_u, _zeros_of(mets_struct))
@@ -814,24 +827,16 @@ def _1f1b_local(stage_params, last_params, in_buf, last_args, *,
         if n_stages > 1:
             incoming = lax.ppermute(y, axis_name, ring_up)
             cot_in = lax.ppermute(dx_u, axis_name, ring_down)
-        if n_virtual == 1:
-            # every cycle rotates (rot is constant True): classic path
-            received = lax.ppermute(head, axis_name, ring_down)
-            in_buf = lax.dynamic_update_index_in_dim(
-                in_buf, received, head_slot, 0
-            )
-        else:
-            # only S of every V cycles rotate; skip the microbatch-sized
-            # ring transfer on the others. ``rot`` depends only on the
-            # cycle counter t, so every device takes the same branch and
-            # the ppermute inside the cond cannot mismatch.
-            def _rotate(buf):
-                received = lax.ppermute(head, axis_name, ring_down)
-                return lax.dynamic_update_index_in_dim(
-                    buf, received, head_slot, 0
-                )
-
-            in_buf = lax.cond(rot, _rotate, lambda buf: buf, in_buf)
+        # only S of every V cycles rotate the input ring (all of them at
+        # n_virtual == 1). The ppermute is issued UNCONDITIONALLY: every
+        # device must run the same collectives in the same order, and a
+        # ppermute under ``lax.cond`` is unordered against the permutes
+        # above (XLA:CPU deadlocks at the rendezvous). Non-rotating cycles
+        # write ``head`` back over itself instead.
+        received = lax.ppermute(head, axis_name, ring_down)
+        in_buf = lax.dynamic_update_index_in_dim(
+            in_buf, jnp.where(rot, received, head), head_slot, 0
+        )
         return (incoming, cot_in, in_buf, stash, res_rings, dx_buf, reg_dx,
                 reg_du, d_stage, d_last, loss_acc, mets_acc, aux_acc), None
 
@@ -899,7 +904,7 @@ def _1f1b_run(stage_fn, last_fn, mesh, n_micro, pipe_axis, data_axes,
         if seq is None
         else (lambda a: P(None, None, seq) if a.ndim >= 3 else P())
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _1f1b_local, stage_fn=stage_fn, last_fn=last_fn,
             axis_name=pipe_axis, n_micro=n_micro, aux_desc=aux_desc,
@@ -1085,11 +1090,20 @@ def one_f_one_b(
         x_stack, NamedSharding(mesh, P(pipe_axis, data or None, seq))
     )
     mb = batch // n_micro
-    last_args = jax.tree_util.tree_map(
-        lambda a: a.reshape(n_micro, mb, *a.shape[1:])
-        if a.shape[:1] == (batch,) else a,
-        last_args,
-    )
+
+    def stack_arg(a):
+        if a.shape[:1] != (batch,):
+            return a
+        # same microbatch layout as the activation queue (mb dim over the
+        # data axes): the head then sees operands that already agree, and
+        # GSPMD has no reason to put a resharding collective inside the
+        # last-stage-only ``lax.cond`` branch (a collective-permute there
+        # is joined by half the devices and never completes on XLA:CPU)
+        a = a.reshape(n_micro, mb, *a.shape[1:])
+        spec = P(None, data or None, seq) if a.ndim >= 3 else P(None, data or None)
+        return lax.with_sharding_constraint(a, NamedSharding(mesh, spec))
+
+    last_args = jax.tree_util.tree_map(stack_arg, last_args)
     if aux_weights is None:
         aux_desc = None
     else:

@@ -239,13 +239,6 @@ def _dequantize_rows(q, scales, n: int, dtype=jnp.float32):
 # -- collective drop-ins (call INSIDE a shard_map manual over ``axis``) ----
 
 
-def _axis_size(axis_name: str) -> int:
-    # psum of a concrete python scalar folds to the static axis size —
-    # avoids lax.axis_index, which lowers to a PartitionId op the pre-0.9
-    # CPU SPMD partitioner cannot handle (see train/step.py body())
-    return int(lax.psum(1, axis_name))
-
-
 def _split_key(key, n: int):
     if key is None:
         return (None,) * n
@@ -270,7 +263,7 @@ def wire_psum_scatter(x, axis_name: str, *, scatter_dimension: int,
         return lax.psum_scatter(
             x, axis_name, scatter_dimension=scatter_dimension, tiled=True
         )
-    d = _axis_size(axis_name)
+    d = lax.axis_size(axis_name)
     dim = scatter_dimension
     if x.shape[dim] % d:
         raise ValueError(
@@ -336,7 +329,7 @@ def wire_psum(x, axis_name: str, *,
     config = config or WireConfig()
     if not config.compresses(x.size):
         return lax.psum(x, axis_name)
-    d = _axis_size(axis_name)
+    d = lax.axis_size(axis_name)
     k1, k2 = _split_key(key if config.stochastic_rounding else None, 2)
     flat = x.reshape(-1)
     pad = (-flat.size) % d
@@ -664,7 +657,7 @@ def sync_grads(grads, dims, axis_name: str, *,
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     dim_leaves = jax.tree_util.tree_leaves(dims, is_leaf=is_dim_leaf)
-    d = _axis_size(axis_name)
+    d = lax.axis_size(axis_name)
     if plan is None:
         plan = plan_buckets(dims, grads, config, d)
     out: list = list(leaves)  # zero-size leaves pass through unsynced
@@ -705,7 +698,6 @@ def replicate_params(params: Any, partitioner, config: WireConfig,
     """
     from jax.sharding import PartitionSpec as P
 
-    from distributed_pytorch_example_tpu.runtime import jax_compat
 
     dims = partitioner.zero1_dims(params)
     is_dim_leaf = lambda d: d is None  # noqa: E731 - tree of Optional[int]
@@ -738,12 +730,13 @@ def replicate_params(params: Any, partitioner, config: WireConfig,
             gather, dims, params, is_leaf=is_dim_leaf
         )
 
-    mapped = jax_compat.shard_map(
+    mapped = jax.shard_map(
         body,
-        partitioner.mesh,
+        mesh=partitioner.mesh,
         in_specs=(in_specs,),
         out_specs=jax.tree_util.tree_map(lambda _: P(), params),
         axis_names={axis_name},
+        check_vma=False,  # a gathered leaf IS replicated; all_gather types it varying
     )
     return mapped(params)
 

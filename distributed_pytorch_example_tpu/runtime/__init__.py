@@ -5,6 +5,9 @@ TPU-native replacement for the reference's launch/communication layers
 host; devices join a global mesh; collectives are compiled by XLA.
 """
 
+from distributed_pytorch_example_tpu.runtime.compile_cache import (  # noqa: F401
+    enable_compile_cache,
+)
 from distributed_pytorch_example_tpu.runtime.distributed import (  # noqa: F401
     DistributedConfig,
     barrier,

@@ -243,7 +243,34 @@ def current_mesh():
             "runtime.mesh.current_mesh for this jax version"
         ) from e
     mesh = thread_resources.env.physical_mesh
-    return mesh if mesh.devices.size > 0 else None
+    # ``Mesh.empty``, not ``devices.size``: the empty mesh's device array is
+    # 0-d, whose size is 1
+    return None if mesh.empty else mesh
+
+
+def free_mesh_axes():
+    """``(mesh, axes)``: the mesh axes that are NOT manual at this point of
+    the trace, and the ``mesh=`` argument a nested ``jax.shard_map`` over
+    them takes (None inside an enclosing shard_map, whose context mesh it
+    then inherits).
+
+    A Mosaic (Pallas TPU) kernel cannot be partitioned automatically: on
+    more than one device it must sit in a region that is manual over EVERY
+    mesh axis, or the lowering refuses it. Callers wrap the kernel in a
+    shard_map over these axes; ``axes == ()`` means no wrap is needed
+    (one device, no mesh, or already fully manual).
+    """
+    import jax
+
+    abstract = jax.sharding.get_abstract_mesh()
+    if not abstract.empty and abstract.manual_axes:
+        # inside a shard_map: its context mesh knows what is manual already
+        manual = set(abstract.manual_axes)
+        return None, tuple(a for a in abstract.axis_names if a not in manual)
+    mesh = current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return None, ()
+    return mesh, tuple(mesh.axis_names)
 
 
 def data_axes(mesh) -> Sequence[str]:

@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Dict, Optional
 
 # peak dense bf16 FLOP/s per chip by PJRT device_kind substring (the table
-# bench.py judges MFU against; CPU and unknown kinds return None)
+# bench.py judges MFU against). A v5e reports device_kind "TPU v5 lite".
 PEAK_BF16 = {
     "v4": 275e12,
     "v5e": 197e12,
@@ -29,11 +29,18 @@ PEAK_BF16 = {
 
 
 def peak_bf16_flops(device) -> Optional[float]:
-    """Peak dense bf16 FLOP/s for one chip, or None when unknown (CPU)."""
+    """Peak dense bf16 FLOP/s for one chip; None off-TPU (no peak to
+    judge against). An unknown TPU kind raises — returning None there
+    would make MFU silently vanish from every record."""
     kind = getattr(device, "device_kind", "").lower()
     for key, peak in PEAK_BF16.items():
         if key in kind:
             return peak
+    if getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"unknown TPU device_kind {device.device_kind!r}: add its peak "
+            f"bf16 FLOP/s to telemetry/cost.py PEAK_BF16"
+        )
     return None
 
 

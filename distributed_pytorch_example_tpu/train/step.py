@@ -265,11 +265,9 @@ def build_train_step(
             acc = jax.tree_util.tree_map(jnp.add, acc, g)
             return (ms, acc), metrics
 
-        # unroll=N (full): a rolled while op inside the data-manual region
-        # hard-crashes the 0.4.x SPMD partitioner (Check failed:
-        # sharding.IsManualSubgroup() partitioning the loop carry); the
-        # unrolled scan keeps the accumulate-then-sync structure with no
-        # while op, at compile time linear in N (N is single-digit)
+        # unroll=N (full): the unrolled scan keeps the accumulate-then-sync
+        # structure with no while op inside the data-manual region, at
+        # compile time linear in N (N is single-digit)
         (new_ms, grads), metrics = jax.lax.scan(
             scan_body,
             (model_state, acc0),
@@ -283,7 +281,6 @@ def build_train_step(
         (micro)batches, then ONE explicit collective per param leaf —
         psum_scatter into the ZeRO-1 layout where the optimizer state is
         sharded, psum where it stays replicated."""
-        from distributed_pytorch_example_tpu.runtime import jax_compat
         from jax.sharding import PartitionSpec as P
 
         mesh = partitioner.mesh
@@ -299,11 +296,9 @@ def build_train_step(
         is_dim_leaf = lambda d: d is None  # noqa: E731 - tree of Optional[int]
 
         def body(params, model_state, batch, shard_id, rng):
-            # per-shard rng WITHOUT lax.axis_index (that lowers to a
-            # PartitionId op pre-0.9 SPMD cannot partition — the known
-            # pipe-config gap): the shard id rides in as the local slice
-            # of an arange sharded over 'data'. Decorrelates dropout/MLM
-            # masking draws across data shards.
+            # per-shard rng: the shard id rides in as the local slice of an
+            # arange sharded over 'data'. Decorrelates dropout/MLM masking
+            # draws across data shards.
             rng = jax.random.fold_in(rng, shard_id[0])
             if grad_accum_steps > 1:
                 grads, metrics, new_ms = accumulate_grads(
@@ -345,9 +340,9 @@ def build_train_step(
             dims, params, is_leaf=is_dim_leaf,
         )
         shard_ids = jnp.arange(max(dsize, 1), dtype=jnp.int32)
-        mapped = jax_compat.shard_map(
+        mapped = jax.shard_map(
             body,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(), P(),
                 partitioner.manual_batch_spec(),
@@ -356,6 +351,14 @@ def build_train_step(
             ),
             out_specs=(grad_out_specs, P(), P()),
             axis_names={axis},
+            # the body works in per-shard partials and reduces them itself:
+            # grads wrt the replicated params must STAY local until
+            # sync_grads (the varying-axes type system would psum them in
+            # the transpose, and rejects chunked_ce's custom-VJP partial
+            # for an unvarying head), and the quantized all-reduce ends in
+            # an all-gather whose result is replicated but cannot be typed
+            # so through a public API
+            check_vma=False,
         )
         return mapped(params, model_state, batch, shard_ids, rng)
 

@@ -173,6 +173,9 @@ def main() -> int:
             for f in os.listdir(args.repeats_dir)
             if f.startswith("repeat") and f.endswith(".json")
         )
+    # This parent never imports jax, and must stay that way: a chip belongs
+    # to one process at a time, so a parent that had touched JAX would hold
+    # it and every bench.py child below would fail to get it (or hang).
     for i in range(args.run):
         path = f"/tmp/bench_noise_run{i + 1}.json"
         cmd = [sys.executable, os.path.join(ROOT, "bench.py")]

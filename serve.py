@@ -479,7 +479,14 @@ def emit_fleet_line(args, report, baseline) -> int:
     return 0
 
 
-def main() -> int:
+def main(argv=None, state=None) -> int:
+    """Run the CLI; returns the exit code.
+
+    ``argv`` defaults to ``sys.argv[1:]``. A caller that wants to look at
+    what ran (chip_smoke.py) passes a dict as ``state``; the single-engine
+    path leaves its ``engine``, ``requests``, ``report`` and the shared
+    ``built`` (model, params, partitioner) triple in it.
+    """
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--family", default="gpt2",
                         choices=("gpt2", "llama"))
@@ -560,7 +567,7 @@ def main() -> int:
                         help="fleet: router queue bound (overflow sheds)")
     parser.add_argument("--queue-deadline", type=float, default=30.0,
                         help="fleet: shed requests queued longer than this")
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
     if args.requests < 1:
         parser.error("--requests must be >= 1")
     if args.replicas < 1:
@@ -580,8 +587,10 @@ def main() -> int:
     if args.swap_poll_s is not None and args.swap_poll_s <= 0:
         parser.error("--swap-poll-s must be > 0")
 
+    from distributed_pytorch_example_tpu.runtime import enable_compile_cache
     from distributed_pytorch_example_tpu.telemetry.trace import TraceWriter
 
+    enable_compile_cache()
     if args.chaos and args.replicas == 1:
         # train.py contract: the plan is live before the engine exists
         from distributed_pytorch_example_tpu.robustness import chaos
@@ -615,6 +624,10 @@ def main() -> int:
     engine = build_engines(args, trace, built, 1)[0]
     report = engine.run(requests)
     trace.close()
+    if state is not None:
+        state.update(
+            engine=engine, requests=requests, report=report, built=built
+        )
     if args.metrics_snapshot:
         write_metrics_snapshot(
             args.metrics_snapshot, report["metrics"], _config_dict(args)

@@ -48,7 +48,7 @@ def _run_gate(prev, cur, tmp_path, extra=()):
     proc = subprocess.run(
         [sys.executable, GATE, "--prev", str(prev_path), "--noise", "",
          "--scaling", "", *extra],
-        input=json.dumps(cur), capture_output=True, text=True,
+        input=json.dumps(cur), capture_output=True, text=True, timeout=120,
     )
     return proc.returncode, proc.stderr
 
@@ -194,7 +194,7 @@ def test_per_model_noise_tolerances(tmp_path):
     proc = subprocess.run(
         [sys.executable, GATE, "--prev", str(prev_path),
          "--noise", str(noise_path)],
-        input=json.dumps(cur), capture_output=True, text=True,
+        input=json.dumps(cur), capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode == 1
     lines = {ln.strip().split(":")[0]: ln for ln in proc.stderr.splitlines()
@@ -221,7 +221,7 @@ def test_not_a_bench_payload(tmp_path):
     prev_path.write_text(json.dumps({"nonsense": True}))
     proc = subprocess.run(
         [sys.executable, GATE, "--prev", str(prev_path)],
-        input="{}", capture_output=True, text=True,
+        input="{}", capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode != 0
 

@@ -1,0 +1,232 @@
+"""AOT-compile the main path's Pallas kernels for a described TPU v5e.
+
+No chip is attached here: ``topologies.get_topology_desc`` describes a
+``v5e:2x2`` host and the installed TPU compiler compiles for it, so a
+kernel the chip would refuse (tile shapes, VMEM, CompilerParams) is
+refused in this file, at no chip time. Nothing runs — these say nothing
+about results or speed (the interpret-mode tests pin numerics;
+``chip_smoke.py`` is the run on the chip).
+
+Shapes are GPT-2 124M's: 12 heads of 64, sequence 1024, bf16, 16
+sequences a chip (bench.py's LM cell), and the serve shapes chip_smoke.py
+uses. The topology is described inside a module-scoped fixture — never
+at import — because only one process may hold libtpu, and every xdist
+worker imports every test file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+BATCH, SEQ, HEADS, HEAD_DIM = 16, 1024, 12, 64
+# serve.py at GPT-2 width as chip_smoke.py drives it: 8 slots, 1024-token
+# context in 16-token blocks
+SLOTS, BLOCK_SIZE, MAX_BLOCKS, NUM_BLOCKS = 8, 16, 64, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _qkv(sharding, seq=SEQ):
+    return tuple(
+        jax.ShapeDtypeStruct(
+            (BATCH, HEADS, seq, HEAD_DIM), jnp.bfloat16, sharding=sharding
+        )
+        for _ in range(3)
+    )
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_causal_compiles(one_chip, no_persistent_cache, direction):
+    """GPT-2's attention: causal, head-major layout, single 1024 block
+    (the fused single-tile forward and one-recompute backward)."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention_bnsh,
+    )
+
+    fwd = functools.partial(flash_attention_bnsh, causal=True)
+    if direction == "fwd":
+        _compile(fwd, *_qkv(one_chip))
+    else:
+        loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_multiblock_causal_compiles(
+    one_chip, no_persistent_cache, direction
+):
+    """Sequence 2048 = two 1024 blocks: the online-softmax forward and the
+    fused multi-block backward (the long-context path)."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention_bnsh,
+    )
+
+    fwd = functools.partial(flash_attention_bnsh, causal=True)
+    if direction == "fwd":
+        _compile(fwd, *_qkv(one_chip, seq=2048))
+    else:
+        loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip, seq=2048))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_kv_mask_compiles(one_chip, no_persistent_cache, direction):
+    """BERT-style: bidirectional with a key-padding mask, (B, S, N, H)."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+    )
+
+    shape = (BATCH, 512, HEADS, HEAD_DIM)
+    q, k, v = (
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        for _ in range(3)
+    )
+    mask = jax.ShapeDtypeStruct((BATCH, 512), jnp.bool_, sharding=one_chip)
+    fwd = lambda q, k, v, m: flash_attention(q, k, v, kv_mask=m)
+    if direction == "fwd":
+        _compile(fwd, q, k, v, mask)
+    else:
+        loss = lambda q, k, v, m: fwd(q, k, v, m).astype(jnp.float32).sum()
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, mask)
+
+
+@pytest.mark.parametrize(
+    "kv_heads,dtype",
+    [(12, jnp.float32), (12, jnp.bfloat16), (4, jnp.bfloat16)],
+    ids=["mha-f32", "mha-bf16", "gqa-bf16"],
+)
+def test_paged_decode_compiles(one_chip, no_persistent_cache, kv_heads, dtype):
+    """The fused paged flash-decode at GPT-2 width: f32 as serve.py builds
+    its model, bf16, and a GQA grouping."""
+    from distributed_pytorch_example_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode,
+    )
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = sds((NUM_BLOCKS, BLOCK_SIZE, kv_heads, HEAD_DIM), dtype)
+    _compile(
+        paged_flash_decode,
+        sds((SLOTS, HEADS, HEAD_DIM), dtype),
+        pool, pool,
+        sds((SLOTS, MAX_BLOCKS), jnp.int32),
+        sds((SLOTS,), jnp.int32),
+    )
+
+
+@pytest.fixture(scope="module")
+def data_mesh(topo):
+    """The mesh train.py builds for ``--mesh-data 4``: all six named axes,
+    five of them of size 1."""
+    from distributed_pytorch_example_tpu.runtime import MeshSpec, make_mesh
+
+    return make_mesh(MeshSpec(data=4), devices=list(topo.devices))
+
+
+def _data_manual(fn, mesh, out_spec):
+    """``fn`` as train/step.py runs the wire collectives: in a region that
+    is manual over ``data`` ONLY. The kernels' own ``_fully_manual`` wrap
+    has to cover the other axes, or the TPU lowering refuses them."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=P("data"), out_specs=out_spec,
+        axis_names={"data"}, check_vma=False,
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8], ids=["f32", "s8"])
+def test_ring_all_gather_compiles(data_mesh, no_persistent_cache, dtype):
+    """The async bidirectional-ring all-gather over four chips: the f32
+    payload and the s8 one the int8 wire gathers carry."""
+    from distributed_pytorch_example_tpu.ops.pallas import collectives
+
+    n = 768 * 768  # one GPT-2 attention projection
+    rows = collectives._half_rows(n // 4)
+    fn = _data_manual(
+        lambda x: collectives._fully_manual(
+            lambda x: collectives.all_gather_kernel(x, "data", 4, rows)
+        )(x),
+        data_mesh, P(),
+    )
+    x = jax.ShapeDtypeStruct(
+        (n,), dtype, sharding=NamedSharding(data_mesh, P("data"))
+    )
+    _compile(fn, x)
+
+
+def test_ring_reduce_scatter_compiles(data_mesh, no_persistent_cache):
+    """The ring reduce-scatter over four chips at one overlap bucket's
+    size (parallel/wire.py DEFAULT_BUCKET_BYTES = 4 MiB of f32)."""
+    from distributed_pytorch_example_tpu.ops.pallas import collectives
+
+    chunk = 4 * 1024 * 1024 // 4 // 4  # elements per destination chunk
+    fn = _data_manual(
+        lambda x: collectives._fully_manual(
+            lambda x: collectives.reduce_scatter_kernel(x[0], "data", 4)
+        )(x),
+        data_mesh, P("data"),
+    )
+    x = jax.ShapeDtypeStruct(
+        (4, 4 * chunk), jnp.float32,
+        sharding=NamedSharding(data_mesh, P("data")),
+    )
+    _compile(fn, x)
+
+
+def test_flash_compiles_on_a_four_chip_mesh(data_mesh, no_persistent_cache):
+    """``--mesh-data 4``: XLA cannot partition a Mosaic kernel, so the
+    flash call has to arrive wrapped in its own shard_map (batch over
+    ``data``) — from plain jit under the mesh, as plain data parallelism
+    traces it."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention_bnsh,
+    )
+
+    sharding = NamedSharding(data_mesh, P("data"))
+    q, k, v = (
+        jax.ShapeDtypeStruct(
+            (4 * BATCH, HEADS, SEQ, HEAD_DIM), jnp.bfloat16, sharding=sharding
+        )
+        for _ in range(3)
+    )
+    with data_mesh:
+        _compile(functools.partial(flash_attention_bnsh, causal=True), q, k, v)

@@ -361,6 +361,9 @@ def test_llama_moe_trains_under_expert_mesh(devices):
     assert "moe_dropped_fraction" in metrics
 
 
+# slow (PR 22): ~100 s on the CPU mesh; SP x EP stays in tier-1 through
+# dryrun config 14 (data x sequence x expert runs a full step)
+@pytest.mark.slow
 def test_sp_ep_matches_dense_mesh(devices):
     """SP x EP without a pipeline: ring attention over the sequence axis
     + expert-parallel MoE MLPs in one program (the per-layer path — ring

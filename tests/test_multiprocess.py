@@ -7,6 +7,10 @@ a cluster"): two OS processes rendezvous through ``jax.distributed``
 batch sharded across processes and params FSDP-sharded across processes —
 exercising the real cross-process collective, metric-agreement, and
 gathered-checkpoint paths that the fake single-process 8-device mesh cannot.
+
+CPU-only by design, and not a chip command: the pytest parent has already
+initialized JAX, and a chip belongs to one process at a time — so each
+worker is held to the CPU platform through its environment.
 """
 
 import json
@@ -40,6 +44,7 @@ def test_two_process_training(tmp_path):
             "COORDINATOR_ADDRESS": f"127.0.0.1:{port}",
             "DPX_TEST_CKPT_DIR": str(tmp_path),
             "PYTHONPATH": repo_root + os.pathsep + os.environ.get("PYTHONPATH", ""),
+            "JAX_PLATFORMS": "cpu",
         }
         env.pop("XLA_FLAGS", None)  # worker sets its own device count
         procs.append(

@@ -40,7 +40,6 @@ from distributed_pytorch_example_tpu.parallel.wire import (
     plan_buckets,
     sync_grads,
 )
-from distributed_pytorch_example_tpu.runtime import jax_compat
 from distributed_pytorch_example_tpu.telemetry.overlap import (
     scheduled_overlap,
 )
@@ -73,9 +72,9 @@ def _batch(partitioner, n=16, seq=16, seed=0):
 
 
 def _smap(mesh, fn, in_specs, out_specs):
-    return jax_compat.shard_map(
-        fn, mesh, in_specs=in_specs, out_specs=out_specs,
-        axis_names={"data"},
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 

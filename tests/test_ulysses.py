@@ -14,7 +14,6 @@ from distributed_pytorch_example_tpu.ops.ulysses import (
     ulysses_attention_sharded,
 )
 from distributed_pytorch_example_tpu.runtime import MeshSpec, make_mesh
-from distributed_pytorch_example_tpu.runtime.jax_compat import shard_map as _shard_map
 
 
 def make_qkv(batch=2, seq=256, heads=4, head_dim=32, seed=0):
@@ -177,7 +176,7 @@ def test_gqa_grouped_exchange_layout_and_bytes(devices):
     rng = np.random.default_rng(11)
     k = jnp.asarray(rng.standard_normal((B, S, kv, H)), jnp.float32)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda x: _grouped_kv_exchange(x, "sequence", rep)[None],
         mesh=mesh,
         in_specs=P(None, "sequence", None, None),

@@ -46,7 +46,6 @@ from distributed_pytorch_example_tpu.parallel.wire import (
     wire_psum,
     wire_psum_scatter,
 )
-from distributed_pytorch_example_tpu.runtime import jax_compat
 from distributed_pytorch_example_tpu.train import checkpoint as ckpt_lib
 from distributed_pytorch_example_tpu.train.step import (
     build_train_step,
@@ -78,9 +77,9 @@ def _batch(partitioner, n=16, seq=16, seed=0):
 
 
 def _smap(mesh, fn, in_specs, out_specs):
-    return jax_compat.shard_map(
-        fn, mesh, in_specs=in_specs, out_specs=out_specs,
-        axis_names={"data"},
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 
@@ -331,6 +330,62 @@ def test_ring_entry_points_fall_back_exactly_on_cpu(mesh_1d):
         )(np.ascontiguousarray(x.reshape(8, 256)))
     np.testing.assert_array_equal(np.asarray(ag), np.asarray(ag_ref))
     np.testing.assert_array_equal(np.asarray(rs), np.asarray(rs_ref))
+
+
+@pytest.mark.parametrize("span", [2, 4])
+@pytest.mark.parametrize(
+    "chunk", [2 * 128 * 8, 1000, 2 * 128 * 1100],
+    ids=["one-tile", "padded", "three-tiles"],
+)
+def test_ring_reduce_scatter_kernel_interpreted(devices, span, chunk):
+    """The ring reduce-scatter KERNEL (remote DMAs, semaphores, the row-
+    tile grid and its cross-step double-buffer slots) on the CPU mesh
+    under the Pallas TPU interpreter, vs ``lax.psum_scatter``: a
+    lane-aligned one-tile chunk, one that needs zero padding, and one
+    that spans three grid steps."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(devices[:span]), ("data",))
+    x = np.random.default_rng(5).standard_normal(
+        (span, span * chunk)
+    ).astype(np.float32)
+
+    def run(fn):
+        return np.asarray(jax.jit(jax.shard_map(
+            lambda v: fn(v[0])[None], mesh=mesh, in_specs=P("data"),
+            out_specs=P("data"), check_vma=False,
+        ))(x))
+
+    got = run(lambda v: ring.reduce_scatter_kernel(
+        v, "data", span, interpret=pltpu.InterpretParams()
+    ))
+    ref = run(lambda v: lax.psum_scatter(
+        v, "data", scatter_dimension=0, tiled=True
+    ))
+    # f32 adds in ring order vs XLA's order: tight, not bit-exact
+    np.testing.assert_allclose(got, ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.int8])
+def test_ring_all_gather_kernel_interpreted(devices, dtype):
+    """The ring all-gather KERNEL under the Pallas TPU interpreter on four
+    CPU devices: moving bytes is exact, for the f32 payload and the s8 one
+    of the int8 wire."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.sharding import Mesh
+
+    span, n = 4, 2 * 128 * 37
+    mesh = Mesh(np.array(devices[:span]), ("data",))
+    x = (np.random.default_rng(6).standard_normal(span * n) * 50).astype(dtype)
+    rows = ring._half_rows(n)
+    got = jax.jit(jax.shard_map(
+        lambda v: ring.all_gather_kernel(
+            v, "data", span, rows, interpret=pltpu.InterpretParams()
+        ),
+        mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False,
+    ))(x)
+    np.testing.assert_array_equal(np.asarray(got), x)
 
 
 def test_ring_kernel_numerics_on_tpu(mesh_1d):

@@ -157,13 +157,20 @@ def pick_auto_plan(args, parser, model, task, train_ds, global_batch):
     return mesh, best.plan.lower(mesh=mesh), best
 
 
-def main():
+def main(argv=None, devices=None):
+    """Run the CLI; returns the fitted Trainer.
+
+    ``argv`` defaults to ``sys.argv[1:]``. ``devices`` (no CLI flag)
+    restricts the mesh to a subset of ``jax.devices()`` — chip_smoke.py's
+    one-device reference run inside a four-chip process.
+    """
     parser = argparse.ArgumentParser(description=__doc__)
     dpx.utils.add_reference_args(parser)
     dpx.utils.add_framework_args(parser)
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     dpx.runtime.setup_logging()
+    dpx.runtime.enable_compile_cache()
     if args.chaos:
         # install BEFORE initialize(): rendezvous-flake faults must see the
         # plan; equivalent to launching with DPX_CHAOS=<value>
@@ -186,13 +193,14 @@ def main():
             sequence=args.mesh_sequence,
             expert=args.mesh_expert,
             pipe=args.mesh_pipe,
-        )
+        ),
+        devices=devices,
     )
     dp_size = dpx.runtime.mesh.data_parallel_size(mesh)
     logger.info(
         "Starting distributed training with %d processes, %d devices, mesh %s",
         jax.process_count(),
-        len(jax.devices()),
+        mesh.size,
         dict(mesh.shape),
     )
     logger.info(
@@ -459,6 +467,7 @@ def main():
         dpx.runtime.shutdown()
         sys.exit(1)
     dpx.runtime.shutdown()
+    return trainer
 
 
 if __name__ == "__main__":
