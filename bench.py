@@ -683,26 +683,6 @@ def run_model(name: str, args) -> dict:
                 args.shard_cache_mb, mesh, elapsed / args.steps
             )
 
-        # graft-lens overlap accounting (post-timing probe, ROADMAP 5(c)):
-        # a short XLA trace of the SAME compiled step, split into
-        # collective vs compute self time — overlap_frac is the fraction
-        # of collective time hidden behind compute. measure_overlap itself
-        # returns None when the profiler or the trace conversion is
-        # unavailable (e.g. plain CPU runs).
-        import tempfile
-
-        from distributed_pytorch_example_tpu.telemetry import (
-            measure_overlap,
-        )
-
-        def _overlap_steps(n, _s=[state]):
-            for _ in range(n):
-                _s[0], m = step(_s[0], batch)
-            float(m["loss"])  # value fetch fences the dispatch chain
-
-        with tempfile.TemporaryDirectory() as td:
-            overlap_report = measure_overlap(_overlap_steps, td)
-
     samples_per_sec = global_batch * args.steps / elapsed
     unit_kind, baseline = BASELINES[name]
     if unit_kind == "tokens":
@@ -779,21 +759,10 @@ def run_model(name: str, args) -> dict:
             **({"auto_mesh": picked_plan} if picked_plan else {}),
         },
     }
-    # measured comm/compute overlap (None = probe unavailable); the
-    # per-step split rides along when the probe ran
-    result["overlap_frac"] = (
-        overlap_report["overlap_frac"] if overlap_report else None
-    )
-    if overlap_report is not None:
-        result["overlap"] = {
-            k: (round(v, 3) if isinstance(v, float) else v)
-            for k, v in overlap_report.items()
-            if k != "overlap_frac"
-        }
     # scheduler-level overlap estimate from the static bucket plan
-    # (telemetry/overlap.py scheduled_overlap) — the CI-gateable stand-in
-    # for overlap_frac on CPU where the HLO probe reports null; non-None
-    # only when --overlap-buckets armed the bucketed sync
+    # (telemetry/overlap.py scheduled_overlap), gateable on the CPU mesh;
+    # non-None only when --overlap-buckets armed the bucketed sync. What a
+    # chip measured is the benchmark's collective_exposed_share.
     result["overlap_frac_scheduled"] = (
         trainer.overlap_report["overlap_frac_scheduled"]
         if trainer.overlap_report else None

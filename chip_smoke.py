@@ -113,30 +113,13 @@ class LoopLog(logging.Handler):
             self.loaded.append(msg)
 
 
-class CompileLog:
-    """JAX's own compile events: persistent-cache hits and misses and the
-    seconds spent in backend compiles."""
+def compile_snapshot():
+    """(persistent-cache hits, misses, seconds in backend compiles) so far,
+    from the program's own compile log (telemetry/compilelog.py)."""
+    from distributed_pytorch_example_tpu.telemetry import compilelog
 
-    def __init__(self):
-        from jax import monitoring
-
-        self.hits = self.misses = 0
-        self.compile_s = 0.0
-        monitoring.register_event_listener(self._event)
-        monitoring.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def _duration(self, name, secs, **_):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def snapshot(self):
-        return self.hits, self.misses, self.compile_s
+    totals = compilelog.totals()
+    return totals["cache_hits"], totals["cache_misses"], totals["compile_s"]
 
 
 def run_train(argv, devices=None):
@@ -147,13 +130,13 @@ def run_train(argv, devices=None):
     log = LoopLog()
     loop_logger = logging.getLogger("distributed_pytorch_example_tpu.train")
     loop_logger.addHandler(log)
-    before = COMPILES.snapshot()
+    before = compile_snapshot()
     t0 = time.time()
     try:
         trainer = train.main(argv, devices=devices)
     finally:
         loop_logger.removeHandler(log)
-    after = COMPILES.snapshot()
+    after = compile_snapshot()
     check(bool(log.losses), f"train.main({' '.join(argv[-6:])}) logged steps")
     check(
         all(math.isfinite(x) for x in log.losses),
@@ -497,9 +480,6 @@ def main() -> int:
         f"native C++ library built from dpxnative.cpp and loaded: "
         f"{getattr(binding, '_SO', None)}",
     )
-    global COMPILES
-    COMPILES = CompileLog()
-
     shutil.rmtree(WORKDIR, ignore_errors=True)
     try:
         if args.chips == 4:
@@ -516,8 +496,6 @@ def main() -> int:
     }), flush=True)
     return 0
 
-
-COMPILES = None
 
 if __name__ == "__main__":
     sys.exit(main())

@@ -27,6 +27,7 @@ XLA never recompiles; ``drop_last=True`` drops it instead.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Iterator, Optional, Sequence
 
 import numpy as np
@@ -91,9 +92,10 @@ class DeviceLoader:
         self.drop_last = drop_last
         self.prefetch = prefetch
         # graft-scope hook: Trainer.fit attaches its Telemetry scope here so
-        # host->device transfers emit "h2d" trace spans (the prefetch
-        # thread's track in the trace) and consumer-side queue waits land
-        # in the per-boundary data_stall_ms counter; None = no tracing
+        # batch assembly and host->device transfers emit "assemble" and
+        # "h2d" spans (the prefetch thread's track in the trace) and
+        # consumer-side queue waits land in the per-boundary data_stall_ms
+        # counter; None = no tracing
         self.telemetry = None
         # graft-intake counters, accumulated across iterations (read by
         # the bench input-plane probe and operators): consumer stalls,
@@ -131,13 +133,20 @@ class DeviceLoader:
             indices = np.concatenate([indices, indices[: n - len(indices)]])
         return indices
 
+    def _span(self, name: str):
+        scope = self.telemetry
+        if scope is None:
+            return contextlib.nullcontext()
+        return scope.span(name)
+
     def _assemble(self, step: int, indices: np.ndarray) -> Dict[str, np.ndarray]:
         """Host batch for one step — a pure function of (epoch, step), the
         property that makes supervised-worker restart exact."""
         lo = step * self.local_batch_size
-        return _get_batch(
-            self.dataset, indices[lo : lo + self.local_batch_size]
-        )
+        with self._span("assemble"):
+            return _get_batch(
+                self.dataset, indices[lo : lo + self.local_batch_size]
+            )
 
     def _host_batches(
         self, start_step: int = 0
@@ -147,15 +156,9 @@ class DeviceLoader:
             yield self._assemble(step, indices)
 
     def _to_device(self, host_batch: Dict[str, np.ndarray]) -> Dict[str, Any]:
-        import contextlib
-
         import jax
 
-        scope = self.telemetry
-        span = scope.span("h2d") if scope is not None else (
-            contextlib.nullcontext()
-        )
-        with span:
+        with self._span("h2d"):
             if self._sharding is not None:
                 return {
                     k: jax.make_array_from_process_local_data(

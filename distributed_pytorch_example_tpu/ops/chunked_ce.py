@@ -95,6 +95,10 @@ def _chunked_xent(x, embedding, bias, targets, block_size, dtype, serial):
     return loss, argmax
 
 
+# the scope sits inside the custom VJP's own functions so that the primal,
+# the forward residual pass and the backward ops all carry it in their op
+# metadata (the device trace's chunked_ce_device_share reads it)
+@jax.named_scope("chunked_ce")
 def _forward(x, embedding, bias, targets, block_size, dtype, serial):
     n = x.shape[0]
     vocab = embedding.shape[0]
@@ -139,6 +143,7 @@ def _fwd(x, embedding, bias, targets, block_size, dtype, serial):
     return (loss, argmax), (x, embedding, bias, targets, lse)
 
 
+@jax.named_scope("chunked_ce")
 def _bwd(block_size, dtype, serial, res, g):
     x, embedding, bias, targets, lse = res
     g_loss = g[0].astype(jnp.float32)  # argmax output is int: float0, ignored

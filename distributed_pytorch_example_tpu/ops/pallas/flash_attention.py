@@ -219,6 +219,7 @@ def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
             block_q=block_q, block_k=block_k, has_mask=has_mask,
             folded=folded,
         ),
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -260,6 +261,7 @@ def _fwd_single(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
             _fwd_single_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
         ),
+        name="flash_fwd_single",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -645,6 +647,7 @@ def _bwd_single(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
             _bwd_single_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
         ),
+        name="flash_bwd_single",
         grid=grid,
         in_specs=in_specs,
         out_specs=[qspec, kspec_out, kspec_out],
@@ -717,6 +720,7 @@ def _bwd_split(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
             _dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
         ),
+        name="flash_bwd_dq",
         grid=(batch, heads, seq_q // block_q, seq_k // block_k),
         in_specs=in_specs,
         out_specs=qspec,
@@ -735,6 +739,7 @@ def _bwd_split(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
             _dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
         ),
+        name="flash_bwd_dkv",
         grid=(batch, heads, seq_k // block_k, seq_q // block_q),
         in_specs=in_specs_t,
         out_specs=[kspec_out, kspec_out],
@@ -834,6 +839,7 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
             folded=folded,
         ),
+        name="flash_bwd_fused",
         grid=grid,
         in_specs=in_specs_t,
         out_specs=[qspec_t, kspec_out, kspec_out],

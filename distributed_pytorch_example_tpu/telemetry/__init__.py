@@ -17,9 +17,53 @@ graft-scope rebuilds that surface TPU-first around four pillars:
   times exchanged via ``process_allgather`` at log boundaries, emitting
   max/median skew and flagging slow hosts (gracefully absent at world
   size 1);
-- **span tracing** (:mod:`~.trace`): ``telemetry.span("data_load")`` etc.
-  streamed as Chrome trace-event JSON (load in Perfetto / chrome://tracing)
-  next to ``metrics.jsonl``.
+- **span tracing** (:mod:`~.trace`): every span the program opens goes
+  through ONE call, ``Telemetry.span(name)`` (module-level
+  ``trace.span(name)`` before ``fit`` has built its scope), which (1) opens
+  a ``jax.profiler.TraceAnnotation`` of the same name — a
+  ``StepTraceAnnotation("train_step", step_num=...)`` for the per-step
+  span — so that whenever a profiler session runs (``--profile-dir``, an
+  auto-armed window, the benchmark's ``--trace 1``) the span lies in the
+  ``.xplane.pb`` on the device planes' clock and a device gap can be laid
+  against it; (2) appends a :class:`~.trace.Span` (name, start_ns, end_ns,
+  thread, id, parent, root, step) to one bounded in-memory record,
+  ``telemetry.trace.recorded()`` (``clear()`` for tests); (3) streams the
+  Chrome trace-event JSON (Perfetto / chrome://tracing) next to
+  ``metrics.jsonl`` when a trace file is configured. With
+  ``--no-telemetry`` all three are off. The span names:
+
+  ====================================  =====================================
+  ``main_args`` ``main_runtime``        ``train.py::main`` (distributed init,
+  ``main_data`` ``main_model``          mesh and compile cache; datasets;
+  ``main_trainer``                      model + partitioner; loaders,
+                                        optimizer, Trainer)
+  ``init_state``                        ``Trainer.init``
+  ``fit`` > ``fit_open``,               ``Trainer.fit``: before the epoch
+  ``train_epoch``, ``fit_close``        loop / one epoch / record + teardown
+  ``data_load`` (wait)                  the training thread's ``next()`` on
+                                        the loader's prefetch queue
+  ``train_step`` > ``aot_lookup``       one loop body (step annotation);
+  (> ``record_compile``), ``step``,     the executable's lookup, the
+  ``metrics_add``, ``saver_check``      dispatch, the running metric sums,
+                                        the background saver's check
+  ``clock_fence`` ``boundary_fetch``    the four places the training thread
+  ``log_fetch`` ``bad_step_drain``      blocks on the device: step clock's
+  (wait)                                fence (every 8th step), the boundary
+                                        scalars, the log line's loss, the
+                                        bad-step flags (every 10th)
+  ``epoch_drain`` (wait)                the epoch's last drain + metric
+                                        means: waits for every step
+  ``assemble`` ``h2d``                  the loader's prefetch thread
+  ``eval`` ``checkpoint``               validation dispatch, saves
+  ``compile:<fun_name>``                the compile log, below
+  ====================================  =====================================
+
+- **compile log** (:mod:`~.compilelog`): one ``jax.monitoring`` listener,
+  installed when this package is imported, records a span
+  ``compile:<fun_name>`` per program built or fetched, with the seconds of
+  tracing, lowering and backend compile (or persistent-cache load) and
+  whether the cache hit; ``Telemetry.close()``'s summary lists the
+  programs of the run as ``compiles_during_fit``.
 
 graft-lens extends the same substrate end-to-end across serving and the
 wire collectives:
@@ -29,9 +73,10 @@ wire collectives:
   spans on per-replica Perfetto pids, queue-depth/KV-occupancy counter
   tracks, and bounded p50/p99 windows for TTFT/TPOT/queue-wait/journal
   lag surfaced in ``serve.py``'s JSON line;
-- **overlap accounting** (:mod:`~.overlap`): a short XLA trace split
-  into collective vs compute self time → measured ``overlap_frac`` in
-  ``bench.py``'s JSON line (ROADMAP 5(c));
+- **scheduled overlap** (:mod:`~.overlap`): the bucket plan's static
+  estimate of how much gradient-sync wire time can hide behind compute
+  (the measured counterpart is the benchmark's
+  ``collective_exposed_share``);
 - **serve-side self-arming sentinels** (:mod:`~.sentinels`
   ``ServeSentinels``): TPOT p99 regression, straggler replica, KV-pool
   pressure — auto-arm the XLA profiler and stamp ``trigger`` events.
@@ -54,11 +99,7 @@ from distributed_pytorch_example_tpu.telemetry.scope import (  # noqa: F401
     Telemetry,
     TelemetryConfig,
 )
-from distributed_pytorch_example_tpu.telemetry.overlap import (  # noqa: F401
-    measure_overlap,
-    overlap_frac_from_times,
-    split_trace_times,
-)
+from distributed_pytorch_example_tpu.telemetry import compilelog
 from distributed_pytorch_example_tpu.telemetry.sentinels import (  # noqa: F401
     SENTINEL_KEYS,
     SERVE_TRIGGER_KINDS,
@@ -73,3 +114,5 @@ from distributed_pytorch_example_tpu.telemetry.trace import (  # noqa: F401
     PrefixedTrace,
     TraceWriter,
 )
+
+compilelog.install()

@@ -12,7 +12,6 @@ unconfigured, and the per-step hot path is a counter compare plus (every
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Callable, Dict, Optional
@@ -23,11 +22,11 @@ from distributed_pytorch_example_tpu.telemetry.steptime import (
     StepClock,
     exchange_step_times,
 )
+from distributed_pytorch_example_tpu.telemetry import compilelog
+from distributed_pytorch_example_tpu.telemetry import trace as trace_lib
 from distributed_pytorch_example_tpu.telemetry.trace import TraceWriter
 
 logger = get_logger(__name__)
-
-_NULL_CTX = contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,8 +62,13 @@ class Telemetry:
         profiler=None,
         process_index: int = 0,
         fallback_every: int = 10,
+        root: Optional[int] = None,
     ):
         self.config = config
+        # the id of the `fit` span this scope lives under: spans opened on
+        # another thread (the loader's prefetch thread) take it as root
+        self.root = root
+        self._programs_at_open = compilelog.totals()["programs"]
         self.writer = writer
         self.profiler = profiler
         self.costs = CostRegistry()
@@ -94,11 +98,11 @@ class Telemetry:
 
     # -- spans ------------------------------------------------------------
 
-    def span(self, name: str):
-        """Context manager recording one trace-event span (no-op w/o file)."""
-        if self.trace is None:
-            return _NULL_CTX
-        return self.trace.span(name)
+    def span(self, name: str, step: Optional[int] = None):
+        """The one span call (``telemetry/trace.py::span``): a profiler
+        annotation on the device trace's clock, a row of the in-memory
+        record, and the Chrome event when a trace file is configured."""
+        return trace_lib.span(name, step, self.trace, self.root)
 
     # -- compiles ---------------------------------------------------------
 
@@ -184,9 +188,10 @@ class Telemetry:
             fetch_scalars,
         )
 
-        scalars = fetch_scalars(metrics, keys=(
-            "loss", "grad_norm", "param_norm", "nonfinite_grads",
-        ))
+        with self.span("boundary_fetch"):
+            scalars = fetch_scalars(metrics, keys=(
+                "loss", "grad_norm", "param_norm", "nonfinite_grads",
+            ))
         straggler = exchange_step_times(
             self.clock.step_time_ms, self.config.skew_threshold
         )
@@ -268,6 +273,12 @@ class Telemetry:
             "straggler": dict(self.last_straggler),
             "overhead_s": round(self.overhead_s, 6),
             "events": list(self.events),
+            # every program built or fetched while this scope was open (the
+            # compile log's names): a step that compiled again mid-run
+            # shows here, not as a mystery step time
+            "compiles_during_fit": compilelog.names_since(
+                self._programs_at_open
+            ),
             "compiles": {
                 tag: {
                     "flops_per_step_per_device": rec.get(

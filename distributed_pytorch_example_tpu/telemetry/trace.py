@@ -22,21 +22,159 @@ graft-lens additions:
   events with an overriding ``pid`` and announces a ``process_name``
   metadata row, so each fleet replica renders as its own Perfetto
   process lane inside the ONE shared trace file.
+
+The program's own spans (:func:`span`, which ``Telemetry.span`` calls) go
+three ways at once:
+
+- a ``jax.profiler.TraceAnnotation`` of the same name (a
+  ``StepTraceAnnotation`` with ``step_num`` where the span carries a step),
+  so that whenever a profiler session runs — ``--profile-dir``, an
+  auto-armed window, a benchmark's traced window — the span lies in the
+  ``.xplane.pb`` on the device planes' clock;
+- one bounded process-wide in-memory record, :func:`recorded`: a
+  :class:`Span` tuple per closed span with its thread, id, parent (the span
+  open on the same thread when this one opened), root (the outermost such
+  span: one ``fit`` call's spans share the ``fit`` span's id) and step;
+- the Chrome event, when a :class:`TraceWriter` is handed in.
 """
 
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
-from typing import List, Optional, Union
+from typing import List, NamedTuple, Optional, Union
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 def _now_us() -> int:
     return time.perf_counter_ns() // 1000
+
+
+# -- the program's own spans: one call, one clock, one record ---------------
+
+# the oldest spans are dropped beyond this many (about 10 a train step)
+RECORD_LIMIT = 1 << 16
+
+
+class Span(NamedTuple):
+    """One closed span of the in-memory record. Times are
+    ``time.perf_counter_ns``; ``parent`` is 0 for a span opened on an empty
+    stack; ``args`` carries a compile-log span's seconds, else None."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: int
+    id: int
+    parent: int
+    root: int
+    step: Optional[int]
+    args: Optional[dict]
+
+
+_record: collections.deque = collections.deque(maxlen=RECORD_LIMIT)
+_record_lock = threading.Lock()
+_ids = itertools.count(1)
+_open = threading.local()  # .stack: [(id, root)] of this thread's open spans
+
+
+def recorded() -> List[Span]:
+    """The spans closed so far in this process, oldest first."""
+    with _record_lock:
+        return list(_record)
+
+
+def clear() -> None:
+    with _record_lock:
+        _record.clear()
+
+
+def _append(row: Span) -> None:
+    with _record_lock:
+        _record.append(row)
+
+
+def _stack() -> list:
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
+def add(name: str, start_ns: int, end_ns: int,
+        args: Optional[dict] = None) -> None:
+    """Record a span that was timed elsewhere (the compile log's), as a
+    child of whatever span is open on this thread."""
+    stack = _stack()
+    ident = next(_ids)
+    parent, root = stack[-1] if stack else (0, ident)
+    _append(Span(
+        name, start_ns, end_ns, threading.get_ident(), ident, parent, root,
+        None, args,
+    ))
+
+
+class span:
+    """``with span(name):`` — profiler annotation, in-memory record and
+    (with ``writer``) Chrome event, as the module docstring says.
+
+    ``root`` adopts a span opened on an empty stack into another thread's
+    tree (the loader's prefetch thread under its ``fit``)."""
+
+    __slots__ = ("name", "step", "writer", "id", "parent", "root", "t0",
+                 "_annotation")
+
+    def __init__(self, name: str, step: Optional[int] = None,
+                 writer: Optional["TraceWriter"] = None,
+                 root: Optional[int] = None):
+        self.name, self.step, self.writer, self.root = name, step, writer, root
+        self.id = next(_ids)
+
+    def __enter__(self):
+        stack = _stack()
+        if stack:
+            self.parent, self.root = stack[-1]
+        else:
+            self.parent = 0
+            if self.root is None:
+                self.root = self.id
+        stack.append((self.id, self.root))
+        self._annotation = (
+            TraceAnnotation(self.name) if self.step is None
+            else StepTraceAnnotation(self.name, step_num=self.step)
+        )
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        _stack().pop()
+        _append(Span(
+            self.name, self.t0, t1, threading.get_ident(), self.id,
+            self.parent, self.root, self.step, None,
+        ))
+        if self.writer is not None:
+            self.writer.add_complete(
+                self.name, self.t0 // 1000, (t1 - self.t0) // 1000
+            )
+        return False
+
+
+_NULL_CTX = contextlib.nullcontext()
+
+
+def no_span(name: str, step: Optional[int] = None):
+    """What stands in for :func:`span` where telemetry is off."""
+    return _NULL_CTX
 
 
 class TraceWriter:

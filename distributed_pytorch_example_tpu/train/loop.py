@@ -50,15 +50,17 @@ from distributed_pytorch_example_tpu.telemetry import (
     Telemetry,
     TelemetryConfig,
 )
+from distributed_pytorch_example_tpu.telemetry import trace as span_lib
 
 logger = get_logger(__name__)
 
 
-def _span(scope: Optional[Telemetry], name: str):
-    """A graft-scope trace span, or a no-op when telemetry is off."""
+def _span(scope: Optional[Telemetry], name: str, step: Optional[int] = None):
+    """A graft-scope span (telemetry/trace.py: profiler annotation,
+    in-memory record, Chrome event), or a no-op when telemetry is off."""
     if scope is None:
-        return contextlib.nullcontext()
-    return scope.span(name)
+        return span_lib.no_span(name)
+    return scope.span(name, step)
 
 
 def _spanned_batches(iterator, scope: Optional[Telemetry]):
@@ -243,7 +245,18 @@ class Trainer:
 
     # -- state ------------------------------------------------------------
 
+    def _bare_span(self, name: str):
+        """A span outside ``fit``'s scope (``init``, the head and tail of
+        ``fit``): the module-level form of ``Telemetry.span``."""
+        if self._telemetry_cfg is None:
+            return span_lib.no_span(name)
+        return span_lib.span(name)
+
     def init(self, sample_inputs: Any) -> TrainState:
+        with self._bare_span("init_state"):
+            return self._init(sample_inputs)
+
+    def _init(self, sample_inputs: Any) -> TrainState:
         with self._mesh_ctx():
             self.state, self.state_shardings = init_state(
                 self.model,
@@ -342,15 +355,17 @@ class Trainer:
         exe = self._compiled.get(key)
         if exe is None:
             exe = self.train_step.lower(self.state, batch).compile()
-            self.scope.record_compile("train_step", exe)
+            with self.scope.span("record_compile"):
+                self.scope.record_compile("train_step", exe)
             self._compiled[key] = exe
         elif (
             exe is not self.train_step
             and self.scope.costs.get("train_step") is None
         ):
             # a later fit() reuses the cached executable: re-register its
-            # cost record with the new run's scope (analysis is cheap)
-            self.scope.record_compile("train_step", exe)
+            # cost record with the new run's scope
+            with self.scope.span("record_compile"):
+                self.scope.record_compile("train_step", exe)
         return key, exe
 
     def _eval_executable(self, batch):
@@ -427,94 +442,102 @@ class Trainer:
         for batch_idx, batch in enumerate(
             _spanned_batches(iter(it), scope), start=start_batch
         ):
-            if self._profiler is not None:
-                self._profiler.step(self._global_step)
-            # deterministic fault injection (no-op without a chaos plan):
-            # the poisoned batch keeps its sharding, so the same compiled
-            # step executes it — the bad-step cond handles the rest
-            batch = chaos.corrupt_batch(batch, self._global_step)
-            with self._mesh_ctx():
-                step_key, step_fn = self._train_executable(batch)
-                with _span(scope, "step"):
-                    self.state, metrics = self._dispatch(
-                        step_key, step_fn, self.train_step,
-                        self.state, batch,
-                    )
-            self._global_step += 1
-            acc.append(metrics)
-            if "bad_step" in metrics:
-                # device scalar, no sync — summed against the budget at
-                # the log boundary below
-                self._pending_bad.append(metrics["bad_step"])
-            # a FAILED background save surfaces here, within one step of
-            # the fault, instead of minutes later at fit's final wait()
-            self._saver.check()
-            # kill-a-slice injection site (graft-elastic): a "kill" fault
-            # at="step" SIGKILLs on the nth step BOUNDARY — the in-flight
-            # step finished, saves for it may be mid-flight — modeling a
-            # preempted slice; no-op without a chaos plan
-            chaos.crash_point("step")
-            if scope is not None:
-                # rate-limited clock tick + (at boundaries) the one-fetch
-                # health check, straggler exchange, and per-N-step record.
-                # The fence fetches a live VALUE: a device->host transfer
-                # of a step output cannot complete before the step has.
-                scope.on_step(
-                    self._global_step, metrics,
-                    fence=lambda m=metrics: float(m["loss"]),
-                )
-            if batch_idx % self.log_every == 0 and dist.is_coordinator():
-                logger.info(
-                    "Epoch %d, Batch %d/%d, Loss: %.4f",
-                    epoch,
-                    batch_idx,
-                    num_batches,
-                    float(metrics["loss"]),
-                )
-            if batch_idx % self.log_every == 0:
-                # EVERY process, same cadence (pure function of the batch
-                # index): budget decisions — rollback, hard-fail — must be
-                # taken identically on all hosts
-                self._drain_bad_steps()
-            if (
-                self.save_every_steps
-                and self.checkpoint_dir
-                and (batch_idx + 1) % self.save_every_steps == 0
-                and batch_idx + 1 < num_batches  # epoch-end save follows
-            ):
-                self._save_mid_epoch(loader, epoch, batch_idx, metrics)
-            if self._preempt_requested:
-                # graceful preemption (SIGTERM): the in-flight step has
-                # finished — write `latest` with the cursor, drain the
-                # saver, and unwind. The launcher still treats the exit as
-                # orchestrator teardown (rc 143, no restart); the NEXT
-                # launch resumes from this exact batch.
-                #
-                # Multi-process scope: signal delivery is NOT synchronized
-                # across hosts, so ranks may be at different steps — a save
-                # here would mix per-rank states (and its begin-save
-                # barrier would mismatch in-flight train-step collectives).
-                # Multi-process jobs get bounded loss from the
-                # DETERMINISTICALLY coordinated --save-every-steps saves
-                # (every rank saves at the same batch index) and exit
-                # cleanly here without an extra save.
-                if self.checkpoint_dir and jax.process_count() == 1:
-                    self._save_mid_epoch(loader, epoch, batch_idx, metrics)
-                    self._saver.wait()
+            with _span(scope, "train_step", self._global_step):
+                if self._profiler is not None:
+                    self._profiler.step(self._global_step)
+                # deterministic fault injection (no-op without a chaos plan):
+                # the poisoned batch keeps its sharding, so the same compiled
+                # step executes it — the bad-step cond handles the rest
+                batch = chaos.corrupt_batch(batch, self._global_step)
+                with self._mesh_ctx():
+                    with _span(scope, "aot_lookup"):
+                        step_key, step_fn = self._train_executable(batch)
+                    with _span(scope, "step"):
+                        self.state, metrics = self._dispatch(
+                            step_key, step_fn, self.train_step,
+                            self.state, batch,
+                        )
+                self._global_step += 1
+                with _span(scope, "metrics_add"):
+                    acc.append(metrics)
+                if "bad_step" in metrics:
+                    # device scalar, no sync — summed against the budget at
+                    # the log boundary below
+                    self._pending_bad.append(metrics["bad_step"])
+                # a FAILED background save surfaces here, within one step of
+                # the fault, instead of minutes later at fit's final wait()
+                with _span(scope, "saver_check"):
+                    self._saver.check()
+                # kill-a-slice injection site (graft-elastic): a "kill" fault
+                # at="step" SIGKILLs on the nth step BOUNDARY — the in-flight
+                # step finished, saves for it may be mid-flight — modeling a
+                # preempted slice; no-op without a chaos plan
+                chaos.crash_point("step")
+                if scope is not None:
+                    # rate-limited clock tick + (at boundaries) the one-fetch
+                    # health check, straggler exchange, and per-N-step record.
+                    # The fence fetches a live VALUE: a device->host transfer
+                    # of a step output cannot complete before the step has.
+                    # The clock calls it on the steps where it really blocks.
+                    def fence(m=metrics):
+                        with scope.span("clock_fence"):
+                            return float(m["loss"])
+
+                    scope.on_step(self._global_step, metrics, fence=fence)
+                if batch_idx % self.log_every == 0 and dist.is_coordinator():
+                    with _span(scope, "log_fetch"):
+                        loss = float(metrics["loss"])
                     logger.info(
-                        "Preemption checkpoint complete (epoch %d, batch "
-                        "%d)", epoch, batch_idx + 1,
+                        "Epoch %d, Batch %d/%d, Loss: %.4f",
+                        epoch, batch_idx, num_batches, loss,
                     )
-                elif self.checkpoint_dir:
-                    logger.warning(
-                        "SIGTERM on a multi-process job: skipping the "
-                        "uncoordinated preemption save; latest periodic "
-                        "checkpoint (--save-every-steps) is the resume "
-                        "point"
-                    )
-                raise PreemptionInterrupt(self._preempt_rc)
-        self._drain_bad_steps()  # epoch tail shorter than log_every
-        return acc.result()
+                if batch_idx % self.log_every == 0:
+                    # EVERY process, same cadence (pure function of the batch
+                    # index): budget decisions — rollback, hard-fail — must be
+                    # taken identically on all hosts
+                    self._drain_bad_steps()
+                if (
+                    self.save_every_steps
+                    and self.checkpoint_dir
+                    and (batch_idx + 1) % self.save_every_steps == 0
+                    and batch_idx + 1 < num_batches  # epoch-end save follows
+                ):
+                    self._save_mid_epoch(loader, epoch, batch_idx, metrics)
+                if self._preempt_requested:
+                    # graceful preemption (SIGTERM): the in-flight step has
+                    # finished — write `latest` with the cursor, drain the
+                    # saver, and unwind. The launcher still treats the exit as
+                    # orchestrator teardown (rc 143, no restart); the NEXT
+                    # launch resumes from this exact batch.
+                    #
+                    # Multi-process scope: signal delivery is NOT synchronized
+                    # across hosts, so ranks may be at different steps — a save
+                    # here would mix per-rank states (and its begin-save
+                    # barrier would mismatch in-flight train-step collectives).
+                    # Multi-process jobs get bounded loss from the
+                    # DETERMINISTICALLY coordinated --save-every-steps saves
+                    # (every rank saves at the same batch index) and exit
+                    # cleanly here without an extra save.
+                    if self.checkpoint_dir and jax.process_count() == 1:
+                        self._save_mid_epoch(loader, epoch, batch_idx, metrics)
+                        self._saver.wait()
+                        logger.info(
+                            "Preemption checkpoint complete (epoch %d, batch "
+                            "%d)", epoch, batch_idx + 1,
+                        )
+                    elif self.checkpoint_dir:
+                        logger.warning(
+                            "SIGTERM on a multi-process job: skipping the "
+                            "uncoordinated preemption save; latest periodic "
+                            "checkpoint (--save-every-steps) is the resume "
+                            "point"
+                        )
+                    raise PreemptionInterrupt(self._preempt_rc)
+        # waits for every dispatched step: the last drain (an epoch tail
+        # shorter than log_every) and the fetch of the running sums
+        with _span(scope, "epoch_drain"):
+            self._drain_bad_steps()
+            return acc.result()
 
     # -- bad-step budget (graft-armor) ------------------------------------
 
@@ -539,7 +562,8 @@ class Trainer:
         the batch index, so every process takes the same decision."""
         if not self._pending_bad:
             return
-        flags = jax.device_get(self._pending_bad)
+        with _span(self.scope, "bad_step_drain"):
+            flags = jax.device_get(self._pending_bad)
         self._pending_bad = []
         new = int(round(sum(float(f) for f in flags)))
         if new == 0:
@@ -655,6 +679,18 @@ class Trainer:
         epochs: int = 10,
         resume: Optional[str] = None,
     ) -> List[Dict[str, float]]:
+        # `fit` is the root of every span this call opens; `fit_open` is
+        # everything before the epoch loop (_fit closes it there, or the
+        # stack does when an exception unwinds first)
+        with self._bare_span("fit") as fit_span, \
+                contextlib.ExitStack() as opening:
+            opening.enter_context(self._bare_span("fit_open"))
+            return self._fit(
+                train_loader, val_loader, epochs, resume,
+                getattr(fit_span, "id", None), opening,
+            )
+
+    def _fit(self, train_loader, val_loader, epochs, resume, fit_id, opening):
         if self._telemetry_cfg is not None:
             # arm the input-plane event sink BEFORE anything touches the
             # loader (init's sample batch below can already quarantine a
@@ -700,6 +736,7 @@ class Trainer:
                 profiler=self._profiler,
                 process_index=dist.process_index(),
                 fallback_every=self.log_every,
+                root=fit_id,
             )
             # h2d spans from the loaders' transfer path (prefetch thread)
             for loader in (train_loader, val_loader):
@@ -793,52 +830,54 @@ class Trainer:
 
             prev_term = signal.signal(signal.SIGTERM, _on_signal)
             prev_int = signal.signal(signal.SIGINT, _on_signal)
+        opening.close()
         try:
             history, best_accuracy = self._epoch_loop(
                 train_loader, val_loader, start_epoch, epochs,
                 best_accuracy, writer, start_batch,
             )
         finally:
-            if prev_term is not None:
-                signal.signal(signal.SIGTERM, prev_term)
-            if prev_int is not None:
-                signal.signal(signal.SIGINT, prev_int)
-            # an exception mid-window must not leave a dangling active
-            # jax trace, an unflushed metrics file, or a half-queued save
-            intake.set_event_sink(None)  # armed at the top of fit
-            if self.scope is not None:
-                self.telemetry_summary = self.scope.close()
-                if self.wire_report is not None:
-                    self.telemetry_summary["wire"] = dict(self.wire_report)
-                if self.overlap_report is not None:
-                    self.telemetry_summary["overlap_scheduled"] = dict(
-                        self.overlap_report
+            with self._bare_span("fit_close"):
+                if prev_term is not None:
+                    signal.signal(signal.SIGTERM, prev_term)
+                if prev_int is not None:
+                    signal.signal(signal.SIGINT, prev_int)
+                # an exception mid-window must not leave a dangling active
+                # jax trace, an unflushed metrics file, or a half-queued save
+                intake.set_event_sink(None)  # armed at the top of fit
+                if self.scope is not None:
+                    self.telemetry_summary = self.scope.close()
+                    if self.wire_report is not None:
+                        self.telemetry_summary["wire"] = dict(self.wire_report)
+                    if self.overlap_report is not None:
+                        self.telemetry_summary["overlap_scheduled"] = dict(
+                            self.overlap_report
+                        )
+                    cache_stats = getattr(
+                        getattr(train_loader, "dataset", None),
+                        "cache_stats", None,
                     )
-                cache_stats = getattr(
-                    getattr(train_loader, "dataset", None),
-                    "cache_stats", None,
-                )
-                if cache_stats:
-                    self.telemetry_summary["shard_cache"] = dict(cache_stats)
-                for loader in (train_loader, val_loader):
-                    if loader is not None and hasattr(loader, "telemetry"):
-                        loader.telemetry = None
-                self.scope = None
-            if self._profiler is not None:
-                self._profiler.close()
-            writer.close()
-            if sys.exc_info()[1] is not None:
-                # already unwinding a training exception: a checkpoint-save
-                # failure must not replace it as the primary error
-                try:
+                    if cache_stats:
+                        self.telemetry_summary["shard_cache"] = dict(cache_stats)
+                    for loader in (train_loader, val_loader):
+                        if loader is not None and hasattr(loader, "telemetry"):
+                            loader.telemetry = None
+                    self.scope = None
+                if self._profiler is not None:
+                    self._profiler.close()
+                writer.close()
+                if sys.exc_info()[1] is not None:
+                    # already unwinding a training exception: a checkpoint-save
+                    # failure must not replace it as the primary error
+                    try:
+                        self._saver.wait()
+                    except Exception:
+                        logger.exception(
+                            "async checkpoint save failed while handling a "
+                            "training exception (training error follows)"
+                        )
+                else:
                     self._saver.wait()
-                except Exception:
-                    logger.exception(
-                        "async checkpoint save failed while handling a "
-                        "training exception (training error follows)"
-                    )
-            else:
-                self._saver.wait()
 
         total_time = time.time() - start_time
         if dist.is_coordinator():
@@ -861,10 +900,11 @@ class Trainer:
         self._best_accuracy = best_accuracy
         for epoch in range(start_epoch, epochs):
             epoch_start = time.time()
-            train_metrics = self.train_epoch(
-                train_loader, epoch,
-                start_batch=start_batch if epoch == start_epoch else 0,
-            )
+            with _span(self.scope, "train_epoch"):
+                train_metrics = self.train_epoch(
+                    train_loader, epoch,
+                    start_batch=start_batch if epoch == start_epoch else 0,
+                )
             train_time = time.time() - epoch_start
             val_metrics = self.validate(val_loader) if val_loader is not None else {}
             epoch_time = time.time() - epoch_start

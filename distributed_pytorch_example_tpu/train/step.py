@@ -397,8 +397,9 @@ def build_train_step(
             update_ok = nonfinite_count(grads) == 0
 
             def apply_update(grads, opt_state, params, ms, _old_ms):
-                updates, opt2 = optimizer.update(grads, opt_state, params)
-                return optax.apply_updates(params, updates), opt2, ms
+                with jax.named_scope("optimizer"):
+                    updates, opt2 = optimizer.update(grads, opt_state, params)
+                    return optax.apply_updates(params, updates), opt2, ms
 
             def skip_update(_grads, opt_state, params, _ms, old_ms):
                 return params, opt_state, old_ms
@@ -409,10 +410,11 @@ def build_train_step(
                 state.model_state,
             )
         else:
-            updates, new_opt_state = optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
         if zero1:
             # pin the ZeRO-1 layout: the sharded-gradient update must KEEP
             # the moments sharded (a propagation choice to replicate them

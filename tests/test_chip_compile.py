@@ -230,3 +230,52 @@ def test_flash_compiles_on_a_four_chip_mesh(data_mesh, no_persistent_cache):
     )
     with data_mesh:
         _compile(functools.partial(flash_attention_bnsh, causal=True), q, k, v)
+
+
+def _kernel_names(compiled):
+    """The HLO instruction names of the compiled program's Pallas calls,
+    without their numbers: ``pallas_call(name=...)`` under the transforms
+    around it (``jvp_flash_fwd_single_``)."""
+    return {
+        line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+        for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    }
+
+
+@pytest.mark.parametrize("where", ["one-chip", "dp4-mesh"])
+@pytest.mark.parametrize("seq", [SEQ, 2 * SEQ], ids=["single", "multiblock"])
+def test_flash_forward_and_backward_differ_by_name(
+    topo, one_chip, data_mesh, no_persistent_cache, where, seq
+):
+    """What the device trace names an op by is the instruction's name: the
+    forward and the backward kernel of one training step must be told
+    apart by it (``name=`` on each ``pallas_call``), on one chip and under
+    the shard_map that wraps the kernels on a mesh."""
+    import contextlib
+
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention_bnsh,
+    )
+
+    fwd = functools.partial(flash_attention_bnsh, causal=True)
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    if where == "one-chip":
+        args, ctx = _qkv(one_chip, seq=seq), contextlib.nullcontext()
+    else:
+        sharding = NamedSharding(data_mesh, P("data"))
+        args, ctx = tuple(
+            jax.ShapeDtypeStruct(
+                (4 * BATCH, HEADS, seq, HEAD_DIM), jnp.bfloat16,
+                sharding=sharding,
+            )
+            for _ in range(3)
+        ), data_mesh
+    with ctx:
+        names = _kernel_names(
+            _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
+        )
+    forward = {n for n in names if "flash_fwd" in n}
+    backward = {n for n in names if "flash_bwd" in n}
+    assert forward and backward, names
+    assert forward | backward == names and not forward & backward, names

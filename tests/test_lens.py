@@ -1,6 +1,5 @@
 """graft-lens: unified train+serve request tracing, rolling latency
-books, comm/compute overlap accounting, and serve-side self-arming
-sentinels.
+books, and serve-side self-arming sentinels.
 
 The load-bearing contracts pinned here:
 
@@ -10,8 +9,6 @@ The load-bearing contracts pinned here:
   distinct replica pids in ONE trace file;
 - ``ServeSentinels`` detectors fire at most once until ``disarm`` and
   drive the real ``StepProfiler.arm`` first-trigger-wins window;
-- overlap accounting math (``overlap_frac``) and its degrade-to-None
-  contract;
 - tracing-enabled steady state costs <= 5% over tracing-off (the
   graft-lens overhead acceptance bound).
 """
@@ -42,10 +39,7 @@ from distributed_pytorch_example_tpu.telemetry import (
     SERVE_TRIGGER_KINDS,
     ServeSentinels,
     TraceWriter,
-    overlap_frac_from_times,
-    split_trace_times,
 )
-from distributed_pytorch_example_tpu.telemetry import overlap as overlap_mod
 
 # same tiny GPT-2 as test_fleet.py: one jit cache serves both modules
 GPT2_KW = dict(vocab_size=61, max_len=32, model_dim=16, num_layers=1,
@@ -313,81 +307,6 @@ def test_serve_trigger_arms_real_profiler_first_trigger_wins(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# overlap accounting
-# ---------------------------------------------------------------------------
-
-
-def test_overlap_frac_math():
-    assert overlap_frac_from_times(100.0, 0.0, 100.0) is None
-    # nothing hidden: wall == compute + collective
-    assert overlap_frac_from_times(150.0, 50.0, 100.0) == 0.0
-    # fully hidden: wall == compute
-    assert overlap_frac_from_times(100.0, 50.0, 100.0) == 1.0
-    assert overlap_frac_from_times(125.0, 50.0, 100.0) == 0.5
-    # clamped against timer noise
-    assert overlap_frac_from_times(90.0, 50.0, 100.0) == 1.0
-    assert overlap_frac_from_times(500.0, 50.0, 100.0) == 0.0
-
-
-def test_is_collective_category_and_scope_fallback():
-    assert overlap_mod.is_collective("all-reduce")
-    assert overlap_mod.is_collective("AllGather")
-    assert overlap_mod.is_collective("reduce scatter")
-    assert overlap_mod.is_collective("collective-permute")
-    assert not overlap_mod.is_collective("convolution")
-    # category silent, named scope in the framework op name decides
-    assert overlap_mod.is_collective("", "jit(step)/wire_psum_scatter/...")
-    assert not overlap_mod.is_collective("", "jit(step)/einsum")
-
-
-def test_split_trace_times_degrades_to_none(tmp_path):
-    assert split_trace_times(str(tmp_path / "nope")) is None
-
-
-def test_split_trace_times_synthetic_rows(monkeypatch):
-    rows = [
-        ("jit(step)/wire_psum_scatter/reduce-scatter", "all-reduce", 40.0),
-        ("jit(step)/wire_all_gather/ag", "all-gather", 10.0),
-        ("jit(step)/transformer/einsum", "convolution fusion", 150.0),
-        ("jit(step)/ring_all_gather/ppermute", "collective-permute", 6.0),
-    ]
-    monkeypatch.setattr(overlap_mod, "_hlo_stats_rows", lambda d: rows)
-    split = split_trace_times("ignored")
-    assert split["collective_us"] == pytest.approx(56.0)
-    assert split["compute_us"] == pytest.approx(150.0)
-    assert split["by_scope"] == {
-        "wire_psum_scatter": 40.0, "wire_all_gather": 10.0,
-        "ring_all_gather": 6.0,
-    }
-
-
-def test_measure_overlap_per_step_accounting(monkeypatch, tmp_path):
-    monkeypatch.setattr(
-        jax.profiler, "start_trace", lambda d, **kw: None
-    )
-    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
-    monkeypatch.setattr(
-        overlap_mod, "split_trace_times",
-        lambda d: {"collective_us": 100.0, "compute_us": 300.0,
-                   "by_scope": {"wire_psum": 100.0}},
-    )
-    ticks = iter([0.0, 350e-6])  # wall = 350 us for 2 steps
-    rep = overlap_mod.measure_overlap(
-        lambda n: None, str(tmp_path), steps=2,
-        clock=lambda: next(ticks),
-    )
-    assert rep["overlap_frac"] == pytest.approx(0.5)
-    assert rep["wall_us_per_step"] == pytest.approx(175.0)
-    assert rep["collective_us_per_step"] == pytest.approx(50.0)
-    assert rep["by_scope"] == {"wire_psum": 50.0}
-
-    monkeypatch.setattr(overlap_mod, "split_trace_times", lambda d: None)
-    assert overlap_mod.measure_overlap(
-        lambda n: None, str(tmp_path), clock=time.perf_counter
-    ) is None
-
-
-# ---------------------------------------------------------------------------
 # fleet request tracing end to end (tentpole): one trace, many pids
 # ---------------------------------------------------------------------------
 
@@ -508,24 +427,6 @@ def _one_json_line(stdout):
     lines = [l for l in stdout.strip().splitlines() if l.strip()]
     assert len(lines) == 1, f"expected ONE JSON line on stdout, got {lines!r}"
     return json.loads(lines[0])
-
-
-@pytest.mark.slow
-def test_bench_cli_line_includes_overlap_frac():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py"),
-         "--model", "resnet18", "--image-size", "32",
-         "--batch-per-chip", "2", "--warmup", "1", "--steps", "2"],
-        capture_output=True, text=True, env=_cli_env(), timeout=600,
-        cwd=REPO_ROOT,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    doc = _one_json_line(proc.stdout)
-    # the key is ALWAYS present; the value degrades to None where the
-    # profile has no per-op device plane (plain CPU runs)
-    assert "overlap_frac" in doc
-    v = doc["overlap_frac"]
-    assert v is None or 0.0 <= v <= 1.0
 
 
 @pytest.mark.slow

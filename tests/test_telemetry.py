@@ -93,6 +93,12 @@ def test_instrumented_fit_records_and_trace(devices, tmp_path):
     trace = json.loads((tmp_path / "ckpt" / "trace_events.json").read_text())
     names = {e["name"] for e in trace}
     assert {"data_load", "h2d", "step", "eval", "checkpoint"} <= names
+    # every span the scope opens reaches the file through the one span call
+    assert {
+        "train_epoch", "train_step", "aot_lookup", "record_compile",
+        "metrics_add", "saver_check", "clock_fence", "boundary_fetch",
+        "log_fetch", "bad_step_drain", "epoch_drain", "assemble",
+    } <= names
     for e in trace:
         if e["ph"] == "X":
             assert e["dur"] >= 1 and e["ts"] >= 0
@@ -102,6 +108,7 @@ def test_instrumented_fit_records_and_trace(devices, tmp_path):
     assert summary["last_record"]["step"] == 8
     assert summary["straggler"] == {}
     assert summary["compiles"]["train_step"]["flops_per_step_per_device"] > 0
+    assert {"train_step", "eval_step"} <= set(summary["compiles_during_fit"])
     assert trainer.scope is None  # scope torn down with the fit
 
 
