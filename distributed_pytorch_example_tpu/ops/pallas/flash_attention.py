@@ -18,17 +18,23 @@ the dq scratch would exceed ``_FUSED_DQ_VMEM_LIMIT``, the historical
 two-kernel split (separate dq and dk/dv passes, two logits recomputes)
 serves as the fallback.
 
-Causal masking is block-aware: fully-masked (q-block, k-block) pairs skip
-their compute entirely, halving causal FLOPs.
+Causal masking skips masked work on every path, at the grain that path has.
+Multi-block grid: fully-masked (q-block, k-block) pairs skip their compute
+(``needed``) or are never scheduled (the folded triangular grid); the blocks
+on the diagonal still compute their masked half. Single tile (S == one
+block, GPT-2 at 1024): the kernel body walks static sub-tiles of
+``CAUSAL_SUB`` rows and gives each only the key prefix it can see
+(``flash_fwd_single_causal`` / ``flash_bwd_single_causal``: 10 of 16
+sub-tile pairs at S 1024, ``causal_visited_pairs``), masking the diagonal
+sub-tiles alone. Non-causal calls run the whole-tile bodies.
 
 Layout: (batch, seq, heads, head_dim) at the boundary — transposed to
 (batch, heads, seq, head_dim) internally so the seq x head_dim tiles are
 contiguous MXU operands.
 
-Block sizes default to 1024x1024 (fastest measured on v5e for head_dim 64 —
-see flash_attention()'s docstring; _fit_block shrinks them lane-aligned for
-shorter sequences). ``interpret=True`` runs the same kernels on CPU for
-tests.
+Block sizes default to 1024x1024 (see flash_attention()'s docstring for
+what was measured; _fit_block shrinks them lane-aligned for shorter
+sequences). ``interpret=True`` runs the same kernels on CPU for tests.
 """
 
 from __future__ import annotations
@@ -42,9 +48,9 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 
-# measured-fastest block size on v5e for head_dim 64 (see
-# flash_attention()'s docstring); ring attention's local folds import this
-# so a retune happens in ONE place
+# default block size (see flash_attention()'s docstring for the v5e
+# readings); ring attention's local folds import this so a retune happens
+# in ONE place
 DEFAULT_BLOCK = 1024
 
 
@@ -54,6 +60,34 @@ def _fit_block(seq: int, requested: int) -> int:
     while b > 128 and seq % b:
         b -= 128
     return b if seq % b == 0 else min(requested, seq)
+
+
+# rows per query sub-tile of the single-tile causal kernels (see
+# _fwd_single_causal_kernel); fitted to the sequence by _causal_sub
+CAUSAL_SUB = 256
+
+
+def _causal_sub(seq: int) -> int:
+    """Sub-tile rows for a causal single tile of ``seq``: the largest
+    multiple of 128 <= CAUSAL_SUB that divides ``seq`` into two or more
+    sub-tiles, or 0 (the whole-tile body runs) where there is none."""
+    b = min(CAUSAL_SUB, seq // 2) // 128 * 128
+    while b and seq % b:
+        b -= 128
+    return b
+
+
+def _causal_prefixes(seq: int, sub: int):
+    """(first row, visible key prefix) of each query sub-tile: rows
+    [row, row + sub) see keys [0, row + sub) and nothing after."""
+    return [(row, row + sub) for row in range(0, seq, sub)]
+
+
+def causal_visited_pairs(seq: int, sub: int):
+    """(visited, total) sub x sub tile pairs of the seq x seq causal
+    square when each query sub-tile is given only its key prefix."""
+    prefixes = _causal_prefixes(seq, sub)
+    return sum(prefix // sub for _, prefix in prefixes), len(prefixes) ** 2
 
 
 def _apply_causal_mask(s, i, j, block_q, block_k):
@@ -167,7 +201,8 @@ def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
     seq_k = k.shape[2]
     group = heads // k.shape[1]
     if seq_k == block_k:  # whole key sequence in one block: plain softmax
-        return _fwd_single(
+        single = _fwd_single if interpret else _fwd_single_shared
+        return single(
             q, k, v, kv_mask, causal, scale, block_q, block_k, interpret
         )
     ni = seq_q // block_q
@@ -256,12 +291,19 @@ def _fwd_single(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, n, i: (b, 0, 0)))
         inputs.append(kv_mask)
-    out, lse = pl.pallas_call(
-        functools.partial(
+    sub = _causal_sub(seq_q) if causal and seq_q == block_q == block_k else 0
+    if sub:
+        kernel, name = functools.partial(
+            _fwd_single_causal_kernel, scale=scale, sub=sub, has_mask=has_mask,
+        ), "flash_fwd_single_causal"
+    else:
+        kernel, name = functools.partial(
             _fwd_single_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
-        ),
-        name="flash_fwd_single",
+        ), "flash_fwd_single"
+    out, lse = pl.pallas_call(
+        kernel,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -340,6 +382,58 @@ def _fwd_single_kernel(
         lse = jnp.where(dead, NEG_INF, lse)
     o_ref[0, 0] = o.astype(o_ref.dtype)
     lse_ref[0, 0] = lse
+
+
+def _fwd_single_causal_kernel(*refs, scale: float, sub: int, has_mask: bool):
+    """Causal one-tile forward that leaves the masked half uncomputed.
+
+    The query rows are walked in static sub-tiles of ``sub`` rows; each is
+    given only the key prefix it can see (static slices of the refs
+    already in VMEM), in two pieces: the tiles left of the diagonal, fully
+    visible, and the diagonal ``sub x sub`` tile, the only one masked.
+    Every row still sees all of its keys at once, so the plain tile
+    softmax of :func:`_fwd_single_kernel` stays; the entries left out are
+    those whose weight was ``exp(NEG_INF - m) = 0``.
+    """
+    if has_mask:
+        q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+        mask_ref = None
+    for row, prefix in _causal_prefixes(q_ref.shape[2], sub):
+        rows = diag = slice(row, prefix)  # the diagonal tile's keys too
+        pieces = ([slice(0, row)] if row else []) + [diag]
+        q = q_ref[0, 0, rows, :]
+        logits = []
+        for cols in pieces:
+            s = jax.lax.dot_general(
+                q, k_ref[0, 0, cols, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            if cols is diag:
+                s = _apply_causal_mask(s, 0, 0, sub, sub)
+            if mask_ref is not None:
+                s = jnp.where(mask_ref[0, :, cols] > 0.0, s, NEG_INF)
+            logits.append(s)
+        m = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in logits]
+        )
+        l, o = 0.0, 0.0
+        for cols, s in zip(pieces, logits):
+            p = jnp.exp(s - m)
+            l += jnp.sum(p, axis=-1, keepdims=True)
+            o += jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, 0, cols, :],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+        o = o / l
+        lse = m + jnp.log(l)
+        if mask_ref is not None:
+            dead = m == NEG_INF  # no valid key at all
+            o = jnp.where(dead, 0.0, o)
+            lse = jnp.where(dead, NEG_INF, lse)
+        o_ref[0, 0, rows, :] = o.astype(o_ref.dtype)
+        lse_ref[0, 0, rows, :] = lse
 
 
 # ---------------------------------------------------------------------------
@@ -623,6 +717,77 @@ def _bwd_single_kernel(
     ).astype(dk_ref.dtype)
 
 
+def _bwd_single_causal_kernel(*refs, scale: float, sub: int, has_mask: bool):
+    """Causal one-tile fused backward that leaves the masked half
+    uncomputed: the sub-tiling of :func:`_fwd_single_causal_kernel`, walked
+    key-major.
+
+    Key sub-tile ``c`` meets only the query rows that can see it, in two
+    pieces: the diagonal ``sub x sub`` tile (the only one masked) and the
+    rows below it, fully visible. Its dk/dv are complete after the two and
+    written once; dq accumulates in a float32 VMEM scratch spanning the
+    sequence, and a row sub-tile is written out at its diagonal tile, its
+    last visit. (The query-major order, dq written once and dk/dv
+    accumulated in two scratch buffers, measured 9% slower: PERF.md, PR 26.)
+    """
+    if has_mask:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc) = refs
+        mask_ref = None
+    seq = q_ref.shape[2]
+
+    def piece(rows, cols, diagonal):
+        """(dq, dk, dv) contributions of the logits of ``rows`` x ``cols``."""
+        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+        k, v = k_ref[0, 0, cols, :], v_ref[0, 0, cols, :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        if diagonal:
+            s = _apply_causal_mask(s, 0, 0, sub, sub)
+        p = jnp.exp(s - lse_ref[0, 0, rows, :])
+        if mask_ref is not None:
+            p = jnp.where(mask_ref[0, :, cols] > 0.0, p, 0.0)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ds = p * (dp - delta_ref[0, 0, rows, :]) * scale
+        dv = jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk = jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq = jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return dq, dk, dv
+
+    for col, prefix in _causal_prefixes(seq, sub):
+        cols = slice(col, prefix)
+        dq, dk, dv = piece(cols, cols, True)
+        if prefix < seq:
+            below = slice(prefix, seq)
+            dq_below, dk_below, dv_below = piece(below, cols, False)
+            dk += dk_below
+            dv += dv_below
+            if col:
+                dq_acc[below, :] += dq_below
+            else:  # the first key sub-tile is the first touch of every row
+                dq_acc[below, :] = dq_below
+        if col:
+            dq += dq_acc[cols, :]
+        dq_ref[0, 0, cols, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
+
+
 def _bwd_single(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
                 block_k, interpret):
     batch, heads, seq_q, head_dim = q.shape
@@ -642,12 +807,21 @@ def _bwd_single(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, n: (b, 0, 0)))
         inputs.append(kv_mask)
-    return pl.pallas_call(
-        functools.partial(
+    sub = _causal_sub(seq_q) if causal and seq_q == seq_k else 0
+    if sub:
+        kernel, name = functools.partial(
+            _bwd_single_causal_kernel, scale=scale, sub=sub, has_mask=has_mask,
+        ), "flash_bwd_single_causal"
+        scratch = [_vmem((seq_q, head_dim))]  # dq accumulator
+    else:
+        kernel, name = functools.partial(
             _bwd_single_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, has_mask=has_mask,
-        ),
-        name="flash_bwd_single",
+        ), "flash_bwd_single"
+        scratch = []
+    return pl.pallas_call(
+        kernel,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=[qspec, kspec_out, kspec_out],
@@ -656,8 +830,21 @@ def _bwd_single(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
             _sds((batch, heads, seq_k, head_dim), k.dtype, q),
             _sds((batch, heads, seq_k, head_dim), v.dtype, q),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*inputs)
+
+
+# The single-tile wrappers as the compiled path calls them: jitted, so that
+# a model's layers share ONE trace of the kernel body (XLA inlines the
+# calls; the step keeps its 24 tpu_custom_call). The unrolled causal bodies
+# are ~6x the equations of the whole-tile ones and, traced once a layer,
+# added 3.7 s to the GPT-2 step's tracing (PERF.md, PR 26). Interpret mode
+# (the CPU tests, which call these eagerly) keeps the plain functions: with
+# the jitted ones, all three tier-1 runs lost a worker to a SIGABRT in a
+# later multi-device CPU program of the same process, and none without.
+_fwd_single_shared = jax.jit(_fwd_single, static_argnums=(4, 5, 6, 7, 8))
+_bwd_single_shared = jax.jit(_bwd_single, static_argnums=(7, 8, 9, 10, 11))
 
 
 # the fused backward's persistent dq scratch (seq_q * head_dim * 4 bytes)
@@ -771,7 +958,8 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
     if seq_q == block_q and seq_k == block_k:
         # both sequences in one tile: fused dq/dk/dv kernel, one logits
         # recompute + one exp instead of two of each
-        dq, dk, dv = _bwd_single(
+        single = _bwd_single if interpret else _bwd_single_shared
+        dq, dk, dv = single(
             q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
             block_k, interpret,
         )
@@ -970,10 +1158,16 @@ def flash_attention(
     Sequence lengths must be multiples of the block sizes (the dispatcher in
     ops/attention.py guarantees this before selecting the flash path; blocks
     shrink to the sequence length when it is shorter). 1024x1024 default
-    blocks measured fastest on v5e for head_dim 64 (12-layer GPT-2-shape
-    chain: 0.67 ms/layer fwd vs 0.98 at 512x512, fwd+bwd 23.5 vs 30.0 ms) —
-    small blocks pay too many grid steps and per-step online-softmax
-    bookkeeping; the 4 MB f32 logits tile still sits comfortably in VMEM.
+    blocks measured fastest on v5e for head_dim 64 (my chip run, PR 26:
+    device time a call at (16, 12, 1024, 64) bf16, forward / backward —
+    causal 0.49 / 0.97 ms at 1024x1024 (one tile, sub-tiled), 1.45 / 1.38
+    at 512x512, 2.54 / 2.46 at 256x256; non-causal 0.71 / 1.37, 1.87 /
+    1.83, 3.89 / 3.83 ms) — small blocks pay too many grid steps and
+    per-step online-softmax bookkeeping; the f32 logits tile (4 MB whole,
+    1 MB a causal sub-tile row) still sits comfortably in VMEM. Causal
+    work is skipped at the grain each path has: whole blocks on the
+    multi-block grid, key prefixes of ``CAUSAL_SUB``-row sub-tiles inside
+    a single tile (see the module docstring).
     """
     if softmax_scale is None:
         softmax_scale = q.shape[-1] ** -0.5

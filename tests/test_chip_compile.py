@@ -76,6 +76,16 @@ def _qkv(sharding, seq=SEQ):
     )
 
 
+def _placed(where, one_chip, data_mesh):
+    """(batch, sharding, context) of a flash call on one chip, or under the
+    four-chip data mesh with 16 sequences a chip."""
+    import contextlib
+
+    if where == "one-chip":
+        return BATCH, one_chip, contextlib.nullcontext()
+    return 4 * BATCH, NamedSharding(data_mesh, P("data")), data_mesh
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_causal_compiles(one_chip, no_persistent_cache, direction):
     """GPT-2's attention: causal, head-major layout, single 1024 block
@@ -129,6 +139,34 @@ def test_flash_kv_mask_compiles(one_chip, no_persistent_cache, direction):
     else:
         loss = lambda q, k, v, m: fwd(q, k, v, m).astype(jnp.float32).sum()
         _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, mask)
+
+
+@pytest.mark.parametrize("where", ["one-chip", "dp4-mesh"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_causal_kv_mask_compiles(
+    one_chip, data_mesh, no_persistent_cache, direction, where
+):
+    """The sub-tiled causal single-tile bodies with the key-padding port:
+    static ref slices of q/k/v/lse/delta and of the mask's lanes, and the
+    backward's dq scratch, at GPT-2's shapes."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+    )
+
+    batch, sharding, ctx = _placed(where, one_chip, data_mesh)
+    q, k, v = (
+        jax.ShapeDtypeStruct(
+            (batch, SEQ, HEADS, HEAD_DIM), jnp.bfloat16, sharding=sharding
+        )
+        for _ in range(3)
+    )
+    mask = jax.ShapeDtypeStruct((batch, SEQ), jnp.bool_, sharding=sharding)
+    fwd = lambda q, k, v, m: flash_attention(q, k, v, causal=True, kv_mask=m)
+    loss = lambda q, k, v, m: fwd(q, k, v, m).astype(jnp.float32).sum()
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    with ctx:
+        names = _kernel_names(_compile(fn, q, k, v, mask))
+    assert all("_single_causal" in n for n in names), names
 
 
 @pytest.mark.parametrize(
@@ -252,25 +290,19 @@ def test_flash_forward_and_backward_differ_by_name(
     forward and the backward kernel of one training step must be told
     apart by it (``name=`` on each ``pallas_call``), on one chip and under
     the shard_map that wraps the kernels on a mesh."""
-    import contextlib
-
     from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
         flash_attention_bnsh,
     )
 
     fwd = functools.partial(flash_attention_bnsh, causal=True)
     loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
-    if where == "one-chip":
-        args, ctx = _qkv(one_chip, seq=seq), contextlib.nullcontext()
-    else:
-        sharding = NamedSharding(data_mesh, P("data"))
-        args, ctx = tuple(
-            jax.ShapeDtypeStruct(
-                (4 * BATCH, HEADS, seq, HEAD_DIM), jnp.bfloat16,
-                sharding=sharding,
-            )
-            for _ in range(3)
-        ), data_mesh
+    batch, sharding, ctx = _placed(where, one_chip, data_mesh)
+    args = tuple(
+        jax.ShapeDtypeStruct(
+            (batch, HEADS, seq, HEAD_DIM), jnp.bfloat16, sharding=sharding
+        )
+        for _ in range(3)
+    )
     with ctx:
         names = _kernel_names(
             _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
@@ -279,3 +311,8 @@ def test_flash_forward_and_backward_differ_by_name(
     backward = {n for n in names if "flash_bwd" in n}
     assert forward and backward, names
     assert forward | backward == names and not forward & backward, names
+    # one causal tile runs the sub-tiled bodies, under names of their own
+    # (the count of calls by name is what says they engaged); the
+    # multi-block grid skips whole blocks and keeps its names
+    causal_tile = {n for n in names if "_single_causal" in n}
+    assert causal_tile == (names if seq == SEQ else set()), names

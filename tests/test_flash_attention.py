@@ -169,6 +169,165 @@ def test_multiblock_split_fallback_grads(causal, monkeypatch):
         )
 
 
+# ---------------------------------------------------------------------------
+# the single-tile causal kernels: prefix-only sub-tiles inside the tile
+# ---------------------------------------------------------------------------
+
+
+def _pallas_kernels(fn, *args):
+    """{kernel name: kernel jaxpr} of every ``pallas_call`` ``fn`` traces."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = eqn.params["jaxpr"]
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _dot_flops(jaxpr):
+    """Operations of the ``dot_general``s a kernel body holds (a CPU run
+    gives counts: what the kernel computes, not how fast)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            depth = np.prod([eqn.invars[0].aval.shape[d] for d in contract])
+            total += 2 * int(np.prod(eqn.outvars[0].aval.shape)) * int(depth)
+    return total
+
+
+def _single_tile_kernels(seq, causal):
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention_bnsh,
+    )
+
+    x = jax.ShapeDtypeStruct((1, 2, seq, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention_bnsh(q, k, v, causal=causal, interpret=True)
+        return out.astype(jnp.float32).sum()
+
+    return _pallas_kernels(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+
+
+@pytest.mark.parametrize("masked", [None, "random", "padded-row"])
+@pytest.mark.parametrize("gqa", [False, True])
+@pytest.mark.parametrize("seq", [256, 384, 1024])
+def test_causal_single_tile_subtiles_match_xla(seq, gqa, masked):
+    """Two, three and four query sub-tiles, each given only its key
+    prefix: forward and all three gradients against the XLA reference,
+    with GQA and with key padding (one batch row fully padded: zero
+    output, zero gradients)."""
+    rng = np.random.default_rng(41 + seq)
+    kvh = 2 if gqa else 4
+    q = jnp.asarray(rng.standard_normal((2, seq, 4, 64)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, seq, kvh, 64)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, seq, kvh, 64)), jnp.float32)
+    kv_mask = None
+    if masked:
+        kv_mask = np.array(make_kv_mask(seq=seq, seed=42))
+        if masked == "padded-row":
+            kv_mask[1, :] = False
+        kv_mask = jnp.asarray(kv_mask)
+    scale = 64 ** -0.5
+
+    expected = _xla_attention(q, k, v, None, kv_mask, True, scale)
+    got = flash_attention(q, k, v, causal=True, kv_mask=kv_mask, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
+    if masked == "padded-row":
+        np.testing.assert_array_equal(np.asarray(got)[1], 0.0)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(_xla_attention(q, k, v, None, kv_mask, True, scale) ** 2)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(
+            flash_attention(
+                q, k, v, causal=True, kv_mask=kv_mask, interpret=True
+            ) ** 2
+        )
+
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    for gr, gf, name in zip(g_ref, g_flash, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
+        )
+        if masked == "padded-row":
+            np.testing.assert_array_equal(np.asarray(gf)[1], 0.0)
+
+
+@pytest.mark.parametrize(
+    "seq,causal,suffix",
+    [
+        (256, True, "_causal"), (384, True, "_causal"), (1024, True, "_causal"),
+        (128, True, ""),  # fewer than two sub-tiles: the whole-tile body
+        (512, False, ""), (1024, False, ""),
+    ],
+)
+def test_single_tile_kernel_names_say_which_body_ran(seq, causal, suffix):
+    """The kernel's name is the engagement counter a device trace shows:
+    the sub-tiled causal bodies have names of their own."""
+    assert set(_single_tile_kernels(seq, causal)) == {
+        "flash_fwd_single" + suffix, "flash_bwd_single" + suffix,
+    }
+
+
+@pytest.mark.parametrize(
+    "sub,visited,total", [(512, 3, 4), (256, 10, 16), (128, 36, 64)]
+)
+def test_causal_visited_pairs(sub, visited, total):
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        causal_visited_pairs,
+    )
+
+    assert causal_visited_pairs(1024, sub) == (visited, total)
+
+
+@pytest.mark.parametrize(
+    "seq,sub",
+    [(64, 0), (128, 0), (192, 0), (256, 128), (320, 0), (384, 128),
+     (512, 256), (640, 128), (768, 256), (1024, 256)],
+)
+def test_causal_sub_fits_the_sequence(seq, sub):
+    """The largest multiple of 128 under the module's constant that cuts
+    the sequence into two or more sub-tiles; 0 = the whole-tile body."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        _causal_sub,
+    )
+
+    assert _causal_sub(seq) == sub
+
+
+@pytest.mark.parametrize(
+    "kernel,equations,flops",
+    [("flash_fwd_single", 20, 268435456), ("flash_bwd_single", 26, 671088640)],
+)
+def test_causal_single_tile_computes_under_two_thirds(kernel, equations, flops):
+    """At S 1024 the causal kernel's products are at most 0.65 of the
+    non-causal kernel's (10 of 16 sub-tile pairs at sub 256), and the
+    non-causal body is the one it was before the causal one was sub-tiled
+    (equations and operations counted on the parent commit)."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        _causal_sub,
+        causal_visited_pairs,
+    )
+
+    full = _single_tile_kernels(1024, False)[kernel]
+    assert (len(full.eqns), _dot_flops(full)) == (equations, flops)
+    tiled = _dot_flops(_single_tile_kernels(1024, True)[kernel + "_causal"])
+    visited, total = causal_visited_pairs(1024, _causal_sub(1024))
+    assert tiled * total == flops * visited
+    assert tiled <= 0.65 * flops
+
+
 def test_uneven_blocks_rejected():
     q, k, v = make_qkv(seq=200)
     with pytest.raises(ValueError):
