@@ -63,8 +63,8 @@ def main(argv=None):
                 job.reseed(seed)
             program = job.program_readings()
             batches = list(job.loader.kept)
-            start = job.reference_params()
-            reference = plain.run(start, batches, job.mask_key)
+            # weights made anew for each run: `run` consumes them
+            reference = plain.run(job.reference_params(), batches, job.mask_key)
             line = {"seed": seed, "cell": cell["name"]}
             line["program"], line["program_leaves"] = train_reference.compare(
                 program, reference
@@ -73,10 +73,13 @@ def main(argv=None):
                 "program": program["losses"], "reference": reference["losses"]
             }
             if n < args.control_seeds:
-                control = fp8.run(start, batches, job.mask_key)
+                control = fp8.run(job.reference_params(), batches, job.mask_key)
                 line["control_fp8"], _ = train_reference.compare(control, reference)
                 for name, used in faults.items():
-                    broken = plain.run(start, batches, job.mask_key, rows_used=used)
+                    broken = plain.run(
+                        job.reference_params(), batches, job.mask_key,
+                        rows_used=used,
+                    )
                     line["fault_" + name], _ = train_reference.compare(
                         broken, reference
                     )

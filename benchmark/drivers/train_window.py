@@ -203,6 +203,7 @@ class TrainRun:
         ))
         self.weight_key = jax.random.key(weight_seed)
         self._mask_seed = mask_seed
+        self.records = []  # the epoch record of every `fit` call
         self.give_weights()
 
     @property
@@ -216,14 +217,13 @@ class TrainRun:
 
     # -- the benchmark's weights, in the program's state ------------------
 
-    def reference_params(self, sharding=None):
-        """This seed's weights under the reference's own names; on one
-        device, or laid out as ``sharding`` says."""
+    def reference_params(self):
+        """This seed's weights under the reference's own names, made anew
+        at each call (``ReferenceSteps.run`` consumes what it is given)."""
         import jax
 
         return jax.jit(
-            lambda key: self.model.init_params(key, self.config),
-            out_shardings=sharding,
+            lambda key: self.model.init_params(key, self.config)
         )(self.weight_key)
 
     def give_weights(self):
@@ -272,6 +272,7 @@ class TrainRun:
         self.loader.arm(limit=limit, deadline=deadline)
         with jax.profiler.TraceAnnotation("train_epoch"):
             history = self.trainer.fit(self.loader, None, epochs=1)
+        self.records.append(history[0])
         return history[0]
 
     def program_readings(self):
@@ -280,19 +281,22 @@ class TrainRun:
         first moment after one step: m1 = (1 - b1) g1) and the change's norm
         by leaf after the last."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec
 
         self.loader.keep, self.loader.kept = CHECK_STEPS, []
         norms = jax.jit(train_reference.leaf_norms)
-        start = self.reference_params(
-            NamedSharding(self.trainer.partitioner.mesh, PartitionSpec())
-        )
         names = self.names
-        change = jax.jit(lambda params, flat: train_reference.leaf_norms(
-            jax.tree_util.tree_map(
-                lambda name, x: x - flat[name].reshape(x.shape), names, params
+
+        def change(params, key):
+            # the weights the steps started from, made again here and not
+            # kept on the device through the steps; the barrier keeps their
+            # making out of the sums, which add up as they would alone
+            flat = jax.lax.optimization_barrier(
+                self.model.init_params(key, self.config)
             )
-        ))
+            return train_reference.leaf_norms(jax.tree_util.tree_map(
+                lambda name, x: x - flat[name].reshape(x.shape), names, params
+            ))
+
         losses, grad_norms = [], None
         for step in range(CHECK_STEPS):
             losses.append(float(self.fit(limit=1)["train_loss"]))
@@ -300,7 +304,9 @@ class TrainRun:
                 moment = _first_moment(self.trainer.state.opt_state)
                 scale = 1.0 - self.traffic["adam"]["b1"]
                 grad_norms = self._by_name(norms(moment), 1.0 / scale)
-        change_norms = self._by_name(change(self.trainer.state.params, start), 1.0)
+        change_norms = self._by_name(
+            jax.jit(change)(self.trainer.state.params, self.weight_key), 1.0
+        )
         return {
             "losses": losses, "grad_norms": grad_norms,
             "change_norms": change_norms,
@@ -350,6 +356,27 @@ def _first_moment(opt_state):
             f"found {len(found)}"
         )
     return found[0]
+
+
+def held_to_zero(config, records):
+    """``{name: (value, 0)}`` for the program's own counts that the
+    configuration holds to zero (``exact_zero`` in its file: a count only
+    the program can make, such as the assignments an expert layer dropped).
+    The value is the largest over the epoch records of the run's ``fit``
+    calls. A record without the name ends the run: the program has stopped
+    reporting what ``correct`` rests on."""
+    entries = {}
+    for name in config.get("exact_zero", []):
+        lacking = [i for i, record in enumerate(records) if name not in record]
+        if lacking:
+            raise SystemExit(
+                f"benchmark: the configuration holds {name!r} to zero, but "
+                f"the epoch records {lacking} of {len(records)} lack it"
+            )
+        values = [float(record[name]) for record in records]
+        # a NaN is the worst there is
+        entries[name] = (next((v for v in values if v != v), max(values)), 0)
+    return entries
 
 
 def run(cell, config, traffic, bench, args, clock, devices, peak):
@@ -418,12 +445,13 @@ def run(cell, config, traffic, bench, args, clock, devices, peak):
 
     # -- the reference, once the program's state is gone ------------------
     reference_steps = job.reference_steps()
-    start = job.reference_params()
     mask_key = job.mask_key
     job.free()
     del trainer, record
     t0 = time.perf_counter()
-    reference = reference_steps.run(start, served_batches, mask_key)
+    reference = reference_steps.run(
+        job.reference_params(), served_batches, mask_key
+    )
     numbers, leaves = train_reference.compare(program, reference)
     harness.say(
         f"reference: {CHECK_STEPS} steps in {time.perf_counter() - t0:.1f} s; "
@@ -437,6 +465,7 @@ def run(cell, config, traffic, bench, args, clock, devices, peak):
     )
     compared["steps_not_taken"] = (steps - steps_done, 0)
     compared["programs_compiled_in_window"] = (compiled_inside, 0)
+    compared.update(held_to_zero(config, job.records))
     correct = all(value <= limit for value, limit in compared.values())
 
     device = {

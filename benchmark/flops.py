@@ -20,14 +20,39 @@ computes the same number:
   gradient needs (dV, dP, dQ, dK). The recomputation of the scores inside
   the kernel is the kernel's own choice and is not counted, so the share
   of the roofline it can reach is at most 4/5 where it recomputes once.
+
+A stack whose layers differ says so in its ``shape`` group, under
+``layer_kinds``: a list of ``{"name", "count", "matmul_params",
+"attention"}``. ``matmul_params`` is the weights of ONE such layer that one
+token meets in matrix products, written out in the configuration's file
+where a reader can check it against the widths; ``attention`` says whether
+the layer has the two attention products. Without the key the stack is
+``layers`` blocks of four d x d projections and a two-matrix MLP, each with
+attention. What a kind counts:
+
+* Grouped queries: the q and o projections at the query heads' width, k and
+  v at the key heads'. The two attention products are at the query heads'
+  width (``heads * head_dim``) whatever the grouping.
+* A gated MLP has three matrices.
+* A gated convolution: its projections in and out are products and are
+  counted; the depthwise taps along the sequence (a few multiply-adds a
+  channel) are not, like every other elementwise pass.
+* Experts: what was routed to experts held here. Per token that is the
+  experts per token, times one expert's matrices, times the share of the
+  layer's experts that this chip holds, plus the router, which every token
+  meets whole. The count is static, so it assumes even routing; a cell says
+  beside it, by a counter of the program, what the routing really was.
 """
 
 
 def matmul_params(shape):
     """Weights that take part in a matrix product, per token, with the head
     weighted by the share of positions it is needed for."""
-    d, ff = shape["d_model"], shape["d_ff"]
-    body = shape["layers"] * (4 * d * d + 2 * d * ff)
+    d = shape["d_model"]
+    if "layer_kinds" in shape:
+        body = sum(k["count"] * k["matmul_params"] for k in shape["layer_kinds"])
+    else:
+        body = shape["layers"] * (4 * d * d + 2 * d * shape["d_ff"])
     head = shape["vocab"] * d + shape.get("head_extra_matmul_params", 0)
     return body + shape["head_token_share"] * head
 
@@ -36,7 +61,11 @@ def attention_flops_per_token(shape, seq_len):
     """Forward operations of the two attention products, per token."""
     visible = seq_len / 2 if shape["causal"] else seq_len
     width = shape["heads"] * shape["head_dim"]
-    return shape["layers"] * 2 * 2 * visible * width
+    if "layer_kinds" in shape:
+        layers = sum(k["count"] for k in shape["layer_kinds"] if k["attention"])
+    else:
+        layers = shape["layers"]
+    return layers * 2 * 2 * visible * width
 
 
 def train_flops_per_token(shape, seq_len):

@@ -2,15 +2,16 @@
 from the cell, their least time on the chip from ``flops.py``, and their
 device time from the trace.
 
-The patterns are written against ``reduce.short_name``. The v5e's trace
-names a Pallas kernel by the module or transform that called it
-(``attn._fused_layout_attention.37`` on one chip, ``shard_map.12`` under a
-mesh) and the shapes it returns, never by the kernel (the program gives its
-``pallas_call``s no ``name=`` yet — PERF.md, "for the tracing issue"). So a
-``tpu_custom_call`` that returns (output (b, h, s, d), float32 log-sum-exp
-(b, h, s, 1)) is the forward kernel, and one that returns three (b, h, s, d)
-tensors (dq, dk, dv) the backward. A cell whose program holds another
-Pallas kernel of either signature needs a metric file of its own."""
+The patterns are written against ``reduce.short_name`` and tell the kernels
+by what they return, not yet by the names the program gives them (PERF.md
+section 7): a ``tpu_custom_call`` that returns (output (b, h, s, d), float32
+log-sum-exp (b, h, s, 1)) is the forward kernel, and one that returns three
+(b, h, s, d) tensors (dq, dk, dv) the backward. Every call found is counted
+at ONE shape: the cell's rows a chip, ``shape["heads"]``, its sequence
+length and ``shape["head_dim"]``. So the two metrics list their cells in
+``BENCHMARK.json``: a cell whose attention has another shape (grouped keys,
+a window, layers of two lengths), or whose program holds another Pallas
+kernel of either signature, brings metric files of its own."""
 
 import flops
 

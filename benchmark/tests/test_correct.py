@@ -57,16 +57,20 @@ def test_fp8_control_is_not_correct(config, cell, seed):
         rng.integers(0, config["vocab_size"], (8, 64), dtype=np.int32)
         for _ in range(3)
     ]
-    params = jax.jit(lambda k: model.init_params(k, config))(jax.random.key(seed))
+    make = jax.jit(lambda k: model.init_params(k, config))
+
+    def params():  # made anew for each run, which consumes them
+        return make(jax.random.key(seed))
+
     key = jax.random.key(seed + 100)
     plain = train_reference.ReferenceSteps(model, config, traffic["adam"], 4)
     fp8 = train_reference.ReferenceSteps(
         model, config, traffic["adam"], 4, train_reference.fp8_dot
     )
-    reference = plain.run(params, batches, key)
-    again, _ = train_reference.compare(plain.run(params, batches, key), reference)
+    reference = plain.run(params(), batches, key)
+    again, _ = train_reference.compare(plain.run(params(), batches, key), reference)
     assert failing(again, cell) == []
-    control, _ = train_reference.compare(fp8.run(params, batches, key), reference)
+    control, _ = train_reference.compare(fp8.run(params(), batches, key), reference)
     assert failing(control, cell), control
 
 

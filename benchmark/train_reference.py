@@ -84,7 +84,16 @@ class ReferenceSteps:
     On a cell of several chips the blocks are that many times as large and
     each chip takes its share of a block's rows (the weights replicated, the
     compiler summing the gradient), so that the reference of a four-chip
-    cell takes no longer than that of a one-chip cell."""
+    cell takes no longer than that of a one-chip cell.
+
+    Memory: at its fullest the device holds five float32 copies of the
+    weights (the weights, Adam's two moments, the gradient summed so far
+    and one block's gradient: 20 bytes a parameter) and one block's
+    activations. The sum and the update are done in place (their inputs are
+    donated), and the weights the steps start from wait on the host until
+    the change is measured. Nothing here rematerialises: a model whose
+    block of float32 activations would not fit (a few GB from 4096 tokens
+    on) wraps each layer of its own ``loss_sum`` in ``jax.checkpoint``."""
 
     def __init__(self, model, sizes, adam, block_rows, dot=plain_dot, devices=None):
         self.model, self.sizes, self.adam = model, sizes, adam
@@ -103,11 +112,14 @@ class ReferenceSteps:
         self._grad = jax.jit(jax.value_and_grad(
             lambda p, rows: model.loss_sum(p, rows, sizes, dot), has_aux=True
         ))
-        self._add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
+        self._add = jax.jit(
+            lambda a, b: jax.tree_util.tree_map(jnp.add, a, b), donate_argnums=0
+        )
         self._update = jax.jit(
             lambda p, m, v, g, count, t: adam_step(
                 p, m, v, jax.tree_util.tree_map(lambda x: x / count, g), t, adam
-            )
+            ),
+            donate_argnums=(0, 1, 2),
         )
         self._norms = jax.jit(leaf_norms)
         self._change = jax.jit(lambda a, b: leaf_norms(
@@ -117,12 +129,19 @@ class ReferenceSteps:
     def run(self, params, batches, mask_key, rows_used=None):
         """{"losses": [...], "grad_norms": {leaf: norm of the first
         gradient}, "change_norms": {leaf: norm of the change after the last
-        step}} for the steps on ``batches`` (host arrays of token rows)."""
+        step}} for the steps on ``batches`` (host arrays of token rows).
+
+        Consumes ``params``: the first update is done in place, in the
+        buffers it is given. Hand it weights made for this call."""
         if self._replicated is not None:
             params = jax.device_put(params, self._replicated)
-        start = params
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-        m, v = zeros, zeros
+        # to the host leaf by leaf, through a copy: on the CPU the host's
+        # view of a buffer is the buffer, and one still looked at is not donated
+        start = jax.tree_util.tree_map(
+            lambda x: jax.device_get(jnp.copy(x)), params
+        )
+        m = jax.tree_util.tree_map(jnp.zeros_like, params)
+        v = jax.tree_util.tree_map(jnp.zeros_like, params)
         losses, grad_norms = [], None
         for step, tokens in enumerate(batches):
             rows = self._prepare(jnp.asarray(tokens), mask_key, step)
@@ -138,7 +157,14 @@ class ReferenceSteps:
                         block, self._by_rows if even else self._replicated
                     )
                 (loss, scored), g = self._grad(params, block)
-                grads = g if grads is None else self._add(grads, g)
+                if grads is None:
+                    grads = g
+                else:
+                    # the host waits for the sum: run ahead, it would have the
+                    # next block's gradient allocated while this one is still
+                    # being added, a sixth copy
+                    grads = jax.block_until_ready(self._add(grads, g))
+                del g  # or it lives on beside the next block's
                 total, count = total + float(loss), count + int(scored)
             losses.append(total / count)
             if grad_norms is None:
@@ -149,7 +175,10 @@ class ReferenceSteps:
             params, m, v = self._update(
                 params, m, v, grads, jnp.float32(count), step + 1
             )
-        change = jax.device_get(self._change(params, start))
+        del m, v, grads
+        change = jax.device_get(self._change(params, jax.device_put(
+            start, self._replicated
+        )))
         return {
             "losses": losses,
             "grad_norms": grad_norms,
