@@ -90,6 +90,11 @@ class MultiHeadAttention(nn.Module):
     num_kv_heads: Optional[int] = None  # < num_heads = GQA (None = MHA)
     rope: bool = False  # rotary embeddings on q/k (LLaMA-style)
     rope_theta: float = 10000.0
+    # RMSNorm over each head's dims of q and of k, with a learned gain,
+    # BEFORE RoPE (models/lfm2.py); off, the module traces what it traced
+    qk_norm: bool = False
+    qk_norm_epsilon: float = 1e-5
+    use_bias: bool = True  # biases on the four projections
     sp_mode: str = "ring"  # sequence parallelism: "ring" | "ulysses"
     decode: bool = False  # autoregressive KV-cache mode (train/generate.py)
     # paged KV cache (graft-serve, serving/engine.py). > 0 switches decode
@@ -136,6 +141,8 @@ class MultiHeadAttention(nn.Module):
         fused = (
             not self.decode
             and not self.rope
+            and not self.qk_norm
+            and self.use_bias
             and mask is None
             and kv_mask is None
             and self.seq_axis is None
@@ -149,12 +156,22 @@ class MultiHeadAttention(nn.Module):
             return self._fused_layout_attention(
                 x, features, kv_features, kv_heads, train
             )
-        q = nn.Dense(features, dtype=self.dtype, name="q")(x)
-        k = nn.Dense(kv_features, dtype=self.dtype, name="k")(x)
-        v = nn.Dense(kv_features, dtype=self.dtype, name="v")(x)
+        def dense(width, name):
+            return nn.Dense(
+                width, use_bias=self.use_bias, dtype=self.dtype, name=name
+            )
+
+        q = dense(features, "q")(x)
+        k = dense(kv_features, "k")(x)
+        v = dense(kv_features, "v")(x)
         q = q.reshape(batch, seq, self.num_heads, self.head_dim)
         k = k.reshape(batch, seq, kv_heads, self.head_dim)
         v = v.reshape(batch, seq, kv_heads, self.head_dim)
+        if self.qk_norm:
+            from distributed_pytorch_example_tpu.models.llama import RMSNorm
+
+            q = RMSNorm(self.qk_norm_epsilon, self.dtype, name="q_norm")(q)
+            k = RMSNorm(self.qk_norm_epsilon, self.dtype, name="k_norm")(k)
 
         if self.decode:
             if not self.causal or mask is not None or kv_mask is not None \
@@ -168,7 +185,7 @@ class MultiHeadAttention(nn.Module):
             else:
                 out = self._decode_step(q, k, v, batch, seq, kv_heads)
             out = out.reshape((batch, seq, features))
-            out = nn.Dense(self.model_dim, dtype=self.dtype, name="o")(out)
+            out = dense(self.model_dim, "o")(out)
             return out
 
         if self.rope:
@@ -205,7 +222,7 @@ class MultiHeadAttention(nn.Module):
                 use_flash=self.use_flash,
             )
         out = out.reshape((batch, seq, features))
-        out = nn.Dense(self.model_dim, dtype=self.dtype, name="o")(out)
+        out = dense(self.model_dim, "o")(out)
         if self.dropout_rate:
             out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
         return out

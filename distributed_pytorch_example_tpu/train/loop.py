@@ -39,7 +39,10 @@ from distributed_pytorch_example_tpu.robustness import (
 from distributed_pytorch_example_tpu.runtime import distributed as dist
 from distributed_pytorch_example_tpu.runtime.logging import get_logger
 from distributed_pytorch_example_tpu.train import checkpoint as ckpt_lib
-from distributed_pytorch_example_tpu.train.metrics import MetricAccumulator
+from distributed_pytorch_example_tpu.train.metrics import (
+    MetricAccumulator,
+    fetch_scalars,
+)
 from distributed_pytorch_example_tpu.train.state import TrainState
 from distributed_pytorch_example_tpu.train.step import (
     build_eval_step,
@@ -61,6 +64,22 @@ def _span(scope: Optional[Telemetry], name: str, step: Optional[int] = None):
     if scope is None:
         return span_lib.no_span(name)
     return scope.span(name, step)
+
+
+def _record_moe_counters(scope: Optional[Telemetry], metrics) -> None:
+    """One ``moe_counters`` row of the in-memory record with the expert
+    layers' counters of this step as ``args``. Called inside ``log_fetch``
+    once the step's loss has come: the step is done, so this is a copy and
+    no wait. A model without experts has no such metric and pays a scan of
+    the metrics' names."""
+    names = [k for k in metrics if k.startswith("moe_")]
+    if scope is None or not names:
+        return
+    now = time.perf_counter_ns()
+    span_lib.add(
+        "moe_counters", now, now,
+        args={k[len("moe_"):]: v for k, v in fetch_scalars(metrics, names).items()},
+    )
 
 
 def _spanned_batches(iterator, scope: Optional[Telemetry]):
@@ -487,6 +506,7 @@ class Trainer:
                 if batch_idx % self.log_every == 0 and dist.is_coordinator():
                     with _span(scope, "log_fetch"):
                         loss = float(metrics["loss"])
+                        _record_moe_counters(scope, metrics)
                     logger.info(
                         "Epoch %d, Batch %d/%d, Loss: %.4f",
                         epoch, batch_idx, num_batches, loss,

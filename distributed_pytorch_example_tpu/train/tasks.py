@@ -66,17 +66,38 @@ def _train_mutable(model_state) -> list:
     return mutable + ["losses", "moe_metrics"]
 
 
+# how the expert layers' sown scalars of one name combine over the layers
+# into ``moe_<name>``: a count adds up, a share is the layers' mean, and an
+# imbalance or a fill is the worst layer's
+_MOE_REDUCE = {
+    "dropped_fraction": lambda v: sum(v) / len(v),
+    "dropped_assignments": sum,
+    "held_share": lambda v: sum(v) / len(v),
+    "load_max_over_mean": lambda v: jnp.max(jnp.stack(v)),
+    "rows_used_share": lambda v: jnp.max(jnp.stack(v)),
+}
+
+
 def _pop_sown(new_vars, model_state):
     """Extract (aux_loss_sum, extra_metrics, remaining_state) from a
     mutable-apply result: ``losses`` sums into the aux loss, the
-    ``moe_metrics`` scalars average into ``moe_dropped_fraction`` —
-    reported, never added to the loss. One implementation for the
-    outer-loss and 1F1B paths so their reporting cannot diverge."""
+    ``moe_metrics`` scalars combine by name over the layers into
+    ``moe_<name>`` (``_MOE_REDUCE``) — reported, never added to the loss.
+    One implementation for the outer-loss and 1F1B paths so their
+    reporting cannot diverge."""
     new_vars = dict(new_vars)
     losses = new_vars.pop("losses", {})
     aux = sum(jax.tree_util.tree_leaves(losses)) if losses else 0.0
-    sown = jax.tree_util.tree_leaves(new_vars.pop("moe_metrics", {}))
-    extra = {"moe_dropped_fraction": sum(sown) / len(sown)} if sown else {}
+    by_name = {}
+    for path, value in jax.tree_util.tree_leaves_with_path(
+        new_vars.pop("moe_metrics", {})
+    ):
+        names = [p.key for p in path if hasattr(p, "key")]
+        by_name.setdefault(names[-1], []).append(value)
+    extra = {
+        f"moe_{name}": _MOE_REDUCE[name](values)
+        for name, values in by_name.items()
+    }
     return aux, extra, (new_vars or (model_state or {}))
 
 

@@ -316,3 +316,74 @@ def test_flash_forward_and_backward_differ_by_name(
     # multi-block grid skips whole blocks and keeps its names
     causal_tile = {n for n in names if "_single_causal" in n}
     assert causal_tile == (names if seq == SEQ else set()), names
+
+
+# -- the lfm2-8b-a1b cell's kernels at its own shapes ------------------------
+# 4 rows of 8192 tokens, 32 query / 8 key heads of 64; 8 held experts of
+# 2048 x 1792, sorted assignments in twice the even share's rows
+
+LFM2_ROWS, LFM2_SEQ, LFM2_Q_HEADS, LFM2_KV_HEADS = 4, 8192, 32, 8
+LFM2_HELD, LFM2_DIM, LFM2_EXPERT_DIM = 8, 2048, 1792
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_gqa_8k_compiles(one_chip, no_persistent_cache, direction):
+    """Grouped-query causal flash at 8192 tokens: the multi-block kernels,
+    each key head serving four query heads, by the names the benchmark's
+    ``gqa_flash_*_roofline`` readers match."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+    )
+
+    sds = functools.partial(
+        jax.ShapeDtypeStruct, dtype=jnp.bfloat16, sharding=one_chip
+    )
+    q = sds((LFM2_ROWS, LFM2_SEQ, LFM2_Q_HEADS, HEAD_DIM))
+    k = v = sds((LFM2_ROWS, LFM2_SEQ, LFM2_KV_HEADS, HEAD_DIM))
+    fwd = functools.partial(flash_attention, causal=True)
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    names = _kernel_names(_compile(fn, q, k, v))
+    # under a bare jax.grad the names carry the transforms around them
+    # (``jvp_flash_fwd_``); in the train step they are ``flash_fwd.N``
+    assert not any("_single" in n for n in names), names
+    assert any("flash_fwd" in n for n in names), names
+    backward = {n for n in names if "flash_bwd" in n}
+    assert bool(backward) == (direction == "bwd"), names
+    assert all(
+        any(kind in n for kind in ("bwd_fused", "bwd_dq", "bwd_dkv"))
+        for n in backward
+    ), names
+
+
+@pytest.mark.parametrize("product", ["gate_up", "down"])
+@pytest.mark.parametrize("program", ["fwd", "d_rows", "d_weights"])
+def test_grouped_product_compiles(one_chip, no_persistent_cache, product, program):
+    """The dropless expert layer's grouped products (``ops/pallas/moe_gmm``,
+    which ``moe.grouped_dot`` calls on the chip), forward and both backward
+    products, at the cell's shapes, under the names the trace carries."""
+    from distributed_pytorch_example_tpu.models import moe
+    from distributed_pytorch_example_tpu.ops.pallas import moe_gmm
+
+    tokens = LFM2_ROWS * LFM2_SEQ
+    rows = moe.dropless_rows_bound(tokens, 4, LFM2_HELD, 32)
+    k, n = {
+        "gate_up": (LFM2_DIM, 2 * LFM2_EXPERT_DIM),
+        "down": (LFM2_EXPERT_DIM, LFM2_DIM),
+    }[product]
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    lhs = sds((rows, k), jnp.bfloat16)
+    rhs = sds((LFM2_HELD, k, n), jnp.bfloat16)
+    sizes = sds((LFM2_HELD,), jnp.int32)
+    g = sds((rows, n), jnp.bfloat16)
+    product_of = moe_gmm.grouped_matmul
+    fn = {
+        "fwd": lambda lhs, rhs, sizes, g: product_of(lhs, rhs, sizes),
+        "d_rows": lambda lhs, rhs, sizes, g: jax.vjp(
+            lambda x: product_of(x, rhs, sizes), lhs)[1](g)[0],
+        "d_weights": lambda lhs, rhs, sizes, g: jax.vjp(
+            lambda w: product_of(lhs, w, sizes), rhs)[1](g)[0],
+    }[program]
+    names = _kernel_names(_compile(fn, lhs, rhs, sizes, g))
+    wanted = "moe_gmm_dw" if program == "d_weights" else "moe_gmm"
+    assert any(wanted in n for n in names), names

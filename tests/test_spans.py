@@ -7,7 +7,8 @@ span a program. Pinned here: what a record row holds, the parent/root
 rules, where an instrumented ``fit`` puts each span (names are a contract
 the benchmark's per-layer readers rely on), that the same names land in a
 ``jax.profiler`` trace on the training thread, the compile log, and the
-``chunked_ce`` / ``optimizer`` scopes inside the lowered step.
+``chunked_ce`` / ``optimizer`` scopes inside the lowered step, the expert
+layer's and the short convolution's scopes, and the ``moe_counters`` rows.
 """
 
 import collections
@@ -422,6 +423,85 @@ def test_lowered_step_carries_chunked_ce_and_optimizer_scopes():
     assert "jit(train_step)/transpose(jvp(chunked_ce))" in scopes
     # Adam inside the bad-step cond's taken branch
     assert any(s.endswith("/optimizer") for s in scopes), scopes
+
+
+TINY_LFM2 = dict(
+    vocab_size=64, model_dim=16, num_heads=4, num_kv_heads=1, mlp_dim=32,
+    moe_mlp_dim=8, num_experts=8, top_k=2, layers_kept=(0, 2, 3),
+    num_dense_layers=1, experts_first=0, experts_held=4,
+    logits_mode="hidden",
+)
+MOE_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "short_conv")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lowered_step_carries_the_expert_and_convolution_scopes(remat):
+    """The names the benchmark's ``named_scopes`` reader looks for, as path
+    components of the lowered step's op names, forward and backward."""
+    from distributed_pytorch_example_tpu.train.tasks import CausalLMTask
+
+    model = dpx.models.get_model("lfm2-8b-a1b", remat=remat, **TINY_LFM2)
+    trainer = dpx.train.Trainer(model, CausalLMTask(), optax.adam(1e-3))
+    batch = {"tokens": jnp.zeros((4, 16), jnp.int32)}
+    trainer.init(batch["tokens"])
+    text = trainer.train_step.lower(trainer.state, batch).as_text(
+        debug_info=True
+    )
+    names = re.findall(r'loc\("(jit\(train_step\)[^"]*)"', text)
+    for scope in MOE_SCOPES + ("chunked_ce", "optimizer"):
+        component = re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)")
+        found = [n for n in names if component.search(n)]
+        assert found, scope
+        if scope != "optimizer":  # the backward pass carries the name too
+            assert any("transpose(" in n for n in found), scope
+
+
+def test_fit_leaves_moe_counters_rows_at_each_log_fetch(devices, record):
+    """One ``moe_counters`` row per ``log_fetch``, inside it, with the
+    step's four counters as ``args``; the epoch record carries their means
+    as ``train_moe_<name>``; a model without experts leaves none."""
+    from distributed_pytorch_example_tpu.train.tasks import CausalLMTask
+
+    class Rows:
+        tokens = np.random.default_rng(0).integers(0, 64, (48, 16)).astype(np.int32)
+
+        def __len__(self):
+            return len(self.tokens)
+
+        def __getitem__(self, i):
+            return {"tokens": self.tokens[i]}
+
+    mesh = dpx.runtime.make_mesh(devices=jax.devices()[:1])
+    trainer = dpx.train.Trainer(
+        dpx.models.get_model("lfm2-8b-a1b", **TINY_LFM2), CausalLMTask(),
+        optax.adam(1e-3), partitioner=dpx.parallel.data_parallel(mesh),
+        checkpoint_dir="", log_every=5,
+    )
+    loader = dpx.data.DeviceLoader(Rows(), 4, mesh=mesh, seed=0)
+    history = trainer.fit(loader, None, epochs=1)
+    rows = record.recorded()
+    counters = [s for s in rows if s.name == "moe_counters"]
+    fetches = [s for s in rows if s.name == "log_fetch"]
+    assert len(counters) == len(fetches) == 3  # steps 0, 5, 10 of 12
+    by_id = {s.id: s for s in rows}
+    (fit,) = [s for s in rows if s.name == "fit"]
+    for row in counters:
+        assert by_id[row.parent].name == "log_fetch" and row.root == fit.id
+        assert sorted(row.args) == [
+            "dropped_assignments", "held_share", "load_max_over_mean",
+            "rows_used_share",
+        ]
+        assert row.args["dropped_assignments"] == 0.0
+        assert 0.0 < row.args["held_share"] < 1.0
+    for name in row.args:
+        assert f"train_moe_{name}" in history[0]
+    assert history[0]["train_moe_dropped_assignments"] == 0.0
+
+
+def test_a_model_without_experts_leaves_no_moe_counters(devices, fit_record):
+    rows, _, _ = fit_record
+    assert any(s.name == "log_fetch" for s in rows)
+    assert not any(s.name == "moe_counters" for s in rows)
 
 
 # ---------------------------------------------------------------------------

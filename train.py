@@ -45,12 +45,14 @@ def build_dataset(args, num_samples: int, seed: int, train: bool = True):
             seed=seed,
         )
     if name == "synthetic-tokens":
-        if args.model.startswith("gpt"):
-            vocab = 50257
-        elif args.model.startswith("llama"):
-            vocab = 32000
-        else:
-            vocab = 30522
+        # the model's own vocabulary (the slice it was told, where it holds
+        # a share); BERT's for a model that states none
+        fields = getattr(
+            dpx.models.model_class(args.model), "__dataclass_fields__", {}
+        )
+        vocab = args.vocab_slice or (
+            fields["vocab_size"].default if "vocab_size" in fields else 30522
+        )
         return dpx_data.SyntheticTokenDataset(
             num_samples=num_samples, seq_len=args.seq_len, vocab_size=vocab, seed=seed
         )
@@ -140,7 +142,7 @@ def pick_auto_plan(args, parser, model, task, train_ds, global_batch):
         weight_decay=args.weight_decay, grad_clip_norm=args.grad_clip,
         every_k=args.grad_accum,
     )
-    lm = args.model.startswith(("bert", "gpt", "llama"))
+    lm = dpx.models.model_has(args.model, "head_params")
     best, scores = planner.pick_train_plan(
         model, task, optimizer, sample, batch,
         kind="lm" if lm else "image",
@@ -272,7 +274,12 @@ def main(argv=None, devices=None):
         overrides = {"dtype": dtype}
         if args.model in ("mlp",) or args.model.startswith("resnet") or args.model.startswith("vit"):
             overrides["num_classes"] = args.num_classes
-        is_transformer = args.model.startswith(("vit", "bert", "gpt", "llama"))
+
+        def has(what):  # a model is taken by what it has, not by its name
+            return dpx.models.model_has(args.model, what)
+
+        # ViT comes from a factory, which has no fields to ask
+        is_transformer = args.model.startswith("vit") or has("use_flash")
         # the RESOLVED axis size, not the raw flag: -1 may absorb to size 1
         seq_span = mesh.shape["sequence"]
         if args.sp_mode is not None and not (is_transformer and seq_span > 1):
@@ -284,10 +291,13 @@ def main(argv=None, devices=None):
             if args.flash != "auto":
                 overrides["use_flash"] = args.flash == "on"
             if seq_span > 1:
+                if has("use_flash") and not has("seq_axis"):
+                    parser.error(f"--mesh-sequence > 1: {args.model!r} has "
+                                 f"no sequence-parallel attention")
                 overrides["seq_axis"] = "sequence"  # SP over the mesh
                 if args.sp_mode is not None:  # None: keep the model's default
                     overrides["sp_mode"] = args.sp_mode
-        if args.model.startswith(("bert", "gpt", "llama")) and args.lm_loss == "fused":
+        if has("head_params") and args.lm_loss == "fused":
             # fused chunked-CE loss: the model returns final hidden states and
             # the task streams the tied-head matmul + softmax over vocab blocks
             overrides["logits_mode"] = "hidden"
@@ -299,8 +309,31 @@ def main(argv=None, devices=None):
             # both SP modes (ring rotates mask chunks with k/v; Ulysses
             # all-gathers the mask after its head swap)
             overrides["pad_token_id"] = args.pad_token_id
+        share = {
+            "--layers-kept": args.layers_kept,
+            "--experts-held": args.experts_held,
+            "--vocab-slice": args.vocab_slice,
+        }
+        for flag, value in share.items():
+            if value is not None and not has("layers_kept"):
+                parser.error(f"{flag} states a deployment's share, which "
+                             f"{args.model!r} has none of (lfm2-8b-a1b has)")
+        try:
+            if args.layers_kept is not None:
+                overrides["layers_kept"] = tuple(
+                    int(i) for i in args.layers_kept.split(",")
+                )
+            if args.experts_held is not None:
+                first, count = (int(i) for i in args.experts_held.split(","))
+                overrides["experts_first"] = first
+                overrides["experts_held"] = count
+        except ValueError:
+            parser.error("--layers-kept takes indices (0,2,3,4,5) and "
+                         "--experts-held FIRST,COUNT (0,8)")
+        if args.vocab_slice is not None:
+            overrides["vocab_size"] = args.vocab_slice
         if args.moe_experts:
-            if not args.model.startswith(("gpt", "llama")):
+            if not has("moe_experts"):
                 parser.error(f"--moe-experts is only supported for gpt2 and "
                              f"llama models, not {args.model!r}")
             overrides["moe_experts"] = args.moe_experts
@@ -321,7 +354,7 @@ def main(argv=None, devices=None):
                          "data parallelism with nothing sharded on the expert "
                          "axis; set --moe-experts too")
         if args.mesh_pipe not in (0, 1):
-            if not args.model.startswith(("gpt", "llama")):
+            if not has("pipe_axis"):
                 parser.error(f"--mesh-pipe is only supported for gpt2 and llama "
                              f"models, not {args.model!r}")
             overrides["pipe_axis"] = "pipe"
