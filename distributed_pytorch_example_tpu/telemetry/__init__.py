@@ -45,7 +45,11 @@ graft-scope rebuilds that surface TPU-first around four pillars:
   ``train_step`` > ``aot_lookup``       one loop body (step annotation);
   (> ``record_compile``), ``step``,     the executable's lookup, the
   ``metrics_add``, ``saver_check``      dispatch, the running metric sums,
-                                        the background saver's check
+                                        the background saver's check.
+                                        ``record_compile`` opens only where
+                                        the step was compiled and analysed:
+                                        a later ``fit`` on the Trainer is
+                                        given the kept record, with no span
   ``clock_fence`` ``boundary_fetch``    the four places the training thread
   ``log_fetch`` ``bad_step_drain``      blocks on the device: step clock's
   (wait)                                fence (every 8th step), the boundary

@@ -140,9 +140,14 @@ class CostRegistry:
     def record(self, tag: str, compiled, device=None,
                extra: Optional[Dict[str, object]] = None):
         rec = compiled_cost_record(compiled, device)
-        rec["tag"] = tag
         if extra:
             rec.update(extra)
+        return self.register(tag, rec)
+
+    def register(self, tag: str, rec: Dict[str, object]):
+        """Take a finished record under ``tag``: what ``record`` made when
+        the program was compiled, handed to a later run's registry."""
+        rec["tag"] = tag
         self.records[tag] = rec
         return rec
 

@@ -108,13 +108,23 @@ class Telemetry:
 
     def record_compile(self, tag: str, compiled, device=None,
                        extra: Optional[Dict[str, object]] = None):
-        """Register one AOT compile's cost/memory/collectives record."""
+        """Analyse one AOT compile (XLA's cost and memory analysis, the
+        collectives of its HLO text) and register the record."""
         if device is None:
             import jax
 
             devices = jax.devices()
             device = devices[0] if devices else None
-        rec = self.costs.record(tag, compiled, device, extra)
+        return self._announce(self.costs.record(tag, compiled, device, extra))
+
+    def register_compile(self, tag: str, rec: Dict[str, object]):
+        """Register the record of an executable analysed before (the
+        Trainer keeps it beside the executable): the same log line and
+        JSONL row, once a scope, and no analysis."""
+        return self._announce(self.costs.register(tag, rec))
+
+    def _announce(self, rec: Dict[str, object]):
+        tag = rec["tag"]
         flops = rec.get("flops_per_step_per_device")
         logger.info(
             "graft-scope compile[%s]: flops/device=%s, hbm_peak=%s bytes, "

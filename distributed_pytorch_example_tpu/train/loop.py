@@ -226,6 +226,9 @@ class Trainer:
         self.overlap_report: Optional[Dict[str, Any]] = None
         self._bucket_plan = None
         self._compiled: Dict[Any, Any] = {}  # AOT executables by shape key
+        # the cost record made at each one's compile, under the same key:
+        # plain numbers and strings, no reference to the executable
+        self._cost_records: Dict[Any, Dict[str, Any]] = {}
         # >0: write `latest` every N train batches WITH the loader cursor
         # (epoch, batch_in_epoch) so resume restarts at the exact batch —
         # step-level resume on top of the reference's epoch granularity
@@ -367,7 +370,11 @@ class Trainer:
         ``cost_analysis()``/``memory_analysis()`` at the moment the compile
         happens. A compile failure raises: a compiler refusal is a fault
         of the program, not something a second compile through ``jit``
-        would cure."""
+        would cure.
+
+        The ``record_compile`` span opens only where the analysis runs: at
+        a compile. A later ``fit`` that finds the executable here gives
+        its new scope the record kept beside it and opens no such span."""
         if self.scope is None:
             return None, self.train_step
         key = self._shape_key("train", batch)
@@ -375,16 +382,12 @@ class Trainer:
         if exe is None:
             exe = self.train_step.lower(self.state, batch).compile()
             with self.scope.span("record_compile"):
-                self.scope.record_compile("train_step", exe)
+                self._cost_records[key] = self.scope.record_compile(
+                    "train_step", exe
+                )
             self._compiled[key] = exe
-        elif (
-            exe is not self.train_step
-            and self.scope.costs.get("train_step") is None
-        ):
-            # a later fit() reuses the cached executable: re-register its
-            # cost record with the new run's scope
-            with self.scope.span("record_compile"):
-                self.scope.record_compile("train_step", exe)
+        else:
+            self._give_cost_record(key, "train_step")
         return key, exe
 
     def _eval_executable(self, batch):
@@ -396,14 +399,20 @@ class Trainer:
             exe = self.eval_step.lower(
                 self.state, batch, jnp.asarray(0, jnp.int32)
             ).compile()
-            self.scope.record_compile("eval_step", exe)
+            self._cost_records[key] = self.scope.record_compile(
+                "eval_step", exe
+            )
             self._compiled[key] = exe
-        elif (
-            exe is not self.eval_step
-            and self.scope.costs.get("eval_step") is None
-        ):
-            self.scope.record_compile("eval_step", exe)
+        else:
+            self._give_cost_record(key, "eval_step")
         return key, exe
+
+    def _give_cost_record(self, key, tag: str) -> None:
+        """A new ``fit``'s scope starts with an empty registry: hand it
+        the record made when this shape's executable was compiled (also
+        after ``_dispatch`` handed the shape back to ``jax.jit``)."""
+        if self.scope.costs.get(tag) is None and key in self._cost_records:
+            self.scope.register_compile(tag, self._cost_records[key])
 
     def _dispatch(self, key, exe, jit_fn, *args):
         """Call an AOT step executable, recovering from sharding drift.

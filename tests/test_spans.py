@@ -12,11 +12,14 @@ layer's and the short convolution's scopes, and the ``moe_counters`` rows.
 """
 
 import collections
+import copy
+import gc
 import glob
 import json
 import os
 import re
 import threading
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -148,20 +151,25 @@ def test_telemetry_span_feeds_record_and_chrome_file_together(
     assert rows[-1].root == 41
 
 
-def test_telemetry_off_opens_no_span(devices, record, tmp_path):
-    assert _span(None, "step") is trace.no_span("step")
-    with _span(None, "train_step", 3):
-        pass
+def _tiny_fit_parts(checkpoint_dir="", **kw):
     mesh = dpx.runtime.make_mesh()
     trainer = dpx.train.Trainer(
         dpx.models.SimpleNet(hidden_size=32),
         dpx.train.ClassificationTask(),
         optax.adam(1e-3),
         partitioner=dpx.parallel.data_parallel(mesh),
-        checkpoint_dir="", telemetry=False,
+        checkpoint_dir=checkpoint_dir, **kw,
     )
-    ds = dpx.data.SyntheticClassificationDataset(num_samples=32, input_size=784)
-    trainer.fit(dpx.data.DeviceLoader(ds, 16, mesh=mesh, seed=0), epochs=1)
+    ds = dpx.data.SyntheticClassificationDataset(num_samples=64, input_size=784)
+    return trainer, lambda: dpx.data.DeviceLoader(ds, 16, mesh=mesh, seed=0)
+
+
+def test_telemetry_off_opens_no_span(devices, record, tmp_path):
+    assert _span(None, "step") is trace.no_span("step")
+    with _span(None, "train_step", 3):
+        pass
+    trainer, loader = _tiny_fit_parts(telemetry=False)
+    trainer.fit(loader(), epochs=1)
     names = {s.name for s in record.recorded()}
     # the compile log is the process's, not the Trainer's: it stays on
     assert {n for n in names if not n.startswith(compilelog.PREFIX)} == set()
@@ -299,6 +307,135 @@ def test_summary_lists_the_programs_compiled_during_fit(devices, fit_record):
     assert by_id[step_compile.parent].name == "aot_lookup"
     (registered,) = [s for s in rows if s.name == "record_compile"]
     assert by_id[registered.parent].name == "aot_lookup"
+
+
+# ---------------------------------------------------------------------------
+# the cost record lives with the executable: a later fit is given it
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def two_fits(tmp_path_factory):
+    """Two ``fit`` calls with validation on ONE Trainer, ``every=1``. For
+    each call: the record's rows, the summary, the registry the scope held
+    when it closed, the JSONL's compile rows, and how many cost analyses
+    had run by its end."""
+    from distributed_pytorch_example_tpu.telemetry import cost
+
+    ckpt = tmp_path_factory.mktemp("two_fits") / "ckpt"
+    trainer, loader = _tiny_fit_parts(
+        str(ckpt), telemetry=TelemetryConfig(every=1)
+    )
+    registries, analysed, calls = [], [], []
+    close, analyse = Telemetry.close, cost.compiled_cost_record
+
+    def closing(scope):
+        registries.append(copy.deepcopy(scope.costs.records))
+        return close(scope)
+
+    def analysing(compiled, device=None):
+        analysed.append(1)
+        return analyse(compiled, device)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(Telemetry, "close", closing)
+        patch.setattr(cost, "compiled_cost_record", analysing)
+        for _ in range(2):
+            trace.clear()
+            trainer.fit(loader(), loader(), epochs=1)
+            rows = [
+                json.loads(line)
+                for line in (ckpt / "metrics.jsonl").read_text().splitlines()
+            ]
+            calls.append({
+                "rows": trace.recorded(),
+                "summary": trainer.telemetry_summary,
+                "registry": registries[-1],
+                "jsonl": {
+                    r["tag"]: r for r in rows if r.get("event") == "compile"
+                },
+                "analysed": len(analysed),
+            })
+    trace.clear()
+    return calls
+
+
+@pytest.mark.parametrize("tag", ["train_step", "eval_step"])
+def test_a_later_fit_is_given_the_record_and_analyses_nothing(
+        devices, two_fits, tag):
+    first, second = two_fits
+    by_id = {s.id: s for s in first["rows"]}
+    (registered,) = [s for s in first["rows"] if s.name == "record_compile"]
+    assert by_id[registered.parent].name == "aot_lookup"
+    # the second call finds both executables: no analysis, no such span,
+    # nothing compiled
+    assert first["analysed"] == 2 and second["analysed"] == 2
+    assert not [s for s in second["rows"] if s.name == "record_compile"]
+    assert second["summary"]["compiles_during_fit"] == []
+    # and its scope holds what the first one derived, value for value
+    record = second["registry"][tag]
+    assert record == first["registry"][tag]
+    assert set(record) >= {
+        "tag", "flops_per_step_per_device", "bytes_accessed",
+        "hbm_peak_bytes", "collectives", "device_kind", "peak_bf16_flops",
+    }
+    assert record["tag"] == tag and record["flops_per_step_per_device"] > 0
+    assert second["summary"]["compiles"][tag] == first["summary"]["compiles"][tag]
+    assert second["summary"]["compiles"][tag] == {
+        "flops_per_step_per_device": record["flops_per_step_per_device"],
+        "hbm_peak_bytes": record["hbm_peak_bytes"],
+    }
+    # every run's JSONL says what was compiled, once a scope
+    assert second["jsonl"][tag] == first["jsonl"][tag]
+    assert first["jsonl"][tag]["flops_per_step_per_device"] == \
+        record["flops_per_step_per_device"]
+    assert set(first["jsonl"]) == {"train_step", "eval_step"}
+
+
+def test_compiled_stays_a_map_of_executables_that_clear_frees(devices, record):
+    """What the benchmark's driver and ``chip_smoke.py`` lean on:
+    ``_compiled`` maps ``("train", ...)`` to the executable itself, and
+    ``clear()`` lets it go, the kept record holding no reference to it;
+    the next ``fit`` then compiles, analyses and records anew."""
+    trainer, loader = _tiny_fit_parts()
+    trainer.fit(loader(), None, epochs=1)
+    ((key, exe),) = trainer._compiled.items()
+    assert key[0] == "train" and exe.memory_analysis() is not None
+    kept = trainer._cost_records[key]
+    summary = trainer.telemetry_summary["compiles"]["train_step"]
+    assert summary.items() <= kept.items()
+    gone = weakref.ref(exe)
+    del exe
+    trainer._compiled.clear()
+    gc.collect()
+    assert gone() is None
+    record.clear()
+    trainer.fit(loader(), None, epochs=1)
+    rows = record.recorded()
+    by_id = {s.id: s for s in rows}
+    (registered,) = [s for s in rows if s.name == "record_compile"]
+    assert by_id[registered.parent].name == "aot_lookup"
+    # (jax's own in-process cache may answer the compile: no compile-log row)
+    assert list(trainer._compiled) == [key]
+    assert trainer._compiled[key].memory_analysis() is not None
+    assert trainer._cost_records[key] is not kept
+    assert trainer._cost_records[key] == kept
+
+
+def test_a_shape_handed_back_to_jit_keeps_its_compile_record(devices, record):
+    """After ``_dispatch``'s sharding-drift fallback the shape's entry is
+    the ``jit`` function; a later ``fit`` still reports the compile's
+    record, and analyses nothing."""
+    trainer, loader = _tiny_fit_parts()
+    trainer.fit(loader(), None, epochs=1)
+    first = trainer.telemetry_summary["compiles"]
+    (key,) = trainer._compiled
+    trainer._compiled[key] = trainer.train_step  # what the fallback leaves
+    record.clear()
+    trainer.fit(loader(), None, epochs=1)
+    assert trainer.telemetry_summary["compiles"] == first
+    assert first["train_step"]["flops_per_step_per_device"] > 0
+    assert not [s for s in record.recorded() if s.name == "record_compile"]
 
 
 def test_profiler_trace_holds_the_same_names_on_the_training_thread(
