@@ -456,7 +456,7 @@ def budget_staleness(
 ) -> Optional[str]:
     """Human note when sources are newer than the committed budget file.
 
-    mtime-based — a hint for ``bench_gate``/CLI reports, not a gate: a
+    mtime-based — a hint for the graft-lint report, not a gate: a
     source edit that changes no collective legitimately leaves budgets
     untouched.
     """

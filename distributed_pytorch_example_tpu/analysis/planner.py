@@ -4,7 +4,7 @@ Generalizes the cross-replica weight-update sharding search of Xu et al.
 (arxiv 2004.13336) to the full (data, fsdp, tensor, pipe, zero1,
 grad_accum, wire) space: enumerate the legal plans for a topology, score
 every one WITHOUT compiling or executing, and hand the ranked list to
-``--auto-mesh`` (train.py / bench.py / serve.py) or the
+``--auto-mesh`` (train.py / serve.py) or the
 ``scripts/plan_search.py`` report.
 
 The three-tier oracle (cheapest first, each tier refining the last):
@@ -179,8 +179,7 @@ def _plan_bucketed(plan: PlanSpec) -> bool:
 def _scheduled_hidden_frac(plan: PlanSpec, data_wire_bytes: float) -> float:
     """Scheduler-level hidden fraction of the bucketed grad sync.
 
-    Mirrors ``telemetry/overlap.scheduled_overlap`` without needing the
-    leaf tree: K roughly-equal buckets hide the first K-1 behind
+    Needs no leaf tree: K roughly-equal buckets hide the first K-1 behind
     remaining backward compute, so the hidden fraction is (K-1)/K with
     K estimated from the traced data-axis wire bytes over the per-bucket
     wire payload (the fp32 ``bucket_bytes`` target scaled by the wire
@@ -430,7 +429,7 @@ def score_flow(
             grad_sync_ms += link.event_ms(wb)
     # bucketed plans hide (K-1)/K of the grad-sync wire time behind the
     # backward segments still computing when early buckets issue
-    # (telemetry/overlap.py scheduled_overlap) — discount the data-axis
+    # (_scheduled_hidden_frac) — discount the data-axis
     # comm so --auto-mesh scores overlap instead of treating bucketed and
     # inline syncs as equal-cost
     if _plan_bucketed(plan) and grad_sync_ms > 0:
@@ -782,10 +781,10 @@ def best_plan(scores: Sequence[PlanScore]) -> Optional[PlanScore]:
 def cli_plan_space(
     n_devices: int, info: ProgramInfo, wire_block: int = 256
 ) -> List[PlanSpec]:
-    """The ``--auto-mesh`` search space shared by train.py / bench.py /
+    """The ``--auto-mesh`` search space shared by train.py /
     scripts/plan_search.py: every automatic-mode mesh family (one shared
     trace) plus the zero1 / int8-wire knobs on the pure-DP mesh (one trace
-    each — where bench's --zero1/--wire run), never wire without zero1.
+    each — where train.py's --zero1/--wire run), never wire without zero1.
     Every pure-DP ZeRO-1 plan also enters in its comm/compute-overlap
     variant (``bucket_bytes`` at the default target) so the oracle can
     pick bucketing when the hidden grad-sync time wins."""
@@ -932,54 +931,3 @@ def load_plans(path: str = DEFAULT_PLANS_PATH) -> Optional[Dict[str, object]]:
             return json.load(fh)
     except (OSError, ValueError):
         return None
-
-
-def plans_staleness(
-    plans_path: str = DEFAULT_PLANS_PATH,
-    budgets_path: Optional[str] = None,
-) -> Optional[str]:
-    """Why the committed plans.json may be stale, or None when current.
-
-    Mirrors ``collectives.budget_staleness``'s advisory contract (warn,
-    never fail): plans are derived from the same traced programs as the
-    committed comm budgets, so a budgets file regenerated after plans.json
-    (mtime), or a jax-version skew between the two _meta blocks, means the
-    rankings were computed against a schedule that no longer matches.
-    """
-    from distributed_pytorch_example_tpu.analysis import collectives
-
-    if budgets_path is None:
-        budgets_path = collectives.DEFAULT_BUDGETS_PATH
-    plans = load_plans(plans_path)
-    if plans is None:
-        return (
-            f"plans.json missing or unreadable at {plans_path} — generate "
-            f"with scripts/plan_search.py --write-plans"
-        )
-    plans_jax = ((plans.get("_meta") or {}).get("jax"))
-    budgets = collectives.load_budgets(budgets_path)
-    if budgets is not None:
-        budgets_jax = (budgets.get("_meta") or {}).get("jax")
-        if plans_jax and budgets_jax and plans_jax != budgets_jax:
-            return (
-                f"plans.json jax {plans_jax} != comm_budgets.json jax "
-                f"{budgets_jax} — regenerate with scripts/plan_search.py "
-                f"--write-plans"
-            )
-        try:
-            if os.path.getmtime(budgets_path) > os.path.getmtime(plans_path):
-                return (
-                    "comm_budgets.json is newer than plans.json — rankings "
-                    "may not reflect the committed budgets; regenerate with "
-                    "scripts/plan_search.py --write-plans"
-                )
-        except OSError:
-            pass
-    import jax
-
-    if plans_jax and plans_jax != jax.__version__:
-        return (
-            f"plans.json written under jax {plans_jax}, runtime is "
-            f"{jax.__version__} — rankings advisory only"
-        )
-    return None

@@ -170,9 +170,8 @@ def ring_all_gather(x, axis_name: str, *, stream: int = 0):
     ``lax.all_gather(x, axis_name, axis=0, tiled=True)``. Call inside a
     shard_map manual over ``axis_name``; any backend or payload shape
     the kernel does not cover takes the identical-numerics XLA path.
-    The dispatch boundary carries a ``ring_all_gather`` named scope so
-    graft-lens' overlap accounting (telemetry/overlap.py) can attribute
-    the moved bytes to this kernel in the XLA trace.
+    The dispatch boundary carries a ``ring_all_gather`` named scope, so
+    a device trace attributes the moved bytes to this kernel.
 
     ``stream`` selects an independent collective buffer set: concurrent
     ring kernels in one program (the per-bucket gathers of the overlap

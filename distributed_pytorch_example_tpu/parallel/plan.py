@@ -1,7 +1,7 @@
 """PlanSpec: the declarative parallelism plan every entry point lowers.
 
 Historically each surface assembled its own Partitioner: ``train.py`` picked
-a factory from CLI flags, ``bench.py`` re-derived the same choices, serve.py
+a factory from CLI flags, serve.py
 hand-built a transformer partitioner from ``--mesh``, and the ZeRO-1/wire
 knobs rode along as ad-hoc keyword overlays. A static planner cannot search
 a space that only exists as scattered call sites — so the whole contract is
@@ -20,7 +20,7 @@ structural signatures gate that equivalence without regeneration.
 
 ``analysis/planner.py`` (graft-plan) enumerates PlanSpecs, prunes illegal
 ones, and scores the survivors through the trace-only three-tier oracle;
-``--auto-mesh`` on train.py/bench.py/serve.py lowers the winner.
+``--auto-mesh`` on train.py/serve.py lowers the winner.
 """
 
 from __future__ import annotations

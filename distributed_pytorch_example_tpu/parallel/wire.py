@@ -49,8 +49,9 @@ on) falls back to the XLA collective with identical numerics.
 
 ``grad_wire_report`` is the analytic accounting side: per-device
 gradient-sync wire bytes per step from the param tree + partitioner +
-config, the quantity ``bench.py`` reports (``grad_wire_bytes_per_step``,
-``wire_compression_ratio``) and the comm-budget ``wire-int8-step``
+config, the quantity the Trainer's telemetry summary carries under
+``wire`` (``grad_wire_bytes_per_step``, ``wire_compression_ratio``) and
+the comm-budget ``wire-int8-step``
 signature gates at >= 3x (analysis/collectives.py).
 """
 
@@ -75,8 +76,8 @@ _QMAX = 127.0
 def _scoped(name: str):
     """Stamp a dispatch boundary with a ``jax.named_scope`` so every HLO
     op the collective lowers to carries the wire-layer scope in its
-    metadata — the attribution key graft-lens' overlap accounting
-    (telemetry/overlap.py) and the comm-budget marker parser grep for."""
+    metadata: the key by which a device trace or the compiled HLO's
+    text attributes the op to this layer."""
 
     def deco(fn):
         @functools.wraps(fn)
@@ -628,9 +629,7 @@ def sync_grads(grads, dims, axis_name: str, *,
     bucket is one named-scope-stamped collective with its own dataflow
     chain (``wire_bucket<k>``), issued in reverse-trace order so the
     XLA latency-hiding scheduler interleaves bucket k's wire time with
-    the backward compute that produces bucket k+1 — and graft-lens'
-    overlap accounting (telemetry/overlap.py) attributes the hidden
-    bytes per bucket by those scopes.
+    the backward compute that produces bucket k+1.
     """
     config = config or WireConfig()
     is_dim_leaf = lambda d: d is None  # noqa: E731 - tree of Optional[int]

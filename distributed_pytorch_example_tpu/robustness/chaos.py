@@ -144,7 +144,7 @@ class ChaosPlan:
 
 
 def preset(name: str) -> ChaosPlan:
-    """Named plans for `bench.py --chaos` and quick CLI use."""
+    """Named plans for `train.py --chaos` and `scripts/chaos_sweep.py`."""
     if name == "nan-step":
         # poison one batch well past warmup; the predicated update skips it
         return ChaosPlan([Fault("nan-batch", step=3)])

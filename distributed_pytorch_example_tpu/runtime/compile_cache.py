@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-One rule, for every entry point (train.py, serve.py, bench.py,
-chip_smoke.py, __graft_entry__.py): where ``JAX_COMPILATION_CACHE_DIR``
+One rule, for every entry point (train.py, serve.py, chip_smoke.py,
+__graft_entry__.py): where ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it itself and this module does nothing at all; where it
 is not, the cache is ``.jax_cache/`` at the root of the checkout. The path
 is part of the cache key's lookup, so it is fixed — never built from a

@@ -69,18 +69,13 @@ graft-scope rebuilds that surface TPU-first around four pillars:
   whether the cache hit; ``Telemetry.close()``'s summary lists the
   programs of the run as ``compiles_during_fit``.
 
-graft-lens extends the same substrate end-to-end across serving and the
-wire collectives:
+graft-lens extends the same substrate to serving:
 
 - **request tracing + rolling latency histograms** (:mod:`~.trace`
   counters/instants + :mod:`~.lens`): router→replica→engine request
   spans on per-replica Perfetto pids, queue-depth/KV-occupancy counter
   tracks, and bounded p50/p99 windows for TTFT/TPOT/queue-wait/journal
   lag surfaced in ``serve.py``'s JSON line;
-- **scheduled overlap** (:mod:`~.overlap`): the bucket plan's static
-  estimate of how much gradient-sync wire time can hide behind compute
-  (the measured counterpart is the benchmark's
-  ``collective_exposed_share``);
 - **serve-side self-arming sentinels** (:mod:`~.sentinels`
   ``ServeSentinels``): TPOT p99 regression, straggler replica, KV-pool
   pressure — auto-arm the XLA profiler and stamp ``trigger`` events.

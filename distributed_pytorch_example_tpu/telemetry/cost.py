@@ -16,8 +16,8 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-# peak dense bf16 FLOP/s per chip by PJRT device_kind substring (the table
-# bench.py judges MFU against). A v5e reports device_kind "TPU v5 lite".
+# peak dense bf16 FLOP/s per chip by PJRT device_kind substring (read by
+# scope.py's `mfu_analytic` and chip_smoke.py). A v5e reports device_kind "TPU v5 lite".
 PEAK_BF16 = {
     "v4": 275e12,
     "v5e": 197e12,

@@ -220,11 +220,6 @@ class Trainer:
         self.scope: Optional[Telemetry] = None
         self.telemetry_summary: Dict[str, Any] = {}
         self.wire_report: Optional[Dict[str, Any]] = None  # set in init()
-        # bucketed comm/compute overlap: the static bucket plan over the
-        # params and its scheduler-level overlap estimate (set in init()
-        # when wire.bucketed; telemetry/overlap.py scheduled_overlap)
-        self.overlap_report: Optional[Dict[str, Any]] = None
-        self._bucket_plan = None
         self._compiled: Dict[Any, Any] = {}  # AOT executables by shape key
         # the cost record made at each one's compile, under the same key:
         # plain numbers and strings, no reference to the executable
@@ -293,7 +288,7 @@ class Trainer:
         logger.info("Model parameters: %s", f"{n_params:,}")
         # analytic gradient-sync wire accounting (parallel/wire.py):
         # per-device bytes per step + compression ratio, surfaced in the
-        # telemetry summary and bench.py's JSON line
+        # telemetry summary (`telemetry_summary["wire"]`)
         if self.partitioner is not None:
             from distributed_pytorch_example_tpu.parallel.wire import (
                 grad_wire_report,
@@ -310,41 +305,6 @@ class Trainer:
                     f"{self.wire_report['grad_wire_bytes_per_step']:,}",
                     f"{self.wire_report['grad_wire_bytes_per_step_fp32']:,}",
                     self.wire_report["wire_compression_ratio"],
-                )
-            if self.wire.bucketed:
-                # static bucket plan + scheduler-level overlap estimate
-                # (grad shapes == param shapes, so planning over params
-                # reproduces exactly what sync_grads builds per step)
-                from distributed_pytorch_example_tpu.parallel import (
-                    wire as wirelib,
-                )
-                from distributed_pytorch_example_tpu.telemetry.overlap import (
-                    scheduled_overlap,
-                )
-
-                d = int(self.partitioner.mesh.shape.get("data", 1))
-                if self.partitioner.dp_shard_opt_state:
-                    dims = self.partitioner.zero1_dims(self.state.params)
-                else:
-                    dims = jax.tree_util.tree_map(
-                        lambda _: None, self.state.params
-                    )
-                self._bucket_plan = wirelib.plan_buckets(
-                    dims, self.state.params, self.wire, d
-                )
-                self.overlap_report = scheduled_overlap(
-                    self._bucket_plan,
-                    grad_accum_steps=self.grad_accum_steps,
-                )
-                logger.info(
-                    "graft-wire: %d overlap buckets (%s B target) — "
-                    "scheduled overlap_frac %.3f (%s of %s wire bytes "
-                    "hideable)",
-                    self.overlap_report["num_buckets"],
-                    f"{self.wire.bucket_bytes:,}",
-                    self.overlap_report["overlap_frac_scheduled"],
-                    f"{self.overlap_report['hideable_wire_bytes']:,}",
-                    f"{self.overlap_report['total_wire_bytes']:,}",
                 )
         else:
             self.wire_report = None
@@ -776,19 +736,6 @@ class Trainer:
             for kind, fields in self._pending_events:
                 self.scope.record_event(kind, **fields)
             self._pending_events = []
-            # bucketed-overlap plans stamp their issue schedule into the
-            # trace stream so CI can gate bucket ordering off-TPU
-            trace = getattr(self.scope, "trace", None)
-            if self._bucket_plan is not None and trace is not None:
-                from distributed_pytorch_example_tpu.telemetry.overlap import (
-                    scheduled_overlap,
-                )
-
-                scheduled_overlap(
-                    self._bucket_plan,
-                    grad_accum_steps=self.grad_accum_steps,
-                    trace=trace,
-                )
 
         start_epoch = 0
         start_batch = 0
@@ -878,10 +825,6 @@ class Trainer:
                     self.telemetry_summary = self.scope.close()
                     if self.wire_report is not None:
                         self.telemetry_summary["wire"] = dict(self.wire_report)
-                    if self.overlap_report is not None:
-                        self.telemetry_summary["overlap_scheduled"] = dict(
-                            self.overlap_report
-                        )
                     cache_stats = getattr(
                         getattr(train_loader, "dataset", None),
                         "cache_stats", None,

@@ -25,8 +25,7 @@ tokens = jnp.asarray(rng.integers(0, 50257, (B, S)).astype(np.int32))
 
 
 def _fence(out):
-    # a real device->host transfer fences the dispatched chain (the
-    # bench.py convention)
+    # a real device->host transfer fences the dispatched chain
     leaf = jax.tree_util.tree_leaves(out)[-1]
     np.asarray(jax.device_get(leaf.ravel()[0] if leaf.ndim else leaf))
 

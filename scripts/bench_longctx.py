@@ -77,7 +77,7 @@ def run(seq_len: int, batch: int, steps: int, warmup: int) -> dict:
     m = None
     for _ in range(warmup):
         params, opt, m = compiled(params, opt, tokens)
-    float(m["loss"])  # value-fetch fence (see bench.py)
+    float(m["loss"])  # value-fetch fence
     t0 = time.perf_counter()
     for _ in range(steps):
         params, opt, m = compiled(params, opt, tokens)

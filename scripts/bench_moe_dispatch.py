@@ -47,7 +47,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _fence(x) -> float:
-    """Fence by fetching a real value (the bench.py convention)."""
+    """Fence by fetching a real value."""
     import jax.numpy as jnp
 
     return float(jnp.sum(x[0]) if isinstance(x, tuple) else jnp.sum(x))

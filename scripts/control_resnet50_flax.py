@@ -8,7 +8,7 @@ times a vanilla flax ResNet-50 — written from the flax examples' idiom
 (plain ``nn.Conv`` NHWC, ``nn.BatchNorm``, canonical 7x7/2 + maxpool stem,
 bottleneck v1.5 blocks), deliberately importing NOTHING from
 ``distributed_pytorch_example_tpu`` — under the same batch/dtype/optimizer
-and the same timing discipline as ``bench.py``.
+and a value-fetch fence around the timed steps.
 
 If this lands at ~0.31 MFU too, the ceiling is XLA:TPU's conv-backward at
 these shapes, not framework overhead. If it lands higher, the framework
@@ -141,7 +141,7 @@ def main():
         params, batch_stats, opt_state, loss = compiled(
             params, batch_stats, opt_state, x, y
         )
-    float(loss)  # value-fetch fence (the bench.py convention)
+    float(loss)  # value-fetch fence
     t0 = time.perf_counter()
     for _ in range(args.steps):
         params, batch_stats, opt_state, loss = compiled(

@@ -7,7 +7,7 @@ collective/donation/placement audits) without executing a single train
 step, and gates against the committed ``analysis/comm_budgets.json`` and
 ``analysis/memory_envelopes.json``.
 
-Driver contract (same as bench.py): stdout carries exactly ONE JSON line;
+Driver contract: stdout carries exactly ONE JSON line;
 every detail — per-config collective tables, shardflow attributions,
 violation renderings, notes — goes to stderr. Exit status is non-zero iff
 there are violations.

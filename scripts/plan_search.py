@@ -12,7 +12,7 @@ fake 8-chip CPU mesh — WITHOUT a single XLA compile. Scoring tiers:
 3. committed compiled-cost records (analysis/comm_budgets.json) override
    the traced estimate when a plan coincides with a measured config.
 
-Driver contract (same as bench.py / graft_lint.py): stdout carries exactly
+Driver contract (same as graft_lint.py): stdout carries exactly
 ONE JSON line; per-plan rankings and event attributions go to stderr.
 
 Usage:
@@ -54,10 +54,10 @@ def _parse_bytes(raw):
 
 
 def _build_train_case(name: str, args):
-    """Model/task/batch mirroring bench.py's BASELINE table (bench.py
-    run_model): bf16 everywhere, fused-CE hidden logits for LMs, the same
-    per-chip batch defaults — the search ranks the exact programs bench
-    runs. Batch leaves are ShapeDtypeStructs: nothing is materialized."""
+    """Model/task/batch for one BASELINE model: bf16 everywhere, fused-CE
+    hidden logits for LMs, 16 rows a chip for an LM, 256 / 128 images for
+    resnet18 / the other image models unless --batch-per-chip says
+    otherwise. Batch leaves are ShapeDtypeStructs: nothing is materialized."""
     import jax
     import jax.numpy as jnp
 
@@ -148,7 +148,7 @@ def search_train(name: str, args, devices, budgets, hbm_limit, link):
 
     if name.startswith(("gpt", "llama")) and not args.no_pipe:
         # Pipeline candidates need the layer-stacked model variant (same
-        # rebuild bench.py does under --mesh-pipe); ranked with the same
+        # rebuild train.py does under --mesh-pipe); ranked with the same
         # program label and merged into one ordering.
         import jax.numpy as jnp
 

@@ -74,8 +74,8 @@ def main():
         ClassificationTask,
     )
 
-    # drive the SAME Trainer train step bench.py times, so the breakdown
-    # explains the bench numbers rather than a near-copy of the step
+    # drive the SAME Trainer train step train.py runs, so the breakdown
+    # explains that step rather than a near-copy of it
     rng = np.random.default_rng(0)
     is_vision = args.model.startswith(("resnet", "vit", "mlp"))
     if is_vision:
@@ -171,7 +171,7 @@ def main():
         metrics = None
         for _ in range(3):
             state, metrics = compiled(state, batch)
-        float(metrics["loss"])  # value-fetch fence (see bench.py)
+        float(metrics["loss"])  # value-fetch fence
         t0 = time.perf_counter()
         for _ in range(10):
             state, metrics = compiled(state, batch)

@@ -8,7 +8,7 @@ Poisson open-loop workload of mixed prompt/output lengths — the standard
 serving-benchmark shape: requests arrive on their own schedule whether or
 not the server is keeping up.
 
-Driver contract (same as bench.py): stdout gets exactly ONE JSON line —
+Driver contract: stdout gets exactly ONE JSON line —
 TTFT and per-output-token latency p50/p95/p99, tokens/sec, slot
 occupancy, preempted/rejected counts, config. Per-request detail lines
 go to stderr as requests finish.

@@ -18,13 +18,19 @@ import jax  # noqa: E402
 # the platform to CPU before any backend initializes, so that on a chip
 # host it neither takes the chip nor finds one device where it needs eight.
 jax.config.update("jax_platforms", "cpu")
-# One step in flight at a time. With asynchronous dispatch a loop of train
-# steps queues several executions, each needing all eight virtual devices'
-# threads at its collectives; under six loaded xdist workers a later
-# execution's threads can sit in the pool waiting at a rendezvous whose
-# other participants cannot get a thread, and after 40 s XLA:CPU ABORTS
-# the process (a lost worker, and with --dist loadfile the rest of its
-# file). Seen in PR 22 on test_sharded_generate's 60-step loop.
+# One execution in flight at a time, as far as a flag can: XLA:CPU runs a
+# collective's rendezvous on pool threads, and a thread that waits there
+# holds its place in the pool. With two executions of the eight virtual
+# devices in flight, the threads waiting at the later one's rendezvous can
+# keep the earlier one's last participant from ever getting a thread: a
+# deadlock, not a slow box, and after 40 s XLA ABORTS the process (a lost
+# xdist worker; a limit of 150 s through
+# --xla_cpu_collective_call_terminate_timeout_seconds aborts just the same,
+# PR 31). This flag keeps eager dispatch in line, but a multi-device
+# execution still returns before its devices finish, so a LOOP of many
+# train steps must also wait for each step's output
+# (test_sharded_generate's 60-step loop lost its worker in the driver's
+# runs of PR 29 and PR 30 until it did).
 jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 import pytest  # noqa: E402

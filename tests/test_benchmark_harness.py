@@ -53,10 +53,15 @@ def bench():
     [
         ["tests/test_flops.py"],
         ["tests/test_reference_steps.py"],
-        ["tests/test_run.py", "-k", "held_to_zero"],
+        ["tests/test_run.py"],
         ["tests/test_layer_metrics_lfm2.py"],
+        ["tests/test_reduce.py"],
+        ["tests/test_program_spans.py"],
     ],
-    ids=["flops", "reference_steps", "run_held_to_zero", "layer_metrics_lfm2"],
+    ids=[
+        "flops", "reference_steps", "run", "layer_metrics_lfm2", "reduce",
+        "program_spans",
+    ],
 )
 def test_the_benchmarks_own_tests_pass(selection):
     env = {

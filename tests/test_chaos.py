@@ -597,8 +597,8 @@ def test_chaos_sweep_full_matrix_green():
 @pytest.mark.slow
 def test_predication_overhead_within_budget(devices):
     """skip_nonfinite adds ≤2% to the compiled step (min-of-N; the ISSUE's
-    ≤1% claim is measured on TPU via `bench.py --chaos`, where the fixed
-    host-side cost this fake CPU mesh amplifies is invisible)."""
+    ≤1% claim is one for the chip and is not measured since PR 22; the
+    fixed host-side cost this fake CPU mesh amplifies is invisible there)."""
     import gc
     import time
 

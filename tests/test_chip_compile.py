@@ -8,7 +8,7 @@ about results or speed (the interpret-mode tests pin numerics;
 ``chip_smoke.py`` is the run on the chip).
 
 Shapes are GPT-2 124M's: 12 heads of 64, sequence 1024, bf16, 16
-sequences a chip (bench.py's LM cell), and the serve shapes chip_smoke.py
+sequences a chip (the benchmark's gpt2 cells), and the serve shapes chip_smoke.py
 uses. The topology is described inside a module-scoped fixture — never
 at import — because only one process may hold libtpu, and every xdist
 worker imports every test file.

@@ -558,7 +558,7 @@ def test_lane_padded_kv_mask_and_fully_padded_row():
 def test_misaligned_seq_auto_dispatch_takes_xla(monkeypatch):
     """Auto dispatch at seq % 128 != 0 must use the XLA path — the
     lane-padded flash path measured SLOWER at ViT bench shapes and is
-    opt-in only (BENCH_r03 regression, VERDICT r3 #1)."""
+    opt-in only (VERDICT r3 #1)."""
     from distributed_pytorch_example_tpu.ops import attention
 
     def _boom(*a, **kw):  # pragma: no cover - fails the test if reached

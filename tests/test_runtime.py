@@ -214,7 +214,7 @@ class TestMultiSliceMesh:
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENTRY_POINTS = (
-    "train.py", "serve.py", "bench.py", "chip_smoke.py", "__graft_entry__.py",
+    "train.py", "serve.py", "chip_smoke.py", "__graft_entry__.py",
 )
 
 
@@ -280,9 +280,12 @@ def test_only_compile_cache_module_sets_a_cache_dir():
         "compile_cache.py",
     )).read()
     assert not re.search(r"mkdtemp|gettempdir|getpid|time\.", module)
-    for entry in ENTRY_POINTS:
-        text = open(os.path.join(REPO_ROOT, entry)).read()
-        assert "enable_compile_cache()" in text, entry
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_every_entry_point_enables_the_compile_cache(entry):
+    text = open(os.path.join(REPO_ROOT, entry)).read()
+    assert "enable_compile_cache()" in text
 
 
 def test_current_mesh_is_none_outside_a_mesh_context(mesh_1d):

@@ -233,7 +233,7 @@ def test_inflight_insertion_slot_isolation():
 def test_continuous_beats_static_batching():
     """Mixed-length workload over 2 slots: continuous batching needs
     strictly fewer decode-program launches (the deterministic throughput
-    proxy; the wall-clock margin rides in bench.py --serve)."""
+    proxy; a wall-clock margin needs a serving cell on the chip: PERF §7)."""
     _, paged_model, params = _family("gpt2")
     prompts = _prompts((8, 8, 8, 8), seed=5)
     reqs = [

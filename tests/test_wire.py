@@ -558,7 +558,8 @@ def test_checkpoint_resume_across_compress_flip(mesh_1d, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# analytic wire accounting (what bench.py and the budget signature read)
+# analytic wire accounting (what telemetry_summary["wire"] and the budget
+# signature read)
 # ---------------------------------------------------------------------------
 
 
