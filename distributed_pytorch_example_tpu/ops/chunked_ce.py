@@ -10,14 +10,45 @@ logits faster than HBM can hold them.
 reduction, streaming over vocabulary blocks:
 
 - forward: one (N, D) x (D, Vb) matmul per block (bf16 operands, float32
-  accumulation on the MXU), a running max/logsumexp carried across blocks,
-  a gather-free target-logit term (select-by-column-id, no dynamic gather),
-  and a streaming argmax for the accuracy metric. Peak live logits are
-  (N, Vb) f32 instead of (N, V).
+  accumulation on the MXU) and ONE pass over the block's logits: a single
+  variadic ``lax.reduce`` over the block's columns yields the block's max,
+  its sum-exp relative to that max, its argmax id and its target-logit sum
+  (select-by-column-id, no dynamic gather), and the same combiner folds the
+  four into the running ``(m, s, best_i, tl)`` carried across blocks. Peak
+  live logits are (N, Vb) f32 instead of (N, V).
+- the combiner (``_combine``) is the online softmax's merge of two partial
+  results over disjoint columns, ``(m1, s1) + (m2, s2) = (max, s1 e^(m1-max)
+  + s2 e^(m2-max))``, with one ``exp`` a merge (the side holding the max is
+  not rescaled), the argmax id and the target sum carried beside. It is
+  associative and commutative in effect, so the compiler may group a block's
+  columns as it likes. Ties go to the LOWER column id, within a block and
+  across blocks (``jnp.argmax``'s first occurrence). Equal maxima rescale
+  by exactly 1 through a ``where``, so ``-inf - (-inf)`` never makes a NaN
+  out of the ``-inf`` identity, of a ``-inf`` bias column or of a whole
+  ``-inf`` block. A NaN or an inf in ``x`` still reaches the loss as a
+  non-finite number (the step's sentinels and bad-step predication read it).
 - backward (custom VJP): recomputes each logits block, forms
   ``(softmax - onehot) * g`` per block, accumulates ``dx`` across blocks and
   writes each embedding-gradient block to its own disjoint (Vb, D) slice —
   the (V, D) gradient is written exactly once, never read-modify-written.
+
+How often a block of f32 logits crosses the HBM (the layer is bound by those
+bytes, not by the MXU; read from the compiled v5e program, pinned in
+``tests/test_chip_compile.py``):
+
+- free schedule (the one-chip cells): XLA CSEs the backward's recomputed
+  logits against the forward's, so each block is written once, kept, and
+  read THREE times: by the forward's one reduction, by the ``dx`` product and
+  by the table-gradient product. (The two-pass forward this replaced read it
+  four times: max + argmax first, because ``exp(logits - max)`` needs the
+  max before it can start.)
+- serialized schedule (``serial``, below): nothing is shared with the
+  backward, the compiler puts the forward's product INSIDE the reduction
+  and the forward's f32 logits are never written (but for the block before
+  the first barrier); the backward's recomputed product writes the bf16
+  ``(softmax - onehot) * g`` once and its two products read that.
+- a forward alone (evaluation): every product sits inside its reduction;
+  no block of logits is written at all.
 
 The block loop is a fully UNROLLED Python loop over static slices, not a
 ``lax.scan``: ~13 blocks cost nothing to unroll, while the scan's while-loop
@@ -95,6 +126,22 @@ def _chunked_xent(x, embedding, bias, targets, block_size, dtype, serial):
     return loss, argmax
 
 
+def _combine(a, b):
+    """Online-softmax merge of two partial ``(max, sum-exp relative to
+    that max, argmax id, target-logit sum)`` over disjoint column sets
+    (the rules: module docstring). The side that holds the larger max keeps
+    its sum; the other is rescaled by ``exp(-|m1 - m2|)``, by exactly 1
+    where the maxima are equal (two ``-inf`` differ by NaN).
+    """
+    m1, s1, i1, t1 = a
+    m2, s2, i2, t2 = b
+    same = m1 == m2
+    e = jnp.where(same, 1.0, jnp.exp(-jnp.abs(m1 - m2)))
+    s = jnp.where(m1 >= m2, s1 + s2 * e, s1 * e + s2)
+    first = (m1 > m2) | (same & (i1 < i2))
+    return jnp.maximum(m1, m2), s, jnp.where(first, i1, i2), t1 + t2
+
+
 # the scope sits inside the custom VJP's own functions so that the primal,
 # the forward residual pass and the backward ops all carry it in their op
 # metadata (the device trace's chunked_ce_device_share reads it)
@@ -102,36 +149,36 @@ def _chunked_xent(x, embedding, bias, targets, block_size, dtype, serial):
 def _forward(x, embedding, bias, targets, block_size, dtype, serial):
     n = x.shape[0]
     vocab = embedding.shape[0]
-    m = jnp.full((n,), -jnp.inf, jnp.float32)  # running max
-    s = jnp.zeros((n,), jnp.float32)  # running sum-exp
-    tl = jnp.zeros((n,), jnp.float32)  # target logit
-    best_v = jnp.full((n,), -jnp.inf, jnp.float32)
-    best_i = jnp.zeros((n,), jnp.int32)
+    # _combine's identity: the running (max, sum-exp, argmax id, target logit)
+    identity = (
+        jnp.float32(-jnp.inf), jnp.float32(0.0),
+        jnp.int32(np.iinfo(np.int32).max), jnp.float32(0.0),
+    )
+    m, s, best_i, tl = (jnp.full((n,), v, v.dtype) for v in identity)
     first = True
     for off, width in _blocks(vocab, block_size):
         if serial and not first:
             # chain this block's matmul after the previous block's
-            # reductions: bounds live f32 logits at one block
+            # reduction: bounds live f32 logits at one block
             x, m = lax.optimization_barrier((x, m))
         first = False
         e_blk = lax.slice_in_dim(embedding, off, off + width)
         b_blk = None if bias is None else lax.slice_in_dim(bias, off, off + width)
         logits = _block_logits(x, e_blk, b_blk, dtype)  # (N, width) f32
-        col_ids = off + jnp.arange(width)  # (width,) global vocab ids
+        col_ids = jnp.broadcast_to(  # global vocab ids
+            off + jnp.arange(width, dtype=jnp.int32), logits.shape
+        )
         # gather-free target term: exactly one column matches per row (or
         # none in this block), so a masked sum IS the gathered logit
-        hit = col_ids[None, :] == targets[:, None]
-        tl = tl + jnp.where(hit, logits, 0.0).sum(axis=1)
-        # streaming logsumexp
-        bm = jnp.max(logits, axis=1)
-        nm = jnp.maximum(m, bm)
-        s = s * jnp.exp(m - nm) + jnp.exp(logits - nm[:, None]).sum(axis=1)
-        m = nm
-        # streaming argmax (strict > keeps first-occurrence tie semantics)
-        bi = jnp.argmax(logits, axis=1).astype(jnp.int32) + off
-        better = bm > best_v
-        best_v = jnp.where(better, bm, best_v)
-        best_i = jnp.where(better, bi, best_i)
+        hit = col_ids == targets[:, None]
+        # ONE reduction reads the block: every column enters as the partial
+        # (its logit, sum-exp 1, its id, its logit if it is the target)
+        block_stats = lax.reduce(
+            (logits, jnp.ones_like(logits), col_ids,
+             jnp.where(hit, logits, 0.0)),
+            identity, _combine, (1,),
+        )
+        m, s, best_i, tl = _combine((m, s, best_i, tl), block_stats)
     lse = m + jnp.log(s)
     return lse - tl, best_i, lse
 

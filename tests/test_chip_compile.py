@@ -387,3 +387,152 @@ def test_grouped_product_compiles(one_chip, no_persistent_cache, product, progra
     names = _kernel_names(_compile(fn, lhs, rhs, sizes, g))
     wanted = "moe_gmm_dw" if program == "d_weights" else "moe_gmm"
     assert any(wanted in n for n in names), names
+
+
+# --- the chunked cross-entropy (ops/chunked_ce.py), alone, at the cells' shapes
+
+
+def _hlo_computations(text):
+    """``{computation: [(name, shape, opcode, operands, attributes)]}`` of a
+    compiled module's text, and the entry computation's name."""
+    import re
+
+    def closes(s):  # index past the parenthesis that closes s[0]
+        depth = 0
+        for i, ch in enumerate(s):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                return i + 1
+
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+            continue
+        ins = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*)$", line)
+        if line.startswith("}") or cur is None or not ins:
+            cur = None if line.startswith("}") else cur
+            continue
+        name, rest = ins.groups()
+        cut = closes(rest) if rest.startswith("(") else rest.index(" ")
+        shape, rest = rest[:cut], rest[cut:].lstrip()
+        opcode = rest[:rest.index("(")]
+        cut = len(opcode) + closes(rest[len(opcode):])
+        operands = re.findall(r"%([\w.\-]+)", rest[len(opcode):cut])
+        comps[cur].append((name, shape, opcode, operands, rest[cut:]))
+    return comps, entry
+
+
+def _forward_statistics(compiled, n, widths):
+    """How the compiled op reads its forward logits. Returns ``(readers,
+    fused)``: for each forward logits block the program materialises (an
+    f32 ``(n, width)`` result of a fusion that holds the product), the
+    fusions that read it and reduce it to row statistics (results of length
+    ``n`` only: the backward's products and the bias gradient are not such);
+    and the number of row-statistics fusions that hold the product
+    themselves, so that their block is never written."""
+    import re
+
+    comps, entry = _hlo_computations(compiled.as_text())
+
+    def opcodes(comp):
+        found = set()
+        for _, _, opcode, _, attrs in comps.get(comp, ()):
+            found.add(opcode)
+            if opcode == "fusion":
+                found |= opcodes(re.search(r"calls=%([\w.\-]+)", attrs).group(1))
+        return found
+
+    alias = {
+        name: operands[0]
+        for name, _, opcode, operands, _ in comps[entry]
+        if opcode in ("copy", "copy-start", "copy-done", "bitcast",
+                      "get-tuple-element")
+    }
+
+    def origin(name):
+        while name in alias:
+            name = alias[name]
+        return name
+
+    fusions = [
+        (name, shape, [origin(o) for o in operands],
+         opcodes(re.search(r"calls=%([\w.\-]+)", attrs).group(1)))
+        for name, shape, opcode, operands, attrs in comps[entry]
+        if opcode == "fusion"
+    ]
+    blocks = [
+        name for name, shape, _, held in fusions
+        if "convolution" in held
+        and any(shape.startswith(f"f32[{n},{w}]") for w in widths)
+    ]
+    statistics = [
+        (name, operands, held) for name, shape, operands, held in fusions
+        if "reduce" in held
+        and all(dims == str(n) for dims in re.findall(r"\w+\[([\d,]*)\]", shape))
+    ]
+    readers = {
+        b: [name for name, operands, _ in statistics if b in operands]
+        for b in blocks
+    }
+    fused = sum("convolution" in held for _, _, held in statistics)
+    return readers, fused
+
+
+@pytest.mark.parametrize("cell,n,vocab,bias,serial", [
+    ("gpt2", BATCH * (SEQ - 1), 50257, False, False),
+    ("bert", 32 * 512, 30522, True, False),
+    ("gpt2-serial", BATCH * (SEQ - 1), 50257, False, True),
+])
+def test_chunked_ce_reads_each_forward_block_once(
+    one_chip, no_persistent_cache, monkeypatch, cell, n, vocab, bias, serial
+):
+    """Value-and-grad of the mean loss at the gpt2 and bert cells' shapes
+    (and once with the block chain forced): every forward logits block has
+    exactly ONE reducing reader (the two-pass forward had two), or none
+    because the product sits inside the one reduction; XLA still CSEs the
+    backward's recomputed logits against the forward's where it did (three
+    products counted, all but four when serialized); and the bytes the compiler
+    counts at gpt2's shapes stay under 17.5e9 (the two-pass forward:
+    20.65e9). Nothing runs: a count of the compiler's, not a time."""
+    from distributed_pytorch_example_tpu.ops import chunked_ce as cc
+
+    if serial:
+        monkeypatch.setattr(cc, "_SERIALIZE_TOTAL_BYTES", 0)
+    dim = HEADS * HEAD_DIM
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    args = [sds((n, dim), jnp.bfloat16), sds((vocab, dim), jnp.float32),
+            sds((n,), jnp.int32)]
+    if bias:
+        args.append(sds((vocab,), jnp.float32))
+
+    def mean_loss(x, table, targets, b=None):
+        loss, argmax = cc.chunked_softmax_xent(x, table, targets, bias=b)
+        return loss.mean(), argmax
+
+    compiled = jax.jit(jax.value_and_grad(
+        mean_loss, argnums=(0, 1, 3) if bias else (0, 1), has_aux=True
+    )).lower(*args).compile()
+
+    spans = cc._blocks(vocab, cc.DEFAULT_BLOCK)
+    readers, fused = _forward_statistics(
+        compiled, n, {width for _, width in spans}
+    )
+    assert all(len(r) == 1 for r in readers.values()), readers
+    assert len(readers) + fused == len(spans), (readers, fused)
+    if serial:
+        assert fused >= len(spans) - 1, (readers, fused)
+    else:
+        assert fused == 0 and len(readers) == len(spans), (readers, fused)
+
+    cost = compiled.cost_analysis()
+    product = 2.0 * n * dim * vocab
+    products = cost["flops"] / product
+    # serialized, only the block before the first barrier is still shared
+    low = 4.0 - 1.5 / len(spans) if serial else 3.0
+    assert low <= products < low + 0.2, products
+    if cell == "gpt2":
+        assert cost["bytes accessed"] < 17.5e9, cost["bytes accessed"]

@@ -207,3 +207,140 @@ def test_serialize_guard_engages_for_replicated_batch(monkeypatch, mesh_2x2x2):
         if e.primitive.name == "optimization_barrier"
     ]
     assert barriers, "guard must engage when the layout is unknown"
+
+
+# --- the one-pass forward (PR 32): one reduction a block whose combiner is
+# the online softmax's. Rows of ``hidden`` are unit vectors and the products
+# run in float32, so the embedding's columns ARE the logits, to the bit.
+
+_V, _BLOCK = 300, 128  # blocks of 128, 128 and a narrow last one of 44
+
+
+def _planted(case):
+    """(logits (n, V), bias or None, targets) of one named case."""
+    rng = np.random.default_rng(7)
+    n, vocab = 6, _V
+    bias = None
+    if case == "small_vocab":
+        vocab = 50  # narrower than one block
+    logits = rng.uniform(-80.0, 70.0, (n, vocab)).astype(np.float32)
+    targets = rng.integers(0, vocab, n)
+    if case == "spread80":
+        # the row's max in the first, a middle and the narrow last block
+        for row, col in enumerate([5, 200, 290, 0, 127, 299]):
+            logits[row, col] = 80.0
+    elif case == "neg_inf_bias_columns":
+        bias = np.zeros(vocab, np.float32)
+        bias[[0, 3, 130, 255, 256, 299]] = -np.inf
+        targets = np.array([1, 2, 129, 131, 257, 298])
+    elif case == "neg_inf_bias_block":
+        bias = rng.uniform(-1.0, 1.0, vocab).astype(np.float32)
+        bias[_BLOCK:2 * _BLOCK] = -np.inf  # the whole second block
+        targets = np.array([0, 127, 256, 299, 5, 270])
+    elif case == "tie_across_blocks":
+        for row, cols in enumerate([(10, 140), (127, 128), (100, 299),
+                                    (0, 256), (255, 256), (3, 130, 290)]):
+            logits[row, list(cols)] = 75.0
+    elif case == "tie_within_block":
+        for row, cols in enumerate([(130, 200), (0, 1), (256, 299),
+                                    (126, 127), (128, 255), (257, 258, 259)]):
+            logits[row, list(cols)] = 75.0
+    return logits, bias, targets
+
+
+@pytest.fixture
+def serial_forced(request, monkeypatch):
+    from distributed_pytorch_example_tpu.ops import chunked_ce as cc
+
+    if request.param:
+        monkeypatch.setattr(cc, "_SERIALIZE_TOTAL_BYTES", 0)
+    return request.param
+
+
+@pytest.mark.parametrize("serial_forced", [False, True], indirect=True,
+                         ids=["free", "serial"])
+@pytest.mark.parametrize("case", [
+    "spread80", "neg_inf_bias_columns", "neg_inf_bias_block",
+    "tie_across_blocks", "tie_within_block", "small_vocab",
+])
+def test_one_pass_forward_matches_dense(case, serial_forced):
+    """Loss, argmax and gradients against the dense float32 loss and
+    ``jnp.argmax``: logits over +-80, ``-inf`` columns and a whole ``-inf``
+    block, equal maxima (the first index wins, within a block and across
+    blocks), a vocabulary under one block; each on both block schedules."""
+    logits, bias, targets = _planted(case)
+    n = logits.shape[0]
+    hidden = jnp.eye(n, dtype=jnp.float32)
+    embedding = jnp.asarray(logits.T)
+    targets = jnp.asarray(targets, jnp.int32)
+    b = None if bias is None else jnp.asarray(bias)
+
+    def chunked(h, e, bb):
+        loss, argmax = chunked_softmax_xent(
+            h, e, targets, bias=bb, block_size=_BLOCK, dtype=jnp.float32
+        )
+        return loss.mean(), (loss, argmax)
+
+    def dense(h, e, bb):
+        loss, argmax = _dense(h, e, targets, bb, dtype=jnp.float32)
+        return loss.mean(), (loss, argmax)
+
+    argnums = (0, 1) if b is None else (0, 1, 2)
+    (_, (loss, argmax)), grads = jax.value_and_grad(
+        chunked, argnums=argnums, has_aux=True)(hidden, embedding, b)
+    (_, (ref_loss, ref_argmax)), ref_grads = jax.value_and_grad(
+        dense, argnums=argnums, has_aux=True)(hidden, embedding, b)
+    assert np.isfinite(np.asarray(loss)).all()
+    np.testing.assert_allclose(loss, ref_loss, rtol=2e-6, atol=2e-5)
+    np.testing.assert_array_equal(argmax, ref_argmax)
+    for got, want in zip(grads, ref_grads):
+        assert np.isfinite(np.asarray(got)).all()
+        # the backward sums +-80 x p over the vocabulary in another order
+        scale = float(np.abs(np.asarray(want)).max())
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-6 * scale)
+
+
+@pytest.mark.parametrize("serial_forced", [False, True], indirect=True,
+                         ids=["free", "serial"])
+@pytest.mark.parametrize("planted", [np.nan, np.inf, -np.inf])
+def test_non_finite_hidden_reaches_the_loss(planted, serial_forced):
+    """A NaN or an inf in ``x`` gives a non-finite loss for that row (the
+    step's sentinels and the bad-step predication read it) and leaves the
+    other rows' losses finite and as they were."""
+    rng = np.random.default_rng(3)
+    vocab, dim = 517, 16
+    hidden = rng.standard_normal((2, 4, dim)).astype(np.float32)
+    embedding = jnp.asarray(rng.standard_normal((vocab, dim)) * 0.1, jnp.float32)
+    targets = jnp.asarray(rng.integers(0, vocab, (2, 4)), jnp.int32)
+    clean, _ = chunked_softmax_xent(
+        jnp.asarray(hidden), embedding, targets, block_size=128
+    )
+    hidden[0, 2, 3] = planted
+    hidden[1, 1, 0] = planted
+    loss, _ = chunked_softmax_xent(
+        jnp.asarray(hidden), embedding, targets, block_size=128
+    )
+    bad = np.zeros((2, 4), bool)
+    bad[0, 2] = bad[1, 1] = True
+    loss, clean = np.asarray(loss), np.asarray(clean)
+    assert not np.isfinite(loss[bad]).any()
+    np.testing.assert_array_equal(loss[~bad], clean[~bad])
+
+
+@pytest.mark.parametrize("serial_forced", [False, True], indirect=True,
+                         ids=["free", "serial"])
+@pytest.mark.parametrize("vocab,block", [(_V, _BLOCK), (50, _BLOCK), (512, 64)])
+def test_forward_holds_one_reduction_per_block(vocab, block, serial_forced):
+    """The lowered forward reads each block of logits in ONE reduction (max,
+    sum-exp, argmax and target logit together); the two-pass body had four."""
+    hidden = jnp.zeros((4, 8), jnp.float32)
+    embedding = jnp.zeros((vocab, 8), jnp.float32)
+    targets = jnp.zeros((4,), jnp.int32)
+    text = jax.jit(
+        lambda h, e, t: chunked_softmax_xent(h, e, t, block_size=block)
+    ).lower(hidden, embedding, targets).as_text()
+    n_blocks = -(-vocab // block)
+    assert text.count("stablehlo.reduce") == n_blocks
+    assert text.count("stablehlo.dot_general") == n_blocks
+    barriers = text.count("stablehlo.optimization_barrier")
+    assert barriers == (n_blocks - 1 if serial_forced else 0)
