@@ -13,6 +13,11 @@
   attention in one stack, dropless sigmoid top-4 experts; a deployment's
   share by ``layers_kept``, ``experts_first`` / ``experts_held`` and
   ``vocab_size``.
+- ``joyai-llm-flash``: ``joyai.JoyaiLlmFlash``, latent attention (MLA) at
+  head dims 192 / 128, a shared expert beside sigmoid top-8 of 256 routed
+  ones, a multi-token-prediction module on the untied head; the same three
+  fields say a deployment's share (``--layers-kept``, ``--experts-held``,
+  ``--vocab-slice``), the prediction module stays with the head.
 
 All models are flax ``nn.Module``s taking NHWC images or int32 token ids and
 routing attention through ``ops.attention`` so kernel/parallelism dispatch is
@@ -66,6 +71,10 @@ def model_class(name: str):
         from distributed_pytorch_example_tpu.models.lfm2 import Lfm2
 
         return Lfm2
+    if name in ("joyai-llm-flash", "joyai"):
+        from distributed_pytorch_example_tpu.models.joyai import JoyaiLlmFlash
+
+        return JoyaiLlmFlash
     raise ValueError(f"Unknown model: {name!r}")
 
 

@@ -377,7 +377,11 @@ class DroplessMoE(nn.Module):
     selection bias ``select_bias`` sits in the parameter tree so that a
     checkpoint carries it, but it is a buffer: no gradient reaches it and
     Adam leaves it as it was. The row bound is derived
-    (:func:`dropless_rows_bound`)."""
+    (:func:`dropless_rows_bound`).
+
+    ``shared_mlp_dim`` > 0 adds a shared expert: one SwiGLU of that width
+    that every token meets, whole on every chip, whatever the routing; its
+    output is added to the held experts' part (scope ``moe_shared``)."""
 
     num_experts: int
     mlp_dim: int
@@ -387,6 +391,7 @@ class DroplessMoE(nn.Module):
     use_select_bias: bool = True
     norm_topk: bool = True
     scaling: float = 1.0
+    shared_mlp_dim: int = 0
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -425,4 +430,13 @@ class DroplessMoE(nn.Module):
         if not self.is_initializing():
             for name, value in counters.items():
                 self.sow("moe_metrics", name, value)
-        return y.reshape(batch, seq, dim)
+        y = y.reshape(batch, seq, dim)
+        if self.shared_mlp_dim:
+            from distributed_pytorch_example_tpu.models.llama import SwiGluMlp
+
+            with jax.named_scope("moe_shared"):
+                y = y + SwiGluMlp(
+                    mlp_dim=self.shared_mlp_dim, model_dim=dim,
+                    dtype=self.dtype, name="shared",
+                )(x, train=train)
+        return y

@@ -192,11 +192,18 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+# widths the flash kernels take for q/k and, independently, for v (latent
+# attention asks for 192 / 128: the 192 lanes of a q/k tile sit in two
+# 128-lane registers, the second half full)
+FLASH_HEAD_DIMS = (64, 128, 192, 256)
+
+
 def _flash_unsupported_reason(q, k, v, mask, causal) -> Optional[str]:
     """None if the flash kernel can serve this call, else a human reason."""
     if mask is not None:
         return "custom masks are not implemented in the flash kernel"
     seq_q, seq_k, head_dim = q.shape[1], k.shape[1], q.shape[-1]
+    v_dim = v.shape[-1]  # the values may be narrower than queries and keys
     if causal and seq_q != seq_k:
         # flash causal masking is top-left (row >= col) aligned; the XLA
         # reference is bottom-right aligned — they only agree for seq_q==seq_k
@@ -209,8 +216,11 @@ def _flash_unsupported_reason(q, k, v, mask, causal) -> Optional[str]:
         return "flash kernel is TPU-only"
     if seq_q % 128 or seq_k % 128:
         return f"seq lengths ({seq_q}, {seq_k}) not multiples of 128"
-    if head_dim not in (64, 128, 256):
-        return f"head_dim {head_dim} not in (64, 128, 256)"
+    if k.shape[-1] != head_dim:
+        return f"q and k head dims differ ({head_dim} != {k.shape[-1]})"
+    for what, width in (("head_dim", head_dim), ("v head_dim", v_dim)):
+        if width not in FLASH_HEAD_DIMS:
+            return f"{what} {width} not in {FLASH_HEAD_DIMS}"
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return f"dtype {q.dtype} not in (float32, bfloat16)"
     return None

@@ -28,6 +28,12 @@ block, GPT-2 at 1024): the kernel body walks static sub-tiles of
 sub-tile pairs at S 1024, ``causal_visited_pairs``), masking the diagonal
 sub-tiles alone. Non-causal calls run the whole-tile bodies.
 
+Widths: q and k share ``head_dim`` (what the scores contract over); v, the
+output and their gradients are ``v_dim`` wide, which may differ (latent
+attention trains at 192 / 128). Every BlockSpec, accumulator and gradient
+output takes its width from the operand it belongs to; at equal widths the
+calls are the ones a single ``head_dim`` gave.
+
 Layout: (batch, seq, heads, head_dim) at the boundary — transposed to
 (batch, heads, seq, head_dim) internally so the seq x head_dim tiles are
 contiguous MXU operands.
@@ -196,8 +202,11 @@ def _fwd_kernel(
 def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
     # q: (B, N, S, H); k, v: (B, K, S_k, H) with N % K == 0 (GQA: the kv
     # index maps route q-head n to kv-head n // group); kv_mask: (B, S_k)
-    # float 0/1 or None
+    # float 0/1 or None. v (and the output) may be narrower or wider than
+    # q and k: ``head_dim`` is the width the scores contract over,
+    # ``v_dim`` the width of the values (latent attention: 192 and 128)
     batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]
     seq_k = k.shape[2]
     group = heads // k.shape[1]
     if seq_k == block_k:  # whole key sequence in one block: plain softmax
@@ -241,8 +250,9 @@ def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
 
     qspec = pl.BlockSpec((1, 1, block_q, head_dim), qmap)
     kspec = pl.BlockSpec((1, 1, block_k, head_dim), kmap)
+    vspec = pl.BlockSpec((1, 1, block_k, v_dim), kmap)
     has_mask = kv_mask is not None
-    in_specs = [qspec, kspec, kspec]
+    in_specs = [qspec, kspec, vspec]
     inputs = [q, k, v]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 1, block_k), mmap))
@@ -258,17 +268,17 @@ def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            qspec,
+            pl.BlockSpec((1, 1, block_q, v_dim), qmap),
             # lse rides as (B, N, S, 1): block (…, block_q, 1) satisfies the
             # TPU tile rule (last dim == array dim, 2nd-to-last % 8 == 0)
             pl.BlockSpec((1, 1, block_q, 1), qmap),
         ],
         out_shape=[
-            _sds(q.shape, q.dtype, q),
+            _sds((batch, heads, seq_q, v_dim), q.dtype, q),
             _sds((batch, heads, seq_q, 1), jnp.float32, q),
         ],
         scratch_shapes=[
-            _vmem((block_q, head_dim)),  # acc
+            _vmem((block_q, v_dim)),     # acc
             _vmem((block_q, 128)),       # running max m (lane-replicated)
             _vmem((block_q, 128)),       # running normalizer l
         ],
@@ -279,14 +289,18 @@ def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
 
 def _fwd_single(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
     batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]
     group = heads // k.shape[1]
     grid = (batch, heads, seq_q // block_q)
     qspec = pl.BlockSpec((1, 1, block_q, head_dim), lambda b, n, i: (b, n, i, 0))
     kspec = pl.BlockSpec(
         (1, 1, block_k, head_dim), lambda b, n, i: (b, n // group, 0, 0)
     )
+    vspec = pl.BlockSpec(
+        (1, 1, block_k, v_dim), lambda b, n, i: (b, n // group, 0, 0)
+    )
     has_mask = kv_mask is not None
-    in_specs = [qspec, kspec, kspec]
+    in_specs = [qspec, kspec, vspec]
     inputs = [q, k, v]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, n, i: (b, 0, 0)))
@@ -307,11 +321,11 @@ def _fwd_single(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, n, i: (b, n, i, 0)),
+            pl.BlockSpec((1, 1, block_q, v_dim), lambda b, n, i: (b, n, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, n, i: (b, n, i, 0)),
         ],
         out_shape=[
-            _sds(q.shape, q.dtype, q),
+            _sds((batch, heads, seq_q, v_dim), q.dtype, q),
             _sds((batch, heads, seq_q, 1), jnp.float32, q),
         ],
         interpret=interpret,
@@ -791,18 +805,24 @@ def _bwd_single_causal_kernel(*refs, scale: float, sub: int, has_mask: bool):
 def _bwd_single(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
                 block_k, interpret):
     batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]  # of v, dO and dV; q, k, dQ, dK are head_dim wide
     seq_k = k.shape[2]
     group = heads // k.shape[1]
     grid = (batch, heads)
     qspec = pl.BlockSpec((1, 1, block_q, head_dim), lambda b, n: (b, n, 0, 0))
+    dospec = pl.BlockSpec((1, 1, block_q, v_dim), lambda b, n: (b, n, 0, 0))
     kspec = pl.BlockSpec(
         (1, 1, block_k, head_dim), lambda b, n: (b, n // group, 0, 0)
     )
+    vspec = pl.BlockSpec(
+        (1, 1, block_k, v_dim), lambda b, n: (b, n // group, 0, 0)
+    )
     # dK/dV accumulate PER Q-HEAD; group-summed by the caller (GQA)
     kspec_out = pl.BlockSpec((1, 1, block_k, head_dim), lambda b, n: (b, n, 0, 0))
+    vspec_out = pl.BlockSpec((1, 1, block_k, v_dim), lambda b, n: (b, n, 0, 0))
     rowspec = pl.BlockSpec((1, 1, block_q, 1), lambda b, n: (b, n, 0, 0))
     has_mask = kv_mask is not None
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    in_specs = [qspec, kspec, vspec, dospec, rowspec, rowspec]
     inputs = [q, k, v, do, lse, delta]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, n: (b, 0, 0)))
@@ -824,11 +844,11 @@ def _bwd_single(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
         name=name,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[qspec, kspec_out, kspec_out],
+        out_specs=[qspec, kspec_out, vspec_out],
         out_shape=[
             _sds(q.shape, q.dtype, q),
             _sds((batch, heads, seq_k, head_dim), k.dtype, q),
-            _sds((batch, heads, seq_k, head_dim), v.dtype, q),
+            _sds((batch, heads, seq_k, v_dim), v.dtype, q),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
@@ -853,33 +873,45 @@ _bwd_single_shared = jax.jit(_bwd_single, static_argnums=(7, 8, 9, 10, 11))
 _FUSED_DQ_VMEM_LIMIT = 8 * 1024 * 1024
 
 
-def _kmajor_specs(kv_mask, block_q, block_k, group, head_dim, inputs):
+def _kmajor_specs(kv_mask, block_q, block_k, group, head_dim, v_dim, inputs):
     """Shared spec construction for the k-block-major backward grid
     (j = k-block outer, i = q-block inner) — used by BOTH the fused kernel
     and the two-kernel fallback so their index maps can never diverge.
 
-    Returns (in_specs, inputs, qspec, kspec_out): qspec doubles as the dq
-    output spec; dK/dV outputs use kspec_out, which indexes PER Q-HEAD
-    (kv blocks are read via the group map, but writes must not race across
-    a group — callers group-sum afterwards).
+    Returns (in_specs, inputs, qspec, kspec_out, vspec_out): qspec doubles
+    as the dq output spec; the dK / dV outputs use kspec_out / vspec_out
+    (``head_dim`` / ``v_dim`` wide), which index PER Q-HEAD (kv blocks are
+    read via the group map, but writes must not race across a group —
+    callers group-sum afterwards).
     """
-    qspec = pl.BlockSpec(
-        (1, 1, block_q, head_dim), lambda b, n, j, i: (b, n, i, 0)
-    )
-    kspec = pl.BlockSpec(
-        (1, 1, block_k, head_dim), lambda b, n, j, i: (b, n // group, j, 0)
-    )
-    kspec_out = pl.BlockSpec(
-        (1, 1, block_k, head_dim), lambda b, n, j, i: (b, n, j, 0)
-    )
-    rowspec = pl.BlockSpec((1, 1, block_q, 1), lambda b, n, j, i: (b, n, i, 0))
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    def q_rows(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width), lambda b, n, j, i: (b, n, i, 0)
+        )
+
+    def k_rows(width, grouped):
+        if grouped:
+            return pl.BlockSpec(
+                (1, 1, block_k, width),
+                lambda b, n, j, i: (b, n // group, j, 0),
+            )
+        return pl.BlockSpec(
+            (1, 1, block_k, width), lambda b, n, j, i: (b, n, j, 0)
+        )
+
+    qspec = q_rows(head_dim)
+    in_specs = [
+        qspec, k_rows(head_dim, True), k_rows(v_dim, True), q_rows(v_dim),
+        q_rows(1), q_rows(1),
+    ]
     if kv_mask is not None:
         in_specs.append(
             pl.BlockSpec((1, 1, block_k), lambda b, n, j, i: (b, 0, j))
         )
         inputs = inputs + [kv_mask]
-    return in_specs, inputs, qspec, kspec_out
+    return (
+        in_specs, inputs, qspec, k_rows(head_dim, False), k_rows(v_dim, False)
+    )
 
 
 def _bwd_split(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
@@ -887,17 +919,22 @@ def _bwd_split(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
     """Separate dq and dk/dv kernels (two logits recomputes): the fallback
     when the fused kernel's dq scratch would not fit VMEM."""
     batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]
     seq_k = k.shape[2]
     group = heads // k.shape[1]
     has_mask = kv_mask is not None
 
     qspec = pl.BlockSpec((1, 1, block_q, head_dim), lambda b, n, i, j: (b, n, i, 0))
+    dospec = pl.BlockSpec((1, 1, block_q, v_dim), lambda b, n, i, j: (b, n, i, 0))
     kspec = pl.BlockSpec(
         (1, 1, block_k, head_dim), lambda b, n, i, j: (b, n // group, j, 0)
     )
+    vspec = pl.BlockSpec(
+        (1, 1, block_k, v_dim), lambda b, n, i, j: (b, n // group, j, 0)
+    )
     rowspec = pl.BlockSpec((1, 1, block_q, 1), lambda b, n, i, j: (b, n, i, 0))
 
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    in_specs = [qspec, kspec, vspec, dospec, rowspec, rowspec]
     inputs = [q, k, v, do, lse, delta]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, n, i, j: (b, 0, j)))
@@ -917,8 +954,8 @@ def _bwd_split(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
     )(*inputs)
 
     # k-block-major grid: q streams innermost
-    in_specs_t, inputs_t, _, kspec_out = _kmajor_specs(
-        kv_mask, block_q, block_k, group, head_dim,
+    in_specs_t, inputs_t, _, kspec_out, vspec_out = _kmajor_specs(
+        kv_mask, block_q, block_k, group, head_dim, v_dim,
         [q, k, v, do, lse, delta],
     )
     dk, dv = pl.pallas_call(
@@ -929,23 +966,24 @@ def _bwd_split(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
         name="flash_bwd_dkv",
         grid=(batch, heads, seq_k // block_k, seq_q // block_q),
         in_specs=in_specs_t,
-        out_specs=[kspec_out, kspec_out],
+        out_specs=[kspec_out, vspec_out],
         out_shape=[
             _sds((batch, heads, seq_k, head_dim), k.dtype, q),
-            _sds((batch, heads, seq_k, head_dim), v.dtype, q),
+            _sds((batch, heads, seq_k, v_dim), v.dtype, q),
         ],
-        scratch_shapes=[_vmem((block_k, head_dim)), _vmem((block_k, head_dim))],
+        scratch_shapes=[_vmem((block_k, head_dim)), _vmem((block_k, v_dim))],
         interpret=interpret,
     )(*inputs_t)
     if group > 1:  # GQA: fold the per-q-head contributions into kv heads
         dk = dk.reshape(batch, k.shape[1], group, seq_k, head_dim).sum(2)
-        dv = dv.reshape(batch, v.shape[1], group, seq_k, head_dim).sum(2)
+        dv = dv.reshape(batch, v.shape[1], group, seq_k, v_dim).sum(2)
     return dq, dk, dv
 
 
 def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
          interpret, delta=None):
     batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]
     seq_k = k.shape[2]
     group = heads // k.shape[1]
     if delta is None:
@@ -965,7 +1003,7 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
         )
         if group > 1:
             dk = dk.reshape(batch, k.shape[1], group, seq_k, head_dim).sum(2)
-            dv = dv.reshape(batch, v.shape[1], group, seq_k, head_dim).sum(2)
+            dv = dv.reshape(batch, v.shape[1], group, seq_k, v_dim).sum(2)
         return dq, dk, dv
     has_mask = kv_mask is not None
     if seq_q * head_dim * 4 > _FUSED_DQ_VMEM_LIMIT:
@@ -1006,19 +1044,24 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
             return (b, 0, j)
 
         qspec_t = pl.BlockSpec((1, 1, block_q, head_dim), fqmap)
-        kspec_f = pl.BlockSpec((1, 1, block_k, head_dim), fkmap)
         kspec_out = pl.BlockSpec((1, 1, block_k, head_dim), fkout)
+        vspec_out = pl.BlockSpec((1, 1, block_k, v_dim), fkout)
         rowspec_f = pl.BlockSpec((1, 1, block_q, 1), fqmap)
-        in_specs_t = [qspec_t, kspec_f, kspec_f, qspec_t, rowspec_f,
-                      rowspec_f]
+        in_specs_t = [
+            qspec_t,
+            pl.BlockSpec((1, 1, block_k, head_dim), fkmap),
+            pl.BlockSpec((1, 1, block_k, v_dim), fkmap),
+            pl.BlockSpec((1, 1, block_q, v_dim), fqmap),
+            rowspec_f, rowspec_f,
+        ]
         inputs_t = [q, k, v, do, lse, delta]
         if has_mask:
             in_specs_t.append(pl.BlockSpec((1, 1, block_k), fmmap))
             inputs_t.append(kv_mask)
     else:
         grid = (batch, heads, seq_k // block_k, ni)
-        in_specs_t, inputs_t, qspec_t, kspec_out = _kmajor_specs(
-            kv_mask, block_q, block_k, group, head_dim,
+        in_specs_t, inputs_t, qspec_t, kspec_out, vspec_out = _kmajor_specs(
+            kv_mask, block_q, block_k, group, head_dim, v_dim,
             [q, k, v, do, lse, delta],
         )
     dq, dk, dv = pl.pallas_call(
@@ -1030,15 +1073,15 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
         name="flash_bwd_fused",
         grid=grid,
         in_specs=in_specs_t,
-        out_specs=[qspec_t, kspec_out, kspec_out],
+        out_specs=[qspec_t, kspec_out, vspec_out],
         out_shape=[
             _sds(q.shape, q.dtype, q),
             _sds((batch, heads, seq_k, head_dim), k.dtype, q),
-            _sds((batch, heads, seq_k, head_dim), v.dtype, q),
+            _sds((batch, heads, seq_k, v_dim), v.dtype, q),
         ],
         scratch_shapes=[
             _vmem((block_k, head_dim)),
-            _vmem((block_k, head_dim)),
+            _vmem((block_k, v_dim)),
             _vmem((seq_q, head_dim)),  # persistent dq accumulator
         ],
         # the persistent dq scratch pushes past the 16 MB default scoped
@@ -1050,7 +1093,7 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
     )(*inputs_t)
     if group > 1:  # GQA: fold the per-q-head contributions into kv heads
         dk = dk.reshape(batch, k.shape[1], group, seq_k, head_dim).sum(2)
-        dv = dv.reshape(batch, v.shape[1], group, seq_k, head_dim).sum(2)
+        dv = dv.reshape(batch, v.shape[1], group, seq_k, v_dim).sum(2)
     return dq, dk, dv
 
 

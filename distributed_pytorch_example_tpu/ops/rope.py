@@ -1,4 +1,4 @@
-"""Rotary position embeddings (RoPE), rotate-half formulation.
+"""Rotary position embeddings (RoPE), rotate-half or adjacent-pair form.
 
 Position information injected by rotating each (q, k) head-dim pair by a
 position-dependent angle — no learned position table, exact relative
@@ -9,7 +9,9 @@ shard's global ``positions`` so rotations stay globally consistent.
 
 The rotate-half (GPT-NeoX / LLaMA) convention: the head dim is split in
 halves (x1, x2) and rotated as (x1·cos − x2·sin, x2·cos + x1·sin) with
-frequencies theta^(−2i/d).
+frequencies theta^(−2i/d). ``interleaved=True`` rotates ADJACENT pairs
+(x[2i], x[2i+1]) by the same angles instead (the GPT-J / DeepSeek
+``rope_interleave`` convention) and leaves each pair where it was.
 """
 
 from __future__ import annotations
@@ -24,6 +26,7 @@ def rope(
     x: jax.Array,
     positions: Optional[jax.Array] = None,
     theta: float = 10000.0,
+    interleaved: bool = False,
 ) -> jax.Array:
     """Rotate (B, S, N, H) queries or keys by their positions.
 
@@ -39,6 +42,18 @@ def rope(
     if positions is None:
         positions = jnp.arange(x.shape[1])
     freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if interleaved:
+        if positions.ndim != 1:
+            raise ValueError("interleaved RoPE takes (S,) positions")
+        angles = positions.astype(jnp.float32)[:, None] * freqs  # (S, half)
+        cos = jnp.cos(angles)[None, :, None, :]
+        sin = jnp.sin(angles)[None, :, None, :]
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        rotated = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        )
+        return rotated.reshape(x.shape).astype(x.dtype)
     if positions.ndim == 2:  # (B, S): per-row offsets
         angles = positions.astype(jnp.float32)[..., None] * freqs
         cos = jnp.cos(angles)[:, :, None, :]

@@ -155,6 +155,11 @@ class CausalLMTask:
     The model sees the FULL sequence (keeping seq_len block-aligned so the
     flash kernel stays eligible); position t's logits predict token t+1, and
     the final position's logits are simply excluded from the loss.
+
+    A model with a multi-token-prediction module returns a pair in training:
+    its second member's position t predicts token t+2 (the last two
+    positions excluded) through the SAME head, and the loss is ``loss_next
+    + model.mtp_loss_weight * loss_mtp``; both parts ride in the metrics.
     """
 
     batch_keys = ("tokens",)
@@ -170,24 +175,37 @@ class CausalLMTask:
         out, new_ms, aux, extra = _apply_model(
             model, params, model_state, tokens, rng, train
         )
-        targets = tokens[:, 1:]
-        if _fused_head(model):
+        out, mtp_out = out if isinstance(out, tuple) else (out, None)
+        fused = _fused_head(model)
+        if fused:
             from distributed_pytorch_example_tpu.ops.chunked_ce import (
                 chunked_softmax_xent,
             )
 
             embedding, bias = type(model).head_params(params)
-            per_tok, argmax = chunked_softmax_xent(
-                out[:, :-1], embedding, targets, bias=bias, dtype=model.dtype
+
+        def ahead(out, n):
+            """Position t against token t + n: (losses, argmax, targets)."""
+            targets = tokens[:, n:]
+            if fused:
+                return *chunked_softmax_xent(
+                    out[:, :-n], embedding, targets, bias=bias,
+                    dtype=model.dtype,
+                ), targets
+            logits = out[:, :-n]
+            per_tok = optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), targets
             )
-            loss = per_tok.mean() + aux
-            accuracy = 100.0 * jnp.mean(argmax == targets)
-            return loss, {"loss": loss, "accuracy": accuracy, **extra}, new_ms
-        logits = out[:, :-1]
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), targets
-        ).mean() + aux
-        accuracy = 100.0 * jnp.mean(jnp.argmax(logits, axis=-1) == targets)
+            return per_tok, jnp.argmax(logits, axis=-1), targets
+
+        per_tok, argmax, targets = ahead(out, 1)
+        loss = per_tok.mean()
+        if mtp_out is not None:
+            loss_mtp = ahead(mtp_out, 2)[0].mean()
+            extra = {**extra, "loss_next": loss, "loss_mtp": loss_mtp}
+            loss = loss + model.mtp_loss_weight * loss_mtp
+        loss = loss + aux
+        accuracy = 100.0 * jnp.mean(argmax == targets)
         return loss, {"loss": loss, "accuracy": accuracy, **extra}, new_ms
 
     def _pipelined_1f1b(self, model, params, model_state, tokens, rng):

@@ -114,17 +114,19 @@ def add_framework_args(parser: argparse.ArgumentParser) -> argparse.ArgumentPars
                         help="a deployment's share: the layers of the "
                         "published stack that this pipeline stage holds, as "
                         "rising indices (0,2,3,4,5); models with "
-                        "layers_kept (lfm2-8b-a1b)")
+                        "layers_kept (lfm2-8b-a1b, joyai-llm-flash)")
     parser.add_argument("--experts-held", type=str, default=None,
                         help="a deployment's share: FIRST,COUNT of the "
                         "published experts that this chip holds in every "
                         "expert layer (0,8); the router keeps its published "
-                        "width; models with experts_held (lfm2-8b-a1b)")
+                        "width; models with experts_held (lfm2-8b-a1b, "
+                        "joyai-llm-flash)")
     parser.add_argument("--vocab-slice", type=int, default=None,
                         help="a deployment's share: the size of this chip's "
                         "slice of the vocabulary (table, logits and loss "
                         "are over the slice; synthetic tokens are drawn "
-                        "from it); models with layers_kept (lfm2-8b-a1b)")
+                        "from it); models with layers_kept (lfm2-8b-a1b, "
+                        "joyai-llm-flash)")
     parser.add_argument("--lm-loss", type=str, default="fused",
                         choices=("fused", "dense"),
                         help="LM-head loss path: fused = chunked vocab "

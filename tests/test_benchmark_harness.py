@@ -8,8 +8,11 @@ selections run as they are, each in a process of its own: the operation
 counts (``test_flops.py``), the reference's three steps and its memory shape
 (``test_reference_steps.py``), the counts of the program's that are held to
 zero (``exact_zero``: ``test_run.py -k held_to_zero``) and the readers the
-lfm2 cell brings (``test_layer_metrics_lfm2.py``). The cells' files are read
-here, in this process, by the harness' own ``load_cell``.
+lfm2 cell brings (``test_layer_metrics_lfm2.py``), those the joyai cell
+brings (``test_layer_metrics_joyai.py``) and that cell's ``correct`` at a
+tiny size, sound and with its own two faults (``test_correct_joyai.py``).
+The cells' files are read here, in this process, by the harness' own
+``load_cell``.
 """
 
 import importlib.util
@@ -24,6 +27,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
 
 LFM2_CELL = "lfm2-8b-a1b.train-ep4share-s8192"
+JOYAI_CELL = "joyai-llm-flash.train-ep32share-s4096"
 
 
 def _bench_module(name):
@@ -57,10 +61,12 @@ def bench():
         ["tests/test_layer_metrics_lfm2.py"],
         ["tests/test_reduce.py"],
         ["tests/test_program_spans.py"],
+        ["tests/test_layer_metrics_joyai.py"],
+        ["tests/test_correct_joyai.py", "-k", "sound or rotary or bfloat16"],
     ],
     ids=[
         "flops", "reference_steps", "run", "layer_metrics_lfm2", "reduce",
-        "program_spans",
+        "program_spans", "layer_metrics_joyai", "correct_joyai",
     ],
 )
 def test_the_benchmarks_own_tests_pass(selection):
@@ -190,3 +196,123 @@ def test_the_lfm2_cells_metrics_have_their_readers(bench):
     weights = 8 * 3 * 2048 * 1792
     rows = 32_768 * ((2048 + 2 * 1792) + (1792 + 2048))
     assert nbytes == 3 * 2 * (weights + rows)
+
+
+def test_the_joyai_cell_counts_what_the_issue_counted(harness):
+    """The cell's ``shape`` by hand: 308,805,632 matmul weights a token with
+    the head twice, 2,607,808,512 operations a token at 4096; each layer
+    kind's count worked from the widths in the file; ``head_dim`` 160
+    counts the two attention products at 192 and 128."""
+    flops = _bench_module("flops")
+    cell, config, traffic, _ = harness.load_cell(JOYAI_CELL)
+    shape = config["shape"]
+    assert cell["chips"] == 1 and traffic["seq_len"] == 4096
+    assert traffic["rows_per_chip"] == 4 and traffic["reference_block_rows"] == 1
+    assert traffic["dataset_rows"] == 512 and traffic["trace_seconds"] == 4.0
+    assert "--remat" in traffic["train_argv"]
+    assert traffic["adam"] == {"lr": 1e-5, "b1": 0.9, "b2": 0.999, "eps": 1e-8}
+    assert flops.matmul_params(shape) == 308_805_632
+    assert flops.train_flops_per_token(shape, traffic["seq_len"]) == 2_607_808_512
+
+    d, heads = config["hidden_size"], config["num_attention_heads"]
+    nope, rot, v = (
+        config["qk_nope_head_dim"], config["qk_rope_head_dim"], config["v_head_dim"]
+    )
+    assert nope + rot == config["qk_head_dim"] == 192 and v == 128
+    mla = (
+        d * config["q_lora_rank"] + config["q_lora_rank"] * heads * (nope + rot)
+        + d * (config["kv_lora_rank"] + rot)
+        + config["kv_lora_rank"] * heads * (nope + v) + heads * v * d
+    )
+    assert mla == 26_345_472
+    narrow = config["moe_intermediate_size"]
+    held, published = config["n_routed_experts"], config["published"]["n_routed_experts"]
+    experts = (
+        d * published + config["n_shared_experts"] * 3 * d * narrow
+        + config["num_experts_per_tok"] * 3 * d * narrow * held // published
+    )
+    kinds = {k["name"]: k for k in shape["layer_kinds"]}
+    assert kinds["mla_dense"]["matmul_params"] == mla + 3 * d * config["intermediate_size"]
+    assert kinds["mla_experts"]["matmul_params"] == mla + experts == 32_768_000
+    assert kinds["mtp_mla_experts"]["matmul_params"] == mla + experts + 2 * d * d
+    dense = sum(i < config["first_k_dense_replace"] for i in config["layers_kept"])
+    assert [k["count"] for k in shape["layer_kinds"]] == [
+        dense, len(config["layers_kept"]) - dense, config["num_nextn_predict_layers"],
+    ]
+    assert all(k["attention"] for k in shape["layer_kinds"])
+    assert shape["heads"] * shape["head_dim"] * 2 == heads * (nope + rot + v)
+    assert shape["head_token_share"] == 1 + config["num_nextn_predict_layers"]
+    # the rows' bound at the cell's tokens, derived and not a flag
+    sys.path.insert(0, ROOT)
+    from distributed_pytorch_example_tpu.models.moe import dropless_rows_bound
+
+    tokens = traffic["rows_per_chip"] * traffic["seq_len"]
+    assert dropless_rows_bound(
+        tokens, config["num_experts_per_tok"], held, published
+    ) == 8192
+
+
+def test_the_joyai_configuration_states_its_source_and_its_share(harness, bench):
+    """Every number of the catalog's config is in the file under its key,
+    but for the reduced ones, which stand beside their published values;
+    the share is the one the program is told on its command line."""
+    _, config, _, _ = harness.load_cell(JOYAI_CELL)
+    (entry,) = [c for c in bench["configs"] if c["name"] == "joyai-llm-flash"]
+    assert entry["reduced"] == config["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"
+    ]
+    assert entry["source"] == config["source"]
+    assert config["published"] == {
+        "num_hidden_layers": 40, "n_routed_experts": 256, "vocab_size": 129280
+    }
+    for key in config["reduced"]:
+        assert config[key] != config["published"][key], key
+    published = {
+        "attention_bias": False, "first_k_dense_replace": 1, "hidden_size": 2048,
+        "intermediate_size": 7168, "kv_lora_rank": 512, "q_lora_rank": 1536,
+        "moe_intermediate_size": 768, "n_shared_experts": 1, "n_group": 1,
+        "topk_group": 1, "num_attention_heads": 32, "num_experts_per_tok": 8,
+        "num_nextn_predict_layers": 1, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128, "rms_norm_eps": 1e-6,
+        "rope_interleave": True, "rope_scaling": None, "rope_theta": 32000000,
+        "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+        "tie_word_embeddings": False, "topk_method": "noaux_tc",
+        "norm_topk_prob": True, "model_type": "joyai_llm_flash",
+    }
+    assert {k: config[k] for k in published} == published
+    argv = config["train_argv"]
+    said = {flag: argv[argv.index(flag) + 1] for flag in argv if flag.startswith("--")}
+    assert said["--model"] == "joyai-llm-flash"
+    assert said["--layers-kept"] == ",".join(map(str, config["layers_kept"]))
+    assert said["--experts-held"] == f"{config['experts_first']},{config['n_routed_experts']}"
+    assert int(said["--vocab-slice"]) == config["vocab_size"] == config["shape"]["vocab"]
+    assert len(config["layers_kept"]) == config["num_hidden_layers"]
+    assert config["exact_zero"] == ["train_moe_dropped_assignments"]
+    assert config["deployment"]["chips_sharing_a_layer"] == 32
+    assert config["deployment"]["parameters_here"] == 491_696_128
+    assert config["init"] == {"std": 0.02, "select_bias_std": 0.02}
+    for assumed in ("mtp_composition", "mtp_eh_input_order", "mtp_hidden_state",
+                    "mtp_loss_weight", "select_bias", "aux_loss",
+                    "initializer_range", "routing_weight_epsilon",
+                    "max_position_embeddings"):
+        assert config["assumed"][assumed]
+
+
+def test_the_joyai_cells_metrics_have_their_readers(bench):
+    """Each per-layer metric that names the cell, and only the six this
+    cell brings do, has its reader's file; no accepted list gained it."""
+    named = [m for m in bench["per_layer"] if JOYAI_CELL in m.get("workloads", [])]
+    assert sorted(m["name"] for m in named) == [
+        "mla_flash_bwd_roofline", "mla_flash_fwd_roofline",
+        "mla_proj_device_share", "moe_held_share", "moe_shared_device_share",
+        "mtp_device_share",
+    ]
+    for metric in named:
+        assert metric["moves"] == "tokens_per_s_per_chip"
+        assert metric["workloads"] == [JOYAI_CELL] and metric["unit"] == "%"
+        assert os.path.exists(
+            os.path.join(BENCH_DIR, "layer_metrics", metric["name"] + ".py")
+        )
+    assert bench["per_layer"][-6:] == named  # appended, nothing moved
+    without_a_list = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
+    assert len(without_a_list) == 10

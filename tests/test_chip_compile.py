@@ -356,6 +356,36 @@ def test_flash_gqa_8k_compiles(one_chip, no_persistent_cache, direction):
     ), names
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_mla_192_128_compiles(one_chip, no_persistent_cache, direction):
+    """Latent attention as it trains: 32 heads, queries and keys 192 wide,
+    values 128 wide, causal at 4096 tokens (the ``joyai-llm-flash`` cell's
+    call): the multi-block kernels, by the names the benchmark's
+    ``mla_flash_*_roofline`` readers match, with no value padded to 192."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+    )
+
+    sds = functools.partial(
+        jax.ShapeDtypeStruct, dtype=jnp.bfloat16, sharding=one_chip
+    )
+    q = k = sds((4, 4096, 32, 192))
+    v = sds((4, 4096, 32, 128))
+    fwd = functools.partial(flash_attention, causal=True)
+    loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    compiled = _compile(fn, q, k, v)
+    names = _kernel_names(compiled)
+    assert not any("_single" in n for n in names), names
+    assert any("flash_fwd" in n for n in names), names
+    assert any("flash_bwd_fused" in n for n in names) == (direction == "bwd")
+    if direction == "bwd":
+        dq, dk, dv = compiled.out_info
+        assert (dq.shape[-1], dk.shape[-1], dv.shape[-1]) == (192, 192, 128)
+    else:
+        assert compiled.out_info.shape == (4, 4096, 32, 128)
+
+
 @pytest.mark.parametrize("product", ["gate_up", "down"])
 @pytest.mark.parametrize("program", ["fwd", "d_rows", "d_weights"])
 def test_grouped_product_compiles(one_chip, no_persistent_cache, product, program):

@@ -705,3 +705,69 @@ def test_flash_on_mesh_is_a_shard_map(mesh_2x2x2):
     assert "shard_map" not in str(jax.make_jaxpr(fn)(q, k, v))
     with mesh_2x2x2:
         assert "shard_map" in str(jax.make_jaxpr(fn)(q, k, v))
+
+
+# -- q/k heads wider than v heads (latent attention: 192 / 128) ---------------
+
+# path -> (seq, causal, blocks, kv heads of 4, force the two-kernel backward)
+WIDTH_PATHS = {
+    "single-tile": (256, False, None, 4, False),
+    "single-tile-causal-subtiles": (256, True, None, 4, False),
+    "multi-block-folded-causal": (256, True, (128, 128), 4, False),
+    "multi-block-square-gqa": (256, False, (128, 64), 2, False),
+    "two-kernel-backward": (256, True, (128, 64), 4, True),
+}
+
+
+@pytest.mark.parametrize("dims", [(192, 128), (64, 64)], ids=["192-128", "64-64"])
+@pytest.mark.parametrize("path", sorted(WIDTH_PATHS))
+def test_value_width_apart_from_query_key_width(dims, path, monkeypatch):
+    """Forward and all three gradients against the XLA attention where the
+    values are narrower than queries and keys, on every kernel path: the
+    single tile (whole and causal sub-tiles), the fused multi-block backward
+    (folded and square grids, grouped keys) and the two-kernel fallback; and
+    at equal widths, where nothing may change."""
+    from distributed_pytorch_example_tpu.ops.pallas import (
+        flash_attention as fa,
+    )
+
+    qk_dim, v_dim = dims
+    seq, causal, blocks, kv_heads, split = WIDTH_PATHS[path]
+    if split:
+        monkeypatch.setattr(fa, "_FUSED_DQ_VMEM_LIMIT", 0)
+    rng = np.random.default_rng(41)
+    q = jnp.asarray(rng.standard_normal((2, seq, 4, qk_dim)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, seq, kv_heads, qk_dim)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, seq, kv_heads, v_dim)), jnp.float32)
+    scale = qk_dim ** -0.5
+    kwargs = {} if blocks is None else dict(block_q=blocks[0], block_k=blocks[1])
+
+    def loss_ref(q, k, v):
+        out = _xla_attention(q, k, v, None, None, causal, scale)
+        return jnp.sum(out ** 2), out
+
+    def loss_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, interpret=True, **kwargs)
+        return jnp.sum(out ** 2), out
+
+    (_, want), g_ref = jax.value_and_grad(loss_ref, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, got), g_flash = jax.value_and_grad(loss_flash, (0, 1, 2), has_aux=True)(q, k, v)
+    assert got.shape == (2, seq, 4, v_dim)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    for gr, gf, name in zip(g_ref, g_flash, "qkv"):
+        assert gf.shape == gr.shape, name
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
+        )
+
+
+def test_dispatcher_admits_192_with_128_wide_values(monkeypatch):
+    from distributed_pytorch_example_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((4, 4096, 32, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16)
+    assert attention._flash_unsupported_reason(q, q, v, None, True) is None
+    odd = jax.ShapeDtypeStruct((4, 4096, 32, 96), jnp.bfloat16)
+    assert "v head_dim 96" in attention._flash_unsupported_reason(q, q, odd, None, True)
+    assert "differ" in attention._flash_unsupported_reason(q, v, v, None, True)

@@ -8,7 +8,9 @@ rules, where an instrumented ``fit`` puts each span (names are a contract
 the benchmark's per-layer readers rely on), that the same names land in a
 ``jax.profiler`` trace on the training thread, the compile log, and the
 ``chunked_ce`` / ``optimizer`` scopes inside the lowered step, the expert
-layer's and the short convolution's scopes, and the ``moe_counters`` rows.
+layer's and the short convolution's scopes, latent attention's, the shared
+expert's and the prediction module's (``mla_proj``, ``moe_shared``, ``mtp``),
+and the ``moe_counters`` rows.
 """
 
 import collections
@@ -591,6 +593,51 @@ def test_lowered_step_carries_the_expert_and_convolution_scopes(remat):
         assert found, scope
         if scope != "optimizer":  # the backward pass carries the name too
             assert any("transpose(" in n for n in found), scope
+
+
+TINY_JOYAI = dict(
+    vocab_size=64, model_dim=16, num_heads=2, q_lora_rank=12, kv_lora_rank=8,
+    qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, mlp_dim=32,
+    moe_mlp_dim=8, num_experts=8, top_k=2, layers_kept=(0, 1),
+    experts_first=0, experts_held=4, logits_mode="hidden",
+)
+JOYAI_SCOPES = ("mla_proj", "moe_shared", "mtp")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lowered_step_carries_the_latent_attention_and_module_scopes(remat):
+    """The names the benchmark's ``nested_scopes`` reader looks for, as
+    path components of the lowered step's op names, forward and backward;
+    the prediction module's own layer carries ``mtp`` AND what nests in it,
+    its pass through the head carries ``chunked_ce`` and not ``mtp``; the
+    step's metrics hold the two losses apart."""
+    from distributed_pytorch_example_tpu.train.tasks import CausalLMTask
+
+    model = dpx.models.get_model("joyai-llm-flash", remat=remat, **TINY_JOYAI)
+    trainer = dpx.train.Trainer(model, CausalLMTask(), optax.adam(1e-3))
+    batch = {"tokens": jnp.zeros((4, 16), jnp.int32)}
+    trainer.init(batch["tokens"])
+    text = trainer.train_step.lower(trainer.state, batch).as_text(
+        debug_info=True
+    )
+    names = re.findall(r'loc\("(jit\(train_step\)[^"]*)"', text)
+
+    def carrying(scope, among=names):
+        component = re.compile(rf"(?:^|[/(]){scope}(?:[/)]|$)")
+        return [n for n in among if component.search(n)]
+
+    for scope in JOYAI_SCOPES + MOE_SCOPES[:3] + ("chunked_ce", "optimizer"):
+        found = carrying(scope)
+        assert found, scope
+        if scope != "optimizer":  # the backward pass carries the name too
+            assert any("transpose(" in n for n in found), scope
+    inside = carrying("mtp")
+    assert carrying("mla_proj", inside) and carrying("moe_shared", inside)
+    assert any("layer_40" in n for n in inside)
+    assert not carrying("chunked_ce", inside)
+    assert len(carrying("mla_proj")) > len(carrying("mla_proj", inside))
+    _, metrics = trainer.train_step(trainer.state, batch)
+    assert {"loss_next", "loss_mtp", "moe_held_share"} <= set(metrics)
 
 
 def test_fit_leaves_moe_counters_rows_at_each_log_fetch(devices, record):

@@ -317,7 +317,8 @@ def main(argv=None, devices=None):
         for flag, value in share.items():
             if value is not None and not has("layers_kept"):
                 parser.error(f"{flag} states a deployment's share, which "
-                             f"{args.model!r} has none of (lfm2-8b-a1b has)")
+                             f"{args.model!r} has none of (lfm2-8b-a1b and "
+                             f"joyai-llm-flash have)")
         try:
             if args.layers_kept is not None:
                 overrides["layers_kept"] = tuple(
