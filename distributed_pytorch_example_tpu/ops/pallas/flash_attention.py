@@ -18,15 +18,24 @@ the dq scratch would exceed ``_FUSED_DQ_VMEM_LIMIT``, the historical
 two-kernel split (separate dq and dk/dv passes, two logits recomputes)
 serves as the fallback.
 
-Causal masking skips masked work on every path, at the grain that path has.
-Multi-block grid: fully-masked (q-block, k-block) pairs skip their compute
-(``needed``) or are never scheduled (the folded triangular grid); the blocks
-on the diagonal still compute their masked half. Single tile (S == one
-block, GPT-2 at 1024): the kernel body walks static sub-tiles of
-``CAUSAL_SUB`` rows and gives each only the key prefix it can see
-(``flash_fwd_single_causal`` / ``flash_bwd_single_causal``: 10 of 16
-sub-tile pairs at S 1024, ``causal_visited_pairs``), masking the diagonal
-sub-tiles alone. Non-causal calls run the whole-tile bodies.
+Causal masking skips masked work on every path, down to static sub-tiles.
+Multi-block grid (``_when_by_block_kind``: two ``pl.when`` bodies in the one
+``pallas_call``, so the kernels keep their names): a (q-block, k-block) pair
+above the diagonal skips its compute (``needed``) or is never scheduled (the
+folded triangular grid); a pair wholly UNDER it runs the body with no mask
+traced into it; a pair ON it walks sub-tiles and leaves what no query of
+them sees uncomputed. Backward (fused and two-kernel): sub-tiles of
+``CAUSAL_SUB`` rows, key-major, the ``sub x sub`` tile on the diagonal alone
+masked (136 of 256 sub-tile pairs at S 4096, 528 of 1024 at S 8192:
+``causal_visited_pairs``). Forward: query sub-tiles of
+``CAUSAL_SUB_FWD_BLOCK`` rows, each one product against its key prefix,
+folded into the running online softmax together (3 of 4 pairs of a block).
+Blocks that are not square, or too short to cut (``_causal_sub`` gives 0),
+keep the whole-block mask on the diagonal. Single tile (S == one block,
+GPT-2 at 1024): the kernel body walks sub-tiles of ``CAUSAL_SUB`` rows and
+gives each only the key prefix it can see (``flash_fwd_single_causal`` /
+``flash_bwd_single_causal``: 10 of 16 sub-tile pairs at S 1024), masking the
+diagonal sub-tiles alone. Non-causal calls run the whole-block bodies.
 
 Widths: q and k share ``head_dim`` (what the scores contract over); v, the
 output and their gradients are ``v_dim`` wide, which may differ (latent
@@ -68,16 +77,24 @@ def _fit_block(seq: int, requested: int) -> int:
     return b if seq % b == 0 else min(requested, seq)
 
 
-# rows per query sub-tile of the single-tile causal kernels (see
-# _fwd_single_causal_kernel); fitted to the sequence by _causal_sub
+# rows per query sub-tile of the causal walks: one causal tile (see
+# _fwd_single_causal_kernel) and the backward's blocks on the diagonal of
+# the multi-block grid; fitted to the tile or block by _causal_sub
 CAUSAL_SUB = 256
+# ... of the multi-block FORWARD's blocks on the diagonal, where every
+# sub-tile pays an online-softmax fold of its own: on the v5e 512 rows
+# (3 of 4 sub-tile pairs) read faster than 256 (10 of 16) at q/k 192 and
+# as fast at 64, the backward and the single tile the other way round
+# (PERF.md, PR 34)
+CAUSAL_SUB_FWD_BLOCK = 512
 
 
-def _causal_sub(seq: int) -> int:
-    """Sub-tile rows for a causal single tile of ``seq``: the largest
-    multiple of 128 <= CAUSAL_SUB that divides ``seq`` into two or more
-    sub-tiles, or 0 (the whole-tile body runs) where there is none."""
-    b = min(CAUSAL_SUB, seq // 2) // 128 * 128
+def _causal_sub(seq: int, rows: Optional[int] = None) -> int:
+    """Sub-tile rows for a causal tile or diagonal block of ``seq`` rows:
+    the largest multiple of 128 <= ``rows`` (CAUSAL_SUB by default) that
+    divides ``seq`` into two or more sub-tiles, or 0 (the whole-tile body
+    runs) where there is none."""
+    b = min(CAUSAL_SUB if rows is None else rows, seq // 2) // 128 * 128
     while b and seq % b:
         b -= 128
     return b
@@ -89,23 +106,95 @@ def _causal_prefixes(seq: int, sub: int):
     return [(row, row + sub) for row in range(0, seq, sub)]
 
 
-def causal_visited_pairs(seq: int, sub: int):
+def causal_visited_pairs(seq: int, sub: int, block: Optional[int] = None):
     """(visited, total) sub x sub tile pairs of the seq x seq causal
-    square when each query sub-tile is given only its key prefix."""
-    prefixes = _causal_prefixes(seq, sub)
-    return sum(prefix // sub for _, prefix in prefixes), len(prefixes) ** 2
+    square, cut in square blocks of ``block`` rows (one tile by default):
+    a block under the diagonal is visited whole, a block on it gives each
+    query sub-tile only its key prefix, a block above it is not visited."""
+    block = block or seq
+    blocks, side = seq // block, block // sub
+    on = sum(prefix // sub for _, prefix in _causal_prefixes(block, sub))
+    under = blocks * (blocks - 1) // 2 * side ** 2
+    return under + blocks * on, (blocks * side) ** 2
 
 
-def _apply_causal_mask(s, i, j, block_q, block_k):
-    """Top-left-aligned causal mask on a (block_q, block_k) logit tile.
+def _apply_causal_mask(s, row0, col0):
+    """Top-left-aligned causal mask on a logit tile whose first query is
+    row ``row0`` and whose first key is column ``col0`` of the sequence.
 
     Valid for seq_q == seq_k (the dispatcher rejects causal cross-length
     calls); shared by the forward and both backward kernels so the
     alignment can never diverge between them.
     """
-    row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(row >= col, s, NEG_INF)
+
+
+def _stack_rows(parts):
+    """Row sub-tiles back into one block (one part: the block itself)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _when_by_block_kind(causal, i, j, block_q, block_k, needed, block,
+                        diagonal, sub_rows=None):
+    """Give the block pair (i, j) of a multi-block grid the body it needs.
+
+    Not causal: ``block(False)`` where ``needed``. Causal, by where the
+    pair lies (pairs above the diagonal are not ``needed`` or never
+    scheduled): wholly UNDER the diagonal, its last key no later than its
+    first query, ``block(False)``: no mask is traced into it; ON it,
+    ``diagonal(sub)`` where the blocks are square and ``_causal_sub`` cuts
+    them into static sub-tiles of ``sub`` rows (at most ``sub_rows``; the
+    block's first query is then its first key, and the walk is the single
+    tile's), else ``block(True)``: the whole block computed and masked.
+    """
+    if not causal:
+        pl.when(needed)(lambda: block(False))
+        return
+    under = (j + 1) * block_k - 1 <= i * block_q
+    sub = _causal_sub(block_q, sub_rows) if block_q == block_k else 0
+    pl.when(needed & under)(lambda: block(False))
+    pl.when(needed & ~under)(lambda: diagonal(sub) if sub else block(True))
+
+
+def _subtile_grads(refs, rows, cols, scale, diagonal, want="qkv"):
+    """(dq, dk, dv) contributions of the logits of ``rows`` x ``cols``
+    (static slices of the blocks in VMEM), None for those not in ``want``;
+    ``diagonal``: the square tile the causal diagonal crosses, the only one
+    masked."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref = refs
+    q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+    k, v = k_ref[0, 0, cols, :], v_ref[0, 0, cols, :]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if diagonal:
+        s = _apply_causal_mask(s, 0, 0)
+    p = jnp.exp(s - lse_ref[0, 0, rows, :])
+    if mask_ref is not None:
+        p = jnp.where(mask_ref[0, :, cols] > 0.0, p, 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - delta_ref[0, 0, rows, :]) * scale
+    dq = dk = dv = None
+    if "v" in want:
+        dv = jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    if "k" in want:
+        dk = jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    if "q" in want:
+        dq = jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -155,32 +244,76 @@ def _fwd_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(needed)
-    def _compute():
+    def fold(tiles):
+        """Online-softmax update of m / l / acc with ``tiles`` of (row
+        slice, f32 logits of those rows, their keys' values), which
+        together cover the block's rows. Every tile's statistics come
+        before any tile's value product and the three carries are written
+        last, together: a store of l between the two phases cost a block on
+        the diagonal 0.5 us of 4.2 (PERF.md, PR 34)."""
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]  # (block_q, 1)
+        stats = []
+        for rows, s, _ in tiles:
+            m_new = jnp.maximum(
+                m_prev[rows], jnp.max(s, axis=-1, keepdims=True)
+            )
+            alpha = jnp.exp(m_prev[rows] - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l_prev[rows] + jnp.sum(p, axis=-1, keepdims=True)
+            stats.append((m_new, alpha, p, l))
+        acc = acc_ref[:]
+        acc = _stack_rows([
+            acc[rows] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for (rows, _, v), (_, alpha, p, _) in zip(tiles, stats)
+        ])
+        l_ref[:] = jnp.broadcast_to(
+            _stack_rows([l for _, _, _, l in stats]), l_ref.shape
+        )
+        acc_ref[:] = acc
+        m_ref[:] = jnp.broadcast_to(
+            _stack_rows([m_new for m_new, _, _, _ in stats]), m_ref.shape
+        )
+
+    def block(masked):
         q = q_ref[0, 0]  # (block_q, head_dim)
         k = k_ref[0, 0]  # (block_k, head_dim)
         v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (block_q, block_k)
-        if causal:
-            s = _apply_causal_mask(s, i, j, block_q, block_k)
+        if masked:
+            s = _apply_causal_mask(s, i * block_q, j * block_k)
         if mask_ref is not None:
             valid = mask_ref[0, 0] > 0.0  # (block_k,) key-padding validity
             s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[:, :1]  # (block_q, 1)
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # (block_q, block_k)
-        l_ref[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
-        )
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        fold([(slice(None), s, v)])
+
+    def diagonal(sub):
+        # a row's LAST block, not its only one: each query sub-tile meets
+        # its key prefix in ONE product and folds into the running m / l /
+        # acc the blocks under the diagonal left. All the logits first, then
+        # fold's phases: so written, the sub-tiles' products and softmax
+        # passes overlap; sub-tile by sub-tile they read up to 1.3x slower
+        # than the whole masked block (PERF.md, PR 34)
+        tiles = []
+        for row, prefix in _causal_prefixes(block_q, sub):
+            s = jax.lax.dot_general(
+                q_ref[0, 0, row:prefix, :], k_ref[0, 0, :prefix, :],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * scale
+            s = _apply_causal_mask(s, row, 0)
+            if mask_ref is not None:
+                s = jnp.where(mask_ref[0, :, :prefix] > 0.0, s, NEG_INF)
+            tiles.append((slice(row, prefix), s, v_ref[0, 0, :prefix, :]))
+        fold(tiles)
+
+    _when_by_block_kind(
+        causal, i, j, block_q, block_k, needed, block, diagonal,
+        sub_rows=CAUSAL_SUB_FWD_BLOCK,
+    )
 
     @pl.when(fin_cond)
     def _finalize():
@@ -205,15 +338,21 @@ def _fwd(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
     # float 0/1 or None. v (and the output) may be narrower or wider than
     # q and k: ``head_dim`` is the width the scores contract over,
     # ``v_dim`` the width of the values (latent attention: 192 and 128)
-    batch, heads, seq_q, head_dim = q.shape
-    v_dim = v.shape[-1]
-    seq_k = k.shape[2]
-    group = heads // k.shape[1]
-    if seq_k == block_k:  # whole key sequence in one block: plain softmax
+    if k.shape[2] == block_k:  # whole key sequence in one block: plain softmax
         single = _fwd_single if interpret else _fwd_single_shared
         return single(
             q, k, v, kv_mask, causal, scale, block_q, block_k, interpret
         )
+    multi = _fwd_multi if interpret else _fwd_multi_shared
+    return multi(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret)
+
+
+def _fwd_multi(q, k, v, kv_mask, causal, scale, block_q, block_k, interpret):
+    """The online-softmax forward over a grid of key blocks (see _fwd)."""
+    batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]
+    seq_k = k.shape[2]
+    group = heads // k.shape[1]
     ni = seq_q // block_q
     folded = (
         causal and seq_q == seq_k and block_q == block_k and ni % 2 == 0
@@ -378,7 +517,7 @@ def _fwd_single_kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     if causal:
-        s = _apply_causal_mask(s, i, 0, block_q, block_k)
+        s = _apply_causal_mask(s, i * block_q, 0)
     if mask_ref is not None:
         valid = mask_ref[0, 0] > 0.0
         s = jnp.where(valid[None, :], s, NEG_INF)
@@ -425,7 +564,7 @@ def _fwd_single_causal_kernel(*refs, scale: float, sub: int, has_mask: bool):
                 preferred_element_type=jnp.float32,
             ) * scale
             if cols is diag:
-                s = _apply_causal_mask(s, 0, 0, sub, sub)
+                s = _apply_causal_mask(s, 0, 0)
             if mask_ref is not None:
                 s = jnp.where(mask_ref[0, :, cols] > 0.0, s, NEG_INF)
             logits.append(s)
@@ -473,8 +612,7 @@ def _dq_kernel(
 
     needed = (j * block_k <= (i + 1) * block_q - 1) if causal else True
 
-    @pl.when(needed)
-    def _compute():
+    def block(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
@@ -484,8 +622,8 @@ def _dq_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
-            s = _apply_causal_mask(s, i, j, block_q, block_k)
+        if masked:
+            s = _apply_causal_mask(s, i * block_q, j * block_k)
         p = jnp.exp(s - lse)  # (block_q, block_k)
         if mask_ref is not None:
             # re-mask: for fully-padded rows lse is NEG_INF, making
@@ -499,6 +637,24 @@ def _dq_kernel(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    def diagonal(sub):
+        # query-major: a row sub-tile's dq is complete after its key prefix
+        piece = functools.partial(
+            _subtile_grads,
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref),
+            scale=scale, want="q",
+        )
+        for row, prefix in _causal_prefixes(block_q, sub):
+            rows = slice(row, prefix)
+            dq = piece(rows, rows, diagonal=True)[0]
+            if row:
+                dq += piece(rows, slice(0, row), diagonal=False)[0]
+            dq_acc[rows, :] += dq
+
+    _when_by_block_kind(
+        causal, i, j, block_q, block_k, needed, block, diagonal
+    )
 
     @pl.when(j == nj - 1)
     def _finalize():
@@ -526,8 +682,7 @@ def _dkv_kernel(
 
     needed = ((i + 1) * block_q - 1 >= j * block_k) if causal else True
 
-    @pl.when(needed)
-    def _compute():
+    def block(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
@@ -537,8 +692,8 @@ def _dkv_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
-            s = _apply_causal_mask(s, i, j, block_q, block_k)
+        if masked:
+            s = _apply_causal_mask(s, i * block_q, j * block_k)
         p = jnp.exp(s - lse)  # (block_q, block_k)
         if mask_ref is not None:
             p = jnp.where((mask_ref[0, 0] > 0.0)[None, :], p, 0.0)
@@ -556,6 +711,29 @@ def _dkv_kernel(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    def diagonal(sub):
+        # key-major: a key sub-tile meets only the rows that can see it
+        piece = functools.partial(
+            _subtile_grads,
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref),
+            scale=scale, want="kv",
+        )
+        for col, prefix in _causal_prefixes(block_k, sub):
+            cols = slice(col, prefix)
+            _, dk, dv = piece(cols, cols, diagonal=True)
+            if prefix < block_q:
+                _, dk_below, dv_below = piece(
+                    slice(prefix, block_q), cols, diagonal=False
+                )
+                dk += dk_below
+                dv += dv_below
+            dk_acc[cols, :] += dk
+            dv_acc[cols, :] += dv
+
+    _when_by_block_kind(
+        causal, i, j, block_q, block_k, needed, block, diagonal
+    )
 
     @pl.when(i == ni - 1)
     def _finalize():
@@ -594,6 +772,14 @@ def _bwd_fused_kernel(
     rest). The scratch costs seq_q*head_dim*4 bytes of VMEM (4 MB at 16k,
     head_dim 64); _bwd falls back to the two-kernel path beyond
     _FUSED_DQ_VMEM_LIMIT.
+
+    Causal, by block pair (``_when_by_block_kind``): under the diagonal the
+    body holds no mask; on it (square blocks that ``_causal_sub`` cuts) the
+    walk is KEY-major, as :func:`_bwd_single_causal_kernel` walks one tile:
+    key sub-tile ``c`` meets its diagonal ``sub x sub`` tile (the only one
+    masked) and the rows of the block below it, ``dk_acc`` / ``dv_acc`` take
+    the two pieces' sum and ``dq_acc``'s stripe each piece's rows. Init,
+    emit and finalize conditions are those of the whole-block body.
     """
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
@@ -636,8 +822,7 @@ def _bwd_fused_kernel(
     def _init_dq():
         dq_acc[row, :] = jnp.zeros((block_q, dq_acc.shape[-1]), jnp.float32)
 
-    @pl.when(needed)
-    def _compute():
+    def block(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
@@ -647,8 +832,8 @@ def _bwd_fused_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
-            s = _apply_causal_mask(s, i, j, block_q, block_k)
+        if masked:
+            s = _apply_causal_mask(s, i * block_q, j * block_k)
         p = jnp.exp(s - lse)  # (block_q, block_k)
         if mask_ref is not None:
             p = jnp.where((mask_ref[0, 0] > 0.0)[None, :], p, 0.0)
@@ -671,6 +856,36 @@ def _bwd_fused_kernel(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    def diagonal(sub):
+        # key-major, as the single tile's backward walks its sub-tiles:
+        # key sub-tile ``cols`` meets its diagonal tile and the rows below
+        piece = functools.partial(
+            _subtile_grads,
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref),
+            scale=scale,
+        )
+
+        def stripe(start, stop):  # rows [start, stop) of this q-block
+            return pl.ds(i * block_q + start, stop - start)
+
+        for col, prefix in _causal_prefixes(block_k, sub):
+            cols = slice(col, prefix)
+            dq, dk, dv = piece(cols, cols, diagonal=True)
+            dq_acc[stripe(col, prefix), :] += dq
+            if prefix < block_q:
+                dq_below, dk_below, dv_below = piece(
+                    slice(prefix, block_q), cols, diagonal=False
+                )
+                dk += dk_below
+                dv += dv_below
+                dq_acc[stripe(prefix, block_q), :] += dq_below
+            dk_acc[cols, :] += dk
+            dv_acc[cols, :] += dv
+
+    _when_by_block_kind(
+        causal, i, j, block_q, block_k, needed, block, diagonal
+    )
 
     @pl.when(emit_dq)
     def _emit_dq():
@@ -709,7 +924,7 @@ def _bwd_single_kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     if causal:
-        s = _apply_causal_mask(s, 0, 0, block_q, block_k)
+        s = _apply_causal_mask(s, 0, 0)
     p = jnp.exp(s - lse)  # (block_q, block_k)
     if mask_ref is not None:
         p = jnp.where((mask_ref[0, 0] > 0.0)[None, :], p, 0.0)
@@ -752,43 +967,18 @@ def _bwd_single_causal_kernel(*refs, scale: float, sub: int, has_mask: bool):
          dq_ref, dk_ref, dv_ref, dq_acc) = refs
         mask_ref = None
     seq = q_ref.shape[2]
-
-    def piece(rows, cols, diagonal):
-        """(dq, dk, dv) contributions of the logits of ``rows`` x ``cols``."""
-        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
-        k, v = k_ref[0, 0, cols, :], v_ref[0, 0, cols, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if diagonal:
-            s = _apply_causal_mask(s, 0, 0, sub, sub)
-        p = jnp.exp(s - lse_ref[0, 0, rows, :])
-        if mask_ref is not None:
-            p = jnp.where(mask_ref[0, :, cols] > 0.0, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0, 0, rows, :]) * scale
-        dv = jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk = jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dq = jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dq, dk, dv
+    piece = functools.partial(
+        _subtile_grads,
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref),
+        scale=scale,
+    )
 
     for col, prefix in _causal_prefixes(seq, sub):
         cols = slice(col, prefix)
-        dq, dk, dv = piece(cols, cols, True)
+        dq, dk, dv = piece(cols, cols, diagonal=True)
         if prefix < seq:
             below = slice(prefix, seq)
-            dq_below, dk_below, dv_below = piece(below, cols, False)
+            dq_below, dk_below, dv_below = piece(below, cols, diagonal=False)
             dk += dk_below
             dv += dv_below
             if col:
@@ -1005,7 +1195,6 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
             dk = dk.reshape(batch, k.shape[1], group, seq_k, head_dim).sum(2)
             dv = dv.reshape(batch, v.shape[1], group, seq_k, v_dim).sum(2)
         return dq, dk, dv
-    has_mask = kv_mask is not None
     if seq_q * head_dim * 4 > _FUSED_DQ_VMEM_LIMIT:
         # the fused kernel's persistent dq scratch would crowd VMEM at
         # this length: fall back to the separate dq and dk/dv kernels
@@ -1013,12 +1202,25 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
             q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
             block_k, interpret,
         )
+    fused = _bwd_fused if interpret else _bwd_fused_shared
+    return fused(
+        q, k, v, lse, do, delta, kv_mask, causal, scale, block_q, block_k,
+        interpret,
+    )
 
-    # ONE fused kernel on the k-block-major grid (q streams innermost):
-    # dk/dv accumulate in VMEM scratch per k-block; dq accumulates in a
-    # persistent VMEM scratch spanning the q sequence, emitted on each
-    # block's last visit. One logits recompute + one exp per block pair,
-    # instead of the two of each the separate kernels paid.
+
+def _bwd_fused(q, k, v, lse, do, delta, kv_mask, causal, scale, block_q,
+               block_k, interpret):
+    """ONE fused kernel on the k-block-major grid (q streams innermost):
+    dk/dv accumulate in VMEM scratch per k-block; dq accumulates in a
+    persistent VMEM scratch spanning the q sequence, emitted on each
+    block's last visit. One logits recompute + one exp per block pair,
+    instead of the two of each the separate kernels pay."""
+    batch, heads, seq_q, head_dim = q.shape
+    v_dim = v.shape[-1]
+    seq_k = k.shape[2]
+    group = heads // k.shape[1]
+    has_mask = kv_mask is not None
     ni = seq_q // block_q
     folded = (
         causal and seq_q == seq_k and block_q == block_k and ni % 2 == 0
@@ -1095,6 +1297,15 @@ def _bwd(q, k, v, o, lse, do, kv_mask, causal, scale, block_q, block_k,
         dk = dk.reshape(batch, k.shape[1], group, seq_k, head_dim).sum(2)
         dv = dv.reshape(batch, v.shape[1], group, seq_k, v_dim).sum(2)
     return dq, dk, dv
+
+
+# The multi-block calls as the compiled path makes them: jitted like the
+# single-tile wrappers above and for their reason. The causal kernels hold
+# two bodies, one of them unrolled over sub-tiles; traced anew by each of
+# six layers' forward, recomputed forward and backward they added 3.6-4.0 s
+# to the joyai step's tracing (PERF.md, PR 34).
+_fwd_multi_shared = jax.jit(_fwd_multi, static_argnums=(4, 5, 6, 7, 8))
+_bwd_fused_shared = jax.jit(_bwd_fused, static_argnums=(7, 8, 9, 10, 11))
 
 
 # ---------------------------------------------------------------------------
@@ -1208,9 +1419,14 @@ def flash_attention(
     1.83, 3.89 / 3.83 ms) — small blocks pay too many grid steps and
     per-step online-softmax bookkeeping; the f32 logits tile (4 MB whole,
     1 MB a causal sub-tile row) still sits comfortably in VMEM. Causal
-    work is skipped at the grain each path has: whole blocks on the
-    multi-block grid, key prefixes of ``CAUSAL_SUB``-row sub-tiles inside
-    a single tile (see the module docstring).
+    work is skipped down to key prefixes of sub-tiles on every path: inside
+    a single tile (``CAUSAL_SUB`` rows), and inside the blocks on the
+    diagonal of the multi-block grid (``CAUSAL_SUB`` rows backward,
+    ``CAUSAL_SUB_FWD_BLOCK`` forward), whose blocks under the diagonal run
+    without a mask and whose blocks above it do not run (see the module
+    docstring; my chip run, PR 34: a call at the joyai cell's 4 x 32 x 4096
+    x 192 / 128 forward / backward 7.02 / 14.91 -> 6.16 / 13.03 ms, at the
+    lfm2 cell's 4 x 32 / 8 x 8192 x 64 18.78 / 34.63 -> 18.51 / 31.99 ms).
     """
     if softmax_scale is None:
         softmax_scale = q.shape[-1] ** -0.5

@@ -105,28 +105,21 @@ def test_folded_causal_grid_forward_and_grads(gqa, masked, ni):
     kv_mask = make_kv_mask(seq=seq, seed=32) if masked else None
     scale = 64 ** -0.5
 
-    expected = _xla_attention(q, k, v, None, kv_mask, True, scale)
-    got = flash_attention(
-        q, k, v, causal=True, kv_mask=kv_mask, interpret=True,
-        block_q=128, block_k=128,
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
-
+    # one trace a side: the output rides out of the loss as its aux
     def loss_ref(q, k, v):
-        return jnp.sum(
-            _xla_attention(q, k, v, None, kv_mask, True, scale) ** 2
-        )
+        out = _xla_attention(q, k, v, None, kv_mask, True, scale)
+        return jnp.sum(out ** 2), out
 
     def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(
-                q, k, v, causal=True, kv_mask=kv_mask, interpret=True,
-                block_q=128, block_k=128,
-            ) ** 2
+        out = flash_attention(
+            q, k, v, causal=True, kv_mask=kv_mask, interpret=True,
+            block_q=128, block_k=128,
         )
+        return jnp.sum(out ** 2), out
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    (_, expected), g_ref = jax.value_and_grad(loss_ref, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, got), g_flash = jax.value_and_grad(loss_flash, (0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
     for gr, gf, name in zip(g_ref, g_flash, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
@@ -170,6 +163,75 @@ def test_multiblock_split_fallback_grads(causal, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the multi-block causal kernels: no mask under the diagonal, prefix-only
+# sub-tiles in the blocks on it
+# ---------------------------------------------------------------------------
+
+# case -> (seq, kv heads of 4, key padding, force the two-kernel backward);
+# blocks of 256 rows, which _causal_sub cuts in two sub-tiles of 128: block
+# counts 2 and 4 run the folded grid, 3 the square one with ``needed``
+# (count 2 with no mask: WIDTH_PATHS' "multi-block-subtiled-diagonal")
+DIAGONAL_CASES = {
+    "folded-4-gqa": (1024, 2, None, False),
+    "folded-2-gqa-padded-row": (512, 2, "padded-row", False),
+    "needed-3-masked": (768, 4, "random", False),
+    "two-kernel-2-gqa-padded-row": (512, 2, "padded-row", True),
+    "two-kernel-3": (768, 4, None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DIAGONAL_CASES))
+def test_multiblock_causal_subtiled_diagonal_matches_xla(case, monkeypatch):
+    """Forward and all three gradients against the XLA reference where the
+    blocks on the diagonal walk static sub-tiles and the blocks under it
+    run without a mask: folded and square grids, grouped keys, key padding
+    (one batch row with no valid key: zero output, zero gradients), the
+    fused backward and the two-kernel fallback."""
+    from distributed_pytorch_example_tpu.ops.pallas import flash_attention as fa
+
+    seq, kvh, masked, split = DIAGONAL_CASES[case]
+    assert fa._causal_sub(256) == 128
+    if split:
+        monkeypatch.setattr(fa, "_FUSED_DQ_VMEM_LIMIT", 0)
+    rng = np.random.default_rng(51 + seq)
+    batch = 2 if masked == "padded-row" else 1
+    q = jnp.asarray(rng.standard_normal((batch, seq, 4, 64)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((batch, seq, kvh, 64)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((batch, seq, kvh, 64)), jnp.float32)
+    kv_mask = None
+    if masked:
+        kv_mask = np.array(make_kv_mask(batch=batch, seq=seq, seed=52))
+        if masked == "padded-row":
+            kv_mask[1, :] = False
+        kv_mask = jnp.asarray(kv_mask)
+    scale = 64 ** -0.5
+
+    def loss_ref(q, k, v):
+        out = _xla_attention(q, k, v, None, kv_mask, True, scale)
+        return jnp.sum(out ** 2), out
+
+    def loss_flash(q, k, v):
+        out = fa.flash_attention(
+            q, k, v, causal=True, kv_mask=kv_mask, interpret=True,
+            block_q=256, block_k=256,
+        )
+        return jnp.sum(out ** 2), out
+
+    (_, want), g_ref = jax.value_and_grad(loss_ref, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, got), g_flash = jax.value_and_grad(loss_flash, (0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    if masked == "padded-row":
+        np.testing.assert_array_equal(np.asarray(got)[1], 0.0)
+    for gr, gf, name in zip(g_ref, g_flash, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-4, err_msg=f"d{name}"
+        )
+        if masked == "padded-row":
+            np.testing.assert_array_equal(np.asarray(gf)[1], 0.0)
+
+
+# ---------------------------------------------------------------------------
 # the single-tile causal kernels: prefix-only sub-tiles inside the tile
 # ---------------------------------------------------------------------------
 
@@ -202,7 +264,10 @@ def _dot_flops(jaxpr):
     return total
 
 
-def _single_tile_kernels(seq, causal):
+def _flash_kernels(seq, causal):
+    """The forward and backward kernels a training call at ``seq`` traces
+    at the default blocks (one tile up to 1024; over it the multi-block
+    grid, folded when causal); nothing runs."""
     from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
         flash_attention_bnsh,
     )
@@ -237,24 +302,22 @@ def test_causal_single_tile_subtiles_match_xla(seq, gqa, masked):
         kv_mask = jnp.asarray(kv_mask)
     scale = 64 ** -0.5
 
-    expected = _xla_attention(q, k, v, None, kv_mask, True, scale)
-    got = flash_attention(q, k, v, causal=True, kv_mask=kv_mask, interpret=True)
+    # one trace a side: the output rides out of the loss as its aux
+    def loss_ref(q, k, v):
+        out = _xla_attention(q, k, v, None, kv_mask, True, scale)
+        return jnp.sum(out ** 2), out
+
+    def loss_flash(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, kv_mask=kv_mask, interpret=True
+        )
+        return jnp.sum(out ** 2), out
+
+    (_, expected), g_ref = jax.value_and_grad(loss_ref, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, got), g_flash = jax.value_and_grad(loss_flash, (0, 1, 2), has_aux=True)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=2e-5)
     if masked == "padded-row":
         np.testing.assert_array_equal(np.asarray(got)[1], 0.0)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(_xla_attention(q, k, v, None, kv_mask, True, scale) ** 2)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(
-                q, k, v, causal=True, kv_mask=kv_mask, interpret=True
-            ) ** 2
-        )
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for gr, gf, name in zip(g_ref, g_flash, "qkv"):
         assert gf.shape == gr.shape
         np.testing.assert_allclose(
@@ -275,7 +338,7 @@ def test_causal_single_tile_subtiles_match_xla(seq, gqa, masked):
 def test_single_tile_kernel_names_say_which_body_ran(seq, causal, suffix):
     """The kernel's name is the engagement counter a device trace shows:
     the sub-tiled causal bodies have names of their own."""
-    assert set(_single_tile_kernels(seq, causal)) == {
+    assert set(_flash_kernels(seq, causal)) == {
         "flash_fwd_single" + suffix, "flash_bwd_single" + suffix,
     }
 
@@ -289,6 +352,29 @@ def test_causal_visited_pairs(sub, visited, total):
     )
 
     assert causal_visited_pairs(1024, sub) == (visited, total)
+
+
+@pytest.mark.parametrize(
+    "seq,sub,visited,total",
+    [
+        # the backward's sub-tiles of 256 rows, the forward's of 512
+        (4096, 256, 136, 256), (8192, 256, 528, 1024),
+        (4096, 512, 36, 64), (8192, 512, 136, 256),
+        # whole blocks, as before the diagonal was told apart: 10 and 36
+        # blocks of 16 sub-tile pairs each
+        (4096, 1024, 10, 16), (8192, 1024, 36, 64),
+    ],
+)
+def test_causal_visited_pairs_in_blocks_of_1024(seq, sub, visited, total):
+    """The multi-block case: blocks under the diagonal whole, blocks on it
+    by key prefixes of their sub-tiles (the joyai cell's S 4096 and the
+    lfm2 cell's S 8192 at the kernels' block and sub-tile sizes)."""
+    from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+        DEFAULT_BLOCK,
+        causal_visited_pairs,
+    )
+
+    assert causal_visited_pairs(seq, sub, DEFAULT_BLOCK) == (visited, total)
 
 
 @pytest.mark.parametrize(
@@ -320,12 +406,64 @@ def test_causal_single_tile_computes_under_two_thirds(kernel, equations, flops):
         causal_visited_pairs,
     )
 
-    full = _single_tile_kernels(1024, False)[kernel]
+    full = _flash_kernels(1024, False)[kernel]
     assert (len(full.eqns), _dot_flops(full)) == (equations, flops)
-    tiled = _dot_flops(_single_tile_kernels(1024, True)[kernel + "_causal"])
+    tiled = _dot_flops(_flash_kernels(1024, True)[kernel + "_causal"])
     visited, total = causal_visited_pairs(1024, _causal_sub(1024))
     assert tiled * total == flops * visited
     assert tiled <= 0.65 * flops
+
+
+def _primitives(jaxpr):
+    """Names of every primitive under ``jaxpr`` (``jnp.where`` traces as a
+    ``jit`` around its ``select_n``)."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize(
+    "kernel,equations,flops,sub,share",
+    [
+        ("flash_fwd", 36, 268435456, 512, 0.75),
+        ("flash_bwd_fused", 44, 671088640, 256, 0.65),
+    ],
+)
+def test_causal_multiblock_tells_the_diagonal_apart(
+    kernel, equations, flops, sub, share
+):
+    """The counter that says the mechanism engaged (the kernels' names
+    cannot: the benchmark's readers match them), at the joyai cell's S 4096
+    in blocks of 1024. Of the causal kernel's two
+    ``pl.when`` bodies that hold products, the one for blocks under the
+    diagonal computes a whole block pair with no iota and no select, and
+    the one for blocks on it only its sub-tiles' key prefixes (backward: 10
+    of 16 sub-tile pairs at sub 256; forward: 3 of 4 at sub 512) with the
+    mask in it; the non-causal kernel is the one it was (equations and
+    operations counted on the parent commit)."""
+    from distributed_pytorch_example_tpu.ops.pallas import flash_attention as fa
+
+    full = _flash_kernels(4096, False)[kernel]
+    assert (len(full.eqns), _dot_flops(full)) == (equations, flops)
+    bodies = [
+        max(eqn.params["branches"], key=lambda b: len(b.jaxpr.eqns)).jaxpr
+        for eqn in _flash_kernels(4096, True)[kernel].eqns
+        if eqn.primitive.name == "cond"
+    ]
+    on, under = sorted(
+        (body for body in bodies if _dot_flops(body)), key=_dot_flops
+    )
+    assert _dot_flops(under) == flops
+    assert not _primitives(under) & {"iota", "select_n"}
+    assert {"iota", "select_n"} <= _primitives(on)
+    rows = fa.CAUSAL_SUB_FWD_BLOCK if kernel == "flash_fwd" else fa.CAUSAL_SUB
+    assert fa._causal_sub(fa.DEFAULT_BLOCK, rows) == sub
+    visited, total = fa.causal_visited_pairs(fa.DEFAULT_BLOCK, sub)
+    assert _dot_flops(on) * total == flops * visited
+    assert _dot_flops(on) <= share * flops
 
 
 def test_uneven_blocks_rejected():
@@ -715,6 +853,7 @@ WIDTH_PATHS = {
     "single-tile-causal-subtiles": (256, True, None, 4, False),
     "multi-block-folded-causal": (256, True, (128, 128), 4, False),
     "multi-block-square-gqa": (256, False, (128, 64), 2, False),
+    "multi-block-subtiled-diagonal": (512, True, (256, 256), 4, False),
     "two-kernel-backward": (256, True, (128, 64), 4, True),
 }
 
@@ -725,8 +864,9 @@ def test_value_width_apart_from_query_key_width(dims, path, monkeypatch):
     """Forward and all three gradients against the XLA attention where the
     values are narrower than queries and keys, on every kernel path: the
     single tile (whole and causal sub-tiles), the fused multi-block backward
-    (folded and square grids, grouped keys) and the two-kernel fallback; and
-    at equal widths, where nothing may change."""
+    (folded and square grids, grouped keys, 256-blocks whose diagonal walks
+    sub-tiles) and the two-kernel fallback; and at equal widths, where
+    nothing may change."""
     from distributed_pytorch_example_tpu.ops.pallas import (
         flash_attention as fa,
     )
