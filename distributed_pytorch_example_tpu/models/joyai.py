@@ -41,7 +41,11 @@ import jax.numpy as jnp
 
 from distributed_pytorch_example_tpu.models.llama import RMSNorm, SwiGluMlp
 from distributed_pytorch_example_tpu.models.moe import DroplessMoE
-from distributed_pytorch_example_tpu.ops.attention import dot_product_attention
+from distributed_pytorch_example_tpu.models.transformer import _DenseParams
+from distributed_pytorch_example_tpu.ops.attention import (
+    dot_product_attention,
+    fused_layout_eligible,
+)
 from distributed_pytorch_example_tpu.ops.rope import rope
 
 
@@ -67,49 +71,104 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self, x, *, train: bool = False):
         batch, seq, _ = x.shape
-        heads, nope, rot = (
-            self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim
+        heads, nope, rot, v_dim = (
+            self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim,
+            self.v_head_dim,
         )
+        dt = self.dtype
+        # where the flash kernel serves the call, q, k and v are built in its
+        # head-major (B, N, S, H) layout and W_o reads its output as it
+        # comes, so no transpose runs on either side of the kernel
+        # (MultiHeadAttention's fused layout); elsewhere (B, S, N, H) through
+        # dot_product_attention. Static, and the tree is the same either way:
+        # the head-major products read nn.Dense's kernels through reshapes
+        head_major = fused_layout_eligible(
+            batch, seq, heads, heads, nope + rot, jnp.dtype(dt), causal=True,
+            use_flash=self.use_flash, v_head_dim=v_dim,
+        )
+        seq_axis = 2 if head_major else 1
 
         def dense(features, name):
-            return nn.Dense(
-                features, use_bias=False, dtype=self.dtype, name=name
+            return nn.Dense(features, use_bias=False, dtype=dt, name=name)
+
+        def kernel(features, in_features, name):
+            return _DenseParams(features, use_bias=False, name=name)(
+                in_features
+            )[0].astype(dt)
+
+        def per_head(h, w, width):  # (B, S, r) -> (B, N, S, width)
+            return jnp.einsum(
+                "bsr,rnh->bnsh", h.astype(dt), w.reshape(-1, heads, width)
             )
 
         with jax.named_scope("mla_proj"):
-            c_q = RMSNorm(self.norm_eps, self.dtype, name="q_a_norm")(
+            c_q = RMSNorm(self.norm_eps, dt, name="q_a_norm")(
                 dense(self.q_lora_rank, "q_a")(x)
             )
-            q = dense(heads * (nope + rot), "q_b")(c_q).reshape(
-                batch, seq, heads, nope + rot
-            )
+            if head_major:
+                q = per_head(
+                    c_q, kernel(heads * (nope + rot), self.q_lora_rank, "q_b"),
+                    nope + rot,
+                )
+            else:
+                q = dense(heads * (nope + rot), "q_b")(c_q).reshape(
+                    batch, seq, heads, nope + rot
+                )
             c_kv, k_rot = jnp.split(
                 dense(self.kv_lora_rank + rot, "kv_a")(x),
                 [self.kv_lora_rank], axis=-1,
             )
-            c_kv = RMSNorm(self.norm_eps, self.dtype, name="kv_a_norm")(c_kv)
-            k_nope, v = jnp.split(
-                dense(heads * (nope + self.v_head_dim), "kv_b")(c_kv).reshape(
-                    batch, seq, heads, nope + self.v_head_dim
-                ),
-                [nope], axis=-1,
+            c_kv = RMSNorm(self.norm_eps, dt, name="kv_a_norm")(c_kv)
+            if head_major:
+                w_kv = kernel(
+                    heads * (nope + v_dim), self.kv_lora_rank, "kv_b"
+                ).reshape(self.kv_lora_rank, heads, nope + v_dim)
+                k_nope = per_head(c_kv, w_kv[..., :nope], nope)
+                v = per_head(c_kv, w_kv[..., nope:], v_dim)
+                k_rot = k_rot[:, None, :, :]
+            else:
+                k_nope, v = jnp.split(
+                    dense(heads * (nope + v_dim), "kv_b")(c_kv).reshape(
+                        batch, seq, heads, nope + v_dim
+                    ),
+                    [nope], axis=-1,
+                )
+                k_rot = k_rot[:, :, None, :]
+            q_rot = rope(
+                q[..., nope:], theta=self.rope_theta, interleaved=True,
+                seq_axis=seq_axis,
             )
-            q_rot = rope(q[..., nope:], theta=self.rope_theta, interleaved=True)
             k_rot = rope(
-                k_rot[:, :, None, :], theta=self.rope_theta, interleaved=True
+                k_rot, theta=self.rope_theta, interleaved=True,
+                seq_axis=seq_axis,
             )
             q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
             k = jnp.concatenate(
-                [k_nope, jnp.broadcast_to(k_rot, (batch, seq, heads, rot))],
+                [k_nope, jnp.broadcast_to(k_rot, k_nope.shape[:-1] + (rot,))],
                 axis=-1,
             )
+        if head_major:
+            from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
+                flash_attention_bnsh,
+            )
+
+            out = flash_attention_bnsh(
+                q, k, v, causal=True, softmax_scale=(nope + rot) ** -0.5
+            )
+            with jax.named_scope("mla_proj"):
+                return jnp.einsum(
+                    "bnsh,nhd->bsd", out,
+                    kernel(self.model_dim, heads * v_dim, "o").reshape(
+                        heads, v_dim, self.model_dim
+                    ),
+                )
         out = dot_product_attention(
             q, k, v, causal=True, softmax_scale=(nope + rot) ** -0.5,
             use_flash=self.use_flash,
         )
         with jax.named_scope("mla_proj"):
             return dense(self.model_dim, "o")(
-                out.reshape(batch, seq, heads * self.v_head_dim)
+                out.reshape(batch, seq, heads * v_dim)
             )
 
 

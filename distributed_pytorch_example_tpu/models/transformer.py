@@ -40,10 +40,12 @@ class _DenseParams(nn.Module):
     The fused projection layout needs the raw arrays (it contracts them in
     a reshaped einsum); names/init mirror nn.Dense exactly so the param
     tree — and therefore checkpoints — stay identical whichever attention
-    path a platform takes.
+    path a platform takes. ``use_bias`` False: no bias, and None in its
+    place (``nn.Dense(use_bias=False)``'s tree).
     """
 
     features: int
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, in_features: int):
@@ -51,6 +53,8 @@ class _DenseParams(nn.Module):
             "kernel", nn.initializers.lecun_normal(),
             (in_features, self.features),
         )
+        if not self.use_bias:
+            return kernel, None
         bias = self.param("bias", nn.initializers.zeros, (self.features,))
         return kernel, bias
 

@@ -183,6 +183,7 @@ def _flash_lane_padded(q, k, v, kv_mask, causal, softmax_scale,
 def fused_layout_eligible(
     batch: int, seq: int, heads: int, kv_heads: int, head_dim: int, dtype,
     *, causal: bool, use_flash: Optional[bool],
+    v_head_dim: Optional[int] = None,
 ) -> bool:
     """True when the flash kernel would serve this self-attention AND the
     caller can use the head-major fused projection layout — project
@@ -191,13 +192,18 @@ def fused_layout_eligible(
     results/lm_mfu_analysis/bsnh_ab.json). The decision must be taken
     BEFORE the projections run, hence this static probe; masks, decode,
     RoPE, and sequence parallelism all disqualify (their paths are
-    (B, S, N, H)-shaped).
+    (B, S, N, H)-shaped in ``MultiHeadAttention``; latent attention turns
+    its rotary parts head-major). ``v_head_dim``: the values' width where
+    it is not the queries' (latent attention: 192 / 128).
     """
     if use_flash is False:
         return False
     q = jax.ShapeDtypeStruct((batch, seq, heads, head_dim), dtype)
     kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, head_dim), dtype)
-    return _flash_unsupported_reason(q, kv, kv, None, causal) is None
+    v = kv if v_head_dim is None else jax.ShapeDtypeStruct(
+        (batch, seq, kv_heads, v_head_dim), dtype
+    )
+    return _flash_unsupported_reason(q, kv, v, None, causal) is None
 
 
 @functools.lru_cache(maxsize=1)

@@ -27,8 +27,11 @@ def rope(
     positions: Optional[jax.Array] = None,
     theta: float = 10000.0,
     interleaved: bool = False,
+    seq_axis: int = 1,
 ) -> jax.Array:
-    """Rotate (B, S, N, H) queries or keys by their positions.
+    """Rotate (B, S, N, H) queries or keys by their positions; with
+    ``seq_axis=2`` the head-major (B, N, S, H) layout the flash kernel
+    takes.
 
     ``positions``: (S,) int32 global positions shared across the batch, or
     (B, S) per-row positions (paged decode: each slot sits at its own
@@ -38,16 +41,28 @@ def rope(
     head_dim = x.shape[-1]
     if head_dim % 2:
         raise ValueError(f"RoPE needs an even head_dim, got {head_dim}")
+    if seq_axis not in (1, 2):
+        raise ValueError(
+            f"seq_axis is 1 (B, S, N, H) or 2 (B, N, S, H), got {seq_axis}"
+        )
     half = head_dim // 2
     if positions is None:
-        positions = jnp.arange(x.shape[1])
+        positions = jnp.arange(x.shape[seq_axis])
     freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+    def along_seq(a):
+        """(S, half) or per-row (B, S, half) angles laid along x's
+        sequence axis."""
+        if a.ndim == 3:
+            return a[:, :, None, :] if seq_axis == 1 else a[:, None, :, :]
+        return a[None, :, None, :] if seq_axis == 1 else a[None, None, :, :]
+
     if interleaved:
         if positions.ndim != 1:
             raise ValueError("interleaved RoPE takes (S,) positions")
         angles = positions.astype(jnp.float32)[:, None] * freqs  # (S, half)
-        cos = jnp.cos(angles)[None, :, None, :]
-        sin = jnp.sin(angles)[None, :, None, :]
+        cos = along_seq(jnp.cos(angles))
+        sin = along_seq(jnp.sin(angles))
         pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
         x1, x2 = pairs[..., 0], pairs[..., 1]
         rotated = jnp.stack(
@@ -56,8 +71,8 @@ def rope(
         return rotated.reshape(x.shape).astype(x.dtype)
     if positions.ndim == 2:  # (B, S): per-row offsets
         angles = positions.astype(jnp.float32)[..., None] * freqs
-        cos = jnp.cos(angles)[:, :, None, :]
-        sin = jnp.sin(angles)[:, :, None, :]
+        cos = along_seq(jnp.cos(angles))
+        sin = along_seq(jnp.sin(angles))
         x1 = x[..., :half].astype(jnp.float32)
         x2 = x[..., half:].astype(jnp.float32)
         rotated = jnp.concatenate(
@@ -65,8 +80,8 @@ def rope(
         )
         return rotated.astype(x.dtype)
     angles = positions.astype(jnp.float32)[:, None] * freqs  # (S, half)
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
+    cos = along_seq(jnp.cos(angles))
+    sin = along_seq(jnp.sin(angles))
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

@@ -399,6 +399,62 @@ def test_flash_mla_192_128_compiles(one_chip, no_persistent_cache, direction):
         assert compiled.out_info.shape == (4, 4096, 32, 128)
 
 
+def _layout_copies(compiled, batch, seq, heads, widths):
+    """The ``copy`` and ``transpose`` instructions of a compiled program whose
+    result has the (B, S, N, H) or (B, N, S, H) shape of a tensor ``widths``
+    wide: each a layout change of a whole kernel operand, its output or
+    their gradients (a copy keeps the logical shape and moves the bytes)."""
+    shapes = {
+        f"[{batch},{a},{b},{w}]"
+        for w in widths for a, b in ((seq, heads), (heads, seq))
+    }
+    found = [
+        re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+(\[[\d,]*\])\S* (\w+)\(", line)
+        for line in compiled.as_text().splitlines()
+    ]
+    return sum(
+        1 for m in found
+        if m and m.group(2) in ("copy", "transpose") and m.group(1) in shapes
+    )
+
+
+@pytest.mark.parametrize("layout", ["head-major", "sequence-major"])
+def test_latent_attention_calls_the_kernel_without_transposes(
+    one_chip, no_persistent_cache, monkeypatch, layout
+):
+    """``LatentAttention`` at the ``joyai-llm-flash`` cell's widths (32 heads,
+    q/k 192, v 128, ranks 1536 / 512), forward and gradient, 2 rows of 2048
+    tokens (the multi-block kernels, as the cell's 4096): where the flash
+    kernel serves the call, q, k and v are projected head-major and no
+    instruction transposes them, the output or their gradients between
+    (B, S, N, H) and (B, N, S, H). The (B, S, N, H) path it replaces, forced
+    here, compiles six such copies: four 128 wide (v, the output and their
+    gradients), the query gradient (192) and the concatenated key/value
+    gradient (256). Nothing runs: a count of the compiler's."""
+    from distributed_pytorch_example_tpu.models import joyai
+    from distributed_pytorch_example_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    if layout == "sequence-major":
+        monkeypatch.setattr(joyai, "fused_layout_eligible", lambda *a, **k: False)
+    batch, seq, dim = 2, 2048, 2048
+    module = joyai.LatentAttention(
+        model_dim=dim, num_heads=32, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_theta=32e6, norm_eps=1e-6, dtype=jnp.bfloat16,
+    )
+    x = jax.ShapeDtypeStruct((batch, seq, dim), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(module.init, jax.random.key(0), x)["params"],
+    )
+    loss = lambda p, x: module.apply({"params": p}, x).astype(jnp.float32).sum()
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    assert _program_names(compiled) == MULTIBLOCK_NAMES["bwd"]
+    copies = _layout_copies(compiled, batch, seq, 32, (128, 192, 256))
+    assert copies == (0 if layout == "head-major" else 6), copies
+
+
 WINDOW_NAMES = {
     "fwd": {"flash_fwd_window"},
     "bwd": {"flash_fwd_window", "flash_bwd_window_fused"},

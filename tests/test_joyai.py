@@ -221,6 +221,91 @@ def test_latent_attention_against_head_by_head_attention():
     )
 
 
+def test_head_major_latent_attention_matches_sequence_major(monkeypatch):
+    """Where the flash kernel serves the call, ``LatentAttention`` builds q,
+    k and v head-major and calls the transpose-free kernel entry: the same
+    parameter tree and values as the (B, S, N, H) path, the same output and
+    the same gradient of every leaf. At the cell's widths (32 heads, q/k
+    192 = 128 + 64 rotary, v 128) and 128 tokens; CPU drive: eligibility
+    forced, the kernel interpreted, the (B, S, N, H) path on XLA
+    attention."""
+    import functools
+
+    from distributed_pytorch_example_tpu.models import joyai as joyai_mod
+    from distributed_pytorch_example_tpu.ops.pallas import (
+        flash_attention as fa_mod,
+    )
+
+    attention = LatentAttention(
+        model_dim=256, num_heads=32, q_lora_rank=96, kv_lora_rank=64,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_theta=32e6, norm_eps=1e-6,
+    )
+    x = jax.random.normal(jax.random.key(3), (2, 128, 256))
+    params = attention.init(jax.random.key(0), x)["params"]
+    cotangent = jax.random.normal(jax.random.key(4), (2, 128, 256))
+
+    def run(p):
+        out, pull = jax.vjp(lambda p: attention.apply({"params": p}, x), p)
+        return out, pull(cotangent)[0]
+
+    sequence_major = run(params)
+    monkeypatch.setattr(joyai_mod, "fused_layout_eligible", lambda *a, **k: True)
+    monkeypatch.setattr(
+        fa_mod, "flash_attention_bnsh",
+        functools.partial(fa_mod.flash_attention_bnsh, interpret=True),
+    )
+    calls = []
+    monkeypatch.setattr(
+        joyai_mod, "dot_product_attention", lambda *a, **k: calls.append(a)
+    )
+    head_major_params = attention.init(jax.random.key(0), x)["params"]
+    assert jax.tree_util.tree_structure(head_major_params) == (
+        jax.tree_util.tree_structure(params)
+    )
+    jax.tree_util.tree_map(
+        np.testing.assert_array_equal, head_major_params, params
+    )
+    head_major = run(params)
+    assert not calls  # the (B, S, N, H) entry was not taken
+    np.testing.assert_allclose(head_major[0], sequence_major[0], atol=2e-5)
+    # a gradient to 2e-5 of its leaf's largest entry (4.5 to 49 here)
+    for (path, got), want in zip(
+        jax.tree_util.tree_leaves_with_path(head_major[1]),
+        jax.tree_util.tree_leaves(sequence_major[1]),
+    ):
+        np.testing.assert_allclose(
+            got, want, atol=2e-5 * float(jnp.max(jnp.abs(want))), rtol=0,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+@pytest.mark.parametrize("interleaved", [False, True], ids=["halves", "pairs"])
+def test_head_major_rope_is_the_transposed_sequence_major_one(interleaved):
+    """``rope(..., seq_axis=2)`` on (B, N, S, H) is ``rope`` on (B, S, N, H)
+    transposed, and the default call traces what it traced: the sequence
+    axis named or not, the same jaxpr."""
+    x = jax.random.normal(jax.random.key(6), (2, 12, 3, 8))
+    want = rope(x, theta=32e6, interleaved=interleaved)
+    got = rope(
+        x.transpose(0, 2, 1, 3), theta=32e6, interleaved=interleaved,
+        seq_axis=2,
+    )
+    np.testing.assert_array_equal(got.transpose(0, 2, 1, 3), want)
+    assert str(jax.make_jaxpr(
+        lambda x: rope(x, theta=32e6, interleaved=interleaved)
+    )(x)) == str(jax.make_jaxpr(
+        lambda x: rope(x, theta=32e6, interleaved=interleaved, seq_axis=1)
+    )(x))
+    if not interleaved:  # per-row positions, as paged decode gives them
+        rows = jnp.asarray([[5] * 12, list(range(12))], jnp.int32)
+        np.testing.assert_array_equal(
+            rope(x.transpose(0, 2, 1, 3), positions=rows, seq_axis=2)
+            .transpose(0, 2, 1, 3),
+            rope(x, positions=rows),
+        )
+
+
 def test_interleaved_rope_turns_adjacent_pairs_and_leaves_them_in_place():
     x = jax.random.normal(jax.random.key(1), (2, 12, 3, 8))
     got = rope(x, theta=32e6, interleaved=True)
