@@ -37,13 +37,24 @@ Typical use::
     ...
 """
 
+import time as _time
+
+# the package's import (JAX's among it) is the first span of the record
+_IMPORT_NS, _IMPORT_CPU_S = _time.perf_counter_ns(), _time.process_time()
+
 __version__ = "0.1.0"
 
-from distributed_pytorch_example_tpu import runtime  # noqa: F401
-from distributed_pytorch_example_tpu import data  # noqa: F401
-from distributed_pytorch_example_tpu import models  # noqa: F401
-from distributed_pytorch_example_tpu import ops  # noqa: F401
-from distributed_pytorch_example_tpu import parallel  # noqa: F401
-from distributed_pytorch_example_tpu import robustness  # noqa: F401
-from distributed_pytorch_example_tpu import train  # noqa: F401
-from distributed_pytorch_example_tpu import utils  # noqa: F401
+from distributed_pytorch_example_tpu import runtime  # noqa: F401,E402
+from distributed_pytorch_example_tpu import data  # noqa: F401,E402
+from distributed_pytorch_example_tpu import models  # noqa: F401,E402
+from distributed_pytorch_example_tpu import ops  # noqa: F401,E402
+from distributed_pytorch_example_tpu import parallel  # noqa: F401,E402
+from distributed_pytorch_example_tpu import robustness  # noqa: F401,E402
+from distributed_pytorch_example_tpu import train  # noqa: F401,E402
+from distributed_pytorch_example_tpu import utils  # noqa: F401,E402
+from distributed_pytorch_example_tpu.telemetry import trace as _trace  # noqa: E402
+
+_trace.add(
+    "import", _IMPORT_NS, _time.perf_counter_ns(),
+    _trace.cpu_args(_IMPORT_CPU_S),
+)

@@ -33,6 +33,10 @@ graft-scope rebuilds that surface TPU-first around four pillars:
   ``--no-telemetry`` all three are off. The span names:
 
   ====================================  =====================================
+  ``import`` (``trace.add``)            the package's import, JAX's among it
+                                        (``__init__.py``), and ``train.py``'s
+                                        module body: one row an import,
+                                        recorded whatever the flags say
   ``main_args`` ``main_runtime``        ``train.py::main`` (distributed init,
   ``main_data`` ``main_model``          mesh and compile cache; datasets;
   ``main_trainer``                      model + partitioner; loaders,
@@ -61,6 +65,13 @@ graft-scope rebuilds that surface TPU-first around four pillars:
   ``eval`` ``checkpoint``               validation dispatch, saves
   ``compile:<fun_name>``                the compile log, below
   ====================================  =====================================
+
+  The ``import`` rows, the five ``main_*`` spans and every ``fit`` carry
+  ``args`` ``{"cpu_open_s", "cpu_close_s"}``: the process's CPU seconds
+  (``time.process_time()``, XLA's compile threads among them) at open and
+  at close (``trace.cpu_args``), so that a reader can tell seconds the host
+  computed from seconds it waited. No span inside ``train_epoch`` takes
+  them.
 
 - **compile log** (:mod:`~.compilelog`): one ``jax.monitoring`` listener,
   installed when this package is imported, records a span

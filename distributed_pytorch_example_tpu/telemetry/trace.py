@@ -1,11 +1,12 @@
 """Chrome trace-event span writer (Perfetto / chrome://tracing loadable).
 
 Streams complete ("ph": "X") events as a JSON array next to
-``metrics.jsonl``: one event per ``span(...)`` context, timestamped in
-microseconds off the monotonic clock, ``pid`` = JAX process index, ``tid`` =
-a small stable id per host thread (the loader's prefetch thread shows up as
-its own track). Buffered writes, thread-safe, and drop-on-closed so late
-spans from a background producer thread never crash teardown.
+``metrics.jsonl``: one event per :func:`span` handed the writer (or per
+``add_complete`` call), timestamped in microseconds off the monotonic
+clock, ``pid`` = JAX process index, ``tid`` = a small stable id per host
+thread (the loader's prefetch thread shows up as its own track). Buffered
+writes, thread-safe, and drop-on-closed so late spans from a background
+producer thread never crash teardown.
 
 graft-lens additions:
 
@@ -36,6 +37,12 @@ three ways at once:
   open on the same thread when this one opened), root (the outermost such
   span: one ``fit`` call's spans share the ``fit`` span's id) and step;
 - the Chrome event, when a :class:`TraceWriter` is handed in.
+
+The set-up's root spans (the ``import`` rows, ``train.py::main``'s five
+``main_*`` spans, every ``fit``) are opened with ``cpu=True``: their row's
+``args`` hold the process's CPU seconds (``time.process_time()``, every
+thread's) at open and at close, :func:`cpu_args`. Nothing inside
+``train_epoch`` takes them.
 """
 
 from __future__ import annotations
@@ -66,7 +73,8 @@ RECORD_LIMIT = 1 << 16
 class Span(NamedTuple):
     """One closed span of the in-memory record. Times are
     ``time.perf_counter_ns``; ``parent`` is 0 for a span opened on an empty
-    stack; ``args`` carries a compile-log span's seconds, else None."""
+    stack; ``args`` carries a compile-log span's seconds, a set-up root
+    span's :func:`cpu_args`, a ``moe_counters`` row's counters, else None."""
 
     name: str
     start_ns: int
@@ -108,10 +116,16 @@ def _stack() -> list:
     return stack
 
 
+def cpu_args(cpu_open_s: float) -> dict:
+    """A set-up root span's ``args``: the process's CPU seconds at its open
+    (read there with ``time.process_time()``) and now, at its close."""
+    return {"cpu_open_s": cpu_open_s, "cpu_close_s": time.process_time()}
+
+
 def add(name: str, start_ns: int, end_ns: int,
         args: Optional[dict] = None) -> None:
-    """Record a span that was timed elsewhere (the compile log's), as a
-    child of whatever span is open on this thread."""
+    """Record a span that was timed elsewhere (the compile log's, an
+    ``import`` row), as a child of whatever span is open on this thread."""
     stack = _stack()
     ident = next(_ids)
     parent, root = stack[-1] if stack else (0, ident)
@@ -126,15 +140,17 @@ class span:
     (with ``writer``) Chrome event, as the module docstring says.
 
     ``root`` adopts a span opened on an empty stack into another thread's
-    tree (the loader's prefetch thread under its ``fit``)."""
+    tree (the loader's prefetch thread under its ``fit``). ``cpu`` gives
+    the row :func:`cpu_args` (the set-up's root spans only)."""
 
     __slots__ = ("name", "step", "writer", "id", "parent", "root", "t0",
-                 "_annotation")
+                 "cpu", "cpu0", "_annotation")
 
     def __init__(self, name: str, step: Optional[int] = None,
                  writer: Optional["TraceWriter"] = None,
-                 root: Optional[int] = None):
+                 root: Optional[int] = None, cpu: bool = False):
         self.name, self.step, self.writer, self.root = name, step, writer, root
+        self.cpu = cpu
         self.id = next(_ids)
 
     def __enter__(self):
@@ -151,20 +167,22 @@ class span:
             else StepTraceAnnotation(self.name, step_num=self.step)
         )
         self._annotation.__enter__()
+        self.cpu0 = time.process_time() if self.cpu else None
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        args = cpu_args(self.cpu0) if self.cpu else None
         self._annotation.__exit__(*exc)
         _stack().pop()
         _append(Span(
             self.name, self.t0, t1, threading.get_ident(), self.id,
-            self.parent, self.root, self.step, None,
+            self.parent, self.root, self.step, args,
         ))
         if self.writer is not None:
             self.writer.add_complete(
-                self.name, self.t0 // 1000, (t1 - self.t0) // 1000
+                self.name, self.t0 // 1000, (t1 - self.t0) // 1000, args=args
             )
         return False
 
@@ -172,7 +190,7 @@ class span:
 _NULL_CTX = contextlib.nullcontext()
 
 
-def no_span(name: str, step: Optional[int] = None):
+def no_span(name: str, step: Optional[int] = None, cpu: bool = False):
     """What stands in for :func:`span` where telemetry is off."""
     return _NULL_CTX
 
@@ -292,14 +310,6 @@ class TraceWriter:
                 event["args"] = args
             self._append_locked(event)
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        t0 = _now_us()
-        try:
-            yield
-        finally:
-            self.add_complete(name, t0, _now_us() - t0)
-
     def _flush_locked(self) -> None:
         if self._fh is None or not self._events:
             return
@@ -372,11 +382,3 @@ class PrefixedTrace:
         self._base.instant(
             f"{self._prefix}/{name}", ts_us=ts_us, pid=self._pid, **args
         )
-
-    @contextlib.contextmanager
-    def span(self, name: str):
-        t0 = _now_us()
-        try:
-            yield
-        finally:
-            self.add_complete(name, t0, _now_us() - t0)

@@ -262,12 +262,12 @@ class Trainer:
 
     # -- state ------------------------------------------------------------
 
-    def _bare_span(self, name: str):
+    def _bare_span(self, name: str, cpu: bool = False):
         """A span outside ``fit``'s scope (``init``, the head and tail of
         ``fit``): the module-level form of ``Telemetry.span``."""
         if self._telemetry_cfg is None:
             return span_lib.no_span(name)
-        return span_lib.span(name)
+        return span_lib.span(name, cpu=cpu)
 
     def init(self, sample_inputs: Any) -> TrainState:
         with self._bare_span("init_state"):
@@ -671,7 +671,7 @@ class Trainer:
         # `fit` is the root of every span this call opens; `fit_open` is
         # everything before the epoch loop (_fit closes it there, or the
         # stack does when an exception unwinds first)
-        with self._bare_span("fit") as fit_span, \
+        with self._bare_span("fit", cpu=True) as fit_span, \
                 contextlib.ExitStack() as opening:
             opening.enter_context(self._bare_span("fit_open"))
             return self._fit(

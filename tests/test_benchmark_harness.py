@@ -12,7 +12,8 @@ lfm2 cell brings (``test_layer_metrics_lfm2.py``), those the joyai cell
 brings (``test_layer_metrics_joyai.py``) and that cell's ``correct`` at a
 tiny size, sound and with its own two faults (``test_correct_joyai.py``);
 the same two for the trinity cell (``test_layer_metrics_trinity.py``,
-``test_correct_trinity.py``: sound, the window left out, bf16 for f32).
+``test_correct_trinity.py``: sound, the window left out, bf16 for f32);
+and the six parts of ``setup_s`` (``test_setup_parts.py``).
 The cells' files are read here, in this process, by the harness' own
 ``load_cell``.
 """
@@ -68,11 +69,12 @@ def bench():
         ["tests/test_correct_joyai.py", "-k", "sound or rotary or bfloat16"],
         ["tests/test_layer_metrics_trinity.py"],
         ["tests/test_correct_trinity.py", "-k", "sound or window or bfloat16"],
+        ["tests/test_setup_parts.py"],
     ],
     ids=[
         "flops", "reference_steps", "run", "layer_metrics_lfm2", "reduce",
         "program_spans", "layer_metrics_joyai", "correct_joyai",
-        "layer_metrics_trinity", "correct_trinity",
+        "layer_metrics_trinity", "correct_trinity", "setup_parts",
     ],
 )
 def test_the_benchmarks_own_tests_pass(selection):
@@ -319,9 +321,9 @@ def test_the_joyai_cells_metrics_have_their_readers(bench):
         assert os.path.exists(
             os.path.join(BENCH_DIR, "layer_metrics", metric["name"] + ".py")
         )
-    assert bench["per_layer"][-10:-4] == named  # appended, nothing moved
+    assert bench["per_layer"][-16:-10] == named  # appended, nothing moved
     without_a_list = [m["name"] for m in bench["per_layer"] if "workloads" not in m]
-    assert len(without_a_list) == 10
+    assert len(without_a_list) == 16
 
 
 def test_the_trinity_cell_counts_what_the_issue_counted(harness):
@@ -444,7 +446,7 @@ def test_the_trinity_cells_metrics_have_their_readers(bench):
         "window_flash_fwd_roofline", "window_flash_bwd_roofline",
         "attn_window_device_share", "attn_full_device_share",
     ]
-    assert bench["per_layer"][-4:] == named
+    assert bench["per_layer"][-10:-6] == named
     for metric in named:
         assert metric["moves"] == "tokens_per_s_per_chip"
         assert metric["workloads"] == [TRINITY_CELL] and metric["unit"] == "%"
@@ -454,3 +456,22 @@ def test_the_trinity_cells_metrics_have_their_readers(bench):
         )
     assert bench["workloads"][-1]["name"] == TRINITY_CELL
     assert bench["configs"][-1]["name"] == "trinity-mini"
+
+
+def test_the_setup_parts_have_their_readers_in_every_cell(bench):
+    """The six parts of ``setup_s``, appended last: seconds from the
+    program's spans, read in every cell (no list of cells), each with its
+    reader's file."""
+    parts = bench["per_layer"][-6:]
+    assert [m["name"] for m in parts] == [
+        "setup_import_s", "setup_before_main_s", "setup_main_s",
+        "setup_steps_s", "setup_outside_s", "setup_cpu_s",
+    ]
+    assert len({m["layer"] for m in parts}) == 1
+    for metric in parts:
+        assert "workloads" not in metric and metric["moves"] == "setup_s"
+        assert (metric["unit"], metric["better"], metric["source"]) == (
+            "s", "lower", "program_span")
+        assert os.path.exists(
+            os.path.join(BENCH_DIR, "layer_metrics", metric["name"] + ".py")
+        )

@@ -179,8 +179,7 @@ def test_prefixed_trace_pid_lanes(tmp_path):
     r0 = PrefixedTrace(base, "r0", pid=1)
     r1 = PrefixedTrace(base, "r1", pid=2, process_name="replica-one")
     r0.add_complete("decode_step", 0, 10)
-    with r1.span("prefill:q"):
-        pass
+    r1.add_complete("prefill:q", 0, 10)
     r1.counter("kv", {"free_blocks": 5})
     base.close()
     events = json.loads(path.read_text())

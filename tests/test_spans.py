@@ -31,6 +31,7 @@ import optax
 import pytest
 
 import distributed_pytorch_example_tpu as dpx
+import train  # its import row lands here, before any test clears the record
 from distributed_pytorch_example_tpu.telemetry import (
     Telemetry,
     TelemetryConfig,
@@ -742,8 +743,6 @@ MAIN_ARGV = [
 
 @pytest.mark.parametrize("telemetry_on", [True, False], ids=["on", "off"])
 def test_train_main_opens_its_setup_spans(devices, record, telemetry_on):
-    import train
-
     train.main(MAIN_ARGV + ([] if telemetry_on else ["--no-telemetry"]))
     rows = [
         s for s in record.recorded()
@@ -761,3 +760,49 @@ def test_train_main_opens_its_setup_spans(devices, record, telemetry_on):
     (init,) = [s for s in rows if s.name == "init_state"]
     by_id = {s.id: s for s in rows}
     assert by_id[init.parent].name == "fit_open"
+    # the set-up's root spans carry the process's CPU seconds, and no span
+    # of the epoch loop does
+    for s in rows:
+        if s.root == s.id:  # (the prefetch thread's are adopted by `fit`)
+            assert set(s.args) == {"cpu_open_s", "cpu_close_s"}, s.name
+            assert 0 < s.args["cpu_open_s"] <= s.args["cpu_close_s"]
+        elif s.name in ("train_step", "data_load", "epoch_drain") + WAITS:
+            assert s.args is None, s.name
+    assert {"train_step", "data_load", "clock_fence"} <= {s.name for s in rows}
+
+
+def test_cpu_args_only_where_asked(record):
+    with trace.span("root_like", cpu=True):
+        with trace.span("inner"):
+            sum(range(100_000))
+    inner, root = record.recorded()
+    assert inner.args is None
+    assert root.args["cpu_open_s"] < root.args["cpu_close_s"]
+    assert trace.no_span("x", cpu=True) is trace.no_span("y")
+
+
+def test_a_fresh_import_leaves_one_import_row():
+    """The package's import (JAX's among it) is a root row of its own,
+    with the process's CPU seconds, in a process that did nothing else."""
+    import subprocess
+    import sys
+
+    code = (
+        "import json, distributed_pytorch_example_tpu\n"
+        "from distributed_pytorch_example_tpu.telemetry import trace\n"
+        "print(json.dumps([[r.name, r.parent, r.start_ns, r.end_ns, r.args]"
+        " for r in trace.recorded()]))\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    rows = json.loads(done.stdout.strip().splitlines()[-1])
+    imports = [r for r in rows if r[0] == "import"]
+    assert len(imports) == 1
+    ((_, parent, start, end, args),) = imports
+    assert parent == 0 and start < end
+    assert args["cpu_open_s"] <= args["cpu_close_s"]

@@ -481,8 +481,7 @@ def test_step_profiler_arm_refusal_matrix(tmp_path):
 def test_trace_writer_valid_json_threads_and_close(tmp_path):
     path = tmp_path / "trace.json"
     tw = TraceWriter(str(path), process_index=0)
-    with tw.span("step"):
-        pass
+    tw.add_complete("step", 0, 5)
     t = threading.Thread(target=lambda: tw.add_complete("h2d", 10, 5))
     t.start()
     t.join()
@@ -499,6 +498,5 @@ def test_trace_writer_valid_json_threads_and_close(tmp_path):
 
 def test_trace_writer_disabled_is_noop():
     tw = TraceWriter(None)
-    with tw.span("x"):
-        pass
+    tw.add_complete("x", 0, 1)
     tw.close()

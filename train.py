@@ -15,17 +15,27 @@ Model/dataset/mesh selection beyond the reference is via the framework flags
 
 from __future__ import annotations
 
-import argparse
-import os
-import sys
+import time
 
-import jax.numpy as jnp
-import optax
+# this module's import is a span of the record of its own (the package's,
+# where this module imports it first, nested inside it)
+_IMPORT_NS, _IMPORT_CPU_S = time.perf_counter_ns(), time.process_time()
 
-import distributed_pytorch_example_tpu as dpx
-from distributed_pytorch_example_tpu.runtime.logging import get_logger
-from distributed_pytorch_example_tpu.telemetry import trace as span_lib
+import argparse  # noqa: E402
+import os  # noqa: E402
+import sys  # noqa: E402
 
+import jax.numpy as jnp  # noqa: E402
+import optax  # noqa: E402
+
+import distributed_pytorch_example_tpu as dpx  # noqa: E402
+from distributed_pytorch_example_tpu.runtime.logging import get_logger  # noqa: E402
+from distributed_pytorch_example_tpu.telemetry import trace as span_lib  # noqa: E402
+
+span_lib.add(
+    "import", _IMPORT_NS, time.perf_counter_ns(),
+    span_lib.cpu_args(_IMPORT_CPU_S),
+)
 logger = get_logger(__name__)
 
 
@@ -175,7 +185,7 @@ def main(argv=None, devices=None):
     # the module-level form of Telemetry.span, for what runs before fit
     # builds its scope (telemetry/trace.py: profiler annotation + record)
     span = span_lib.no_span if args.no_telemetry else span_lib.span
-    with span("main_args"):
+    with span("main_args", cpu=True):
         dpx.runtime.setup_logging()
         if args.chaos:
             # install BEFORE initialize(): rendezvous-flake faults must see the
@@ -187,7 +197,7 @@ def main(argv=None, devices=None):
                 if args.chaos.lstrip().startswith("{")
                 else chaos.preset(args.chaos)
             )
-    with span("main_runtime"):
+    with span("main_runtime", cpu=True):
         dpx.runtime.enable_compile_cache()
         config = dpx.runtime.initialize()
 
@@ -219,7 +229,7 @@ def main(argv=None, devices=None):
             args.lr,
         )
 
-    with span("main_data"):
+    with span("main_data", cpu=True):
         # Reference semantics: --batch-size is per data-parallel replica
         # (train.py:215 with one process per device); global batch scales with
         # the data-parallel size.
@@ -269,7 +279,7 @@ def main(argv=None, devices=None):
                     f"{ds_classes}"
                 )
 
-    with span("main_model"):
+    with span("main_model", cpu=True):
         dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
         overrides = {"dtype": dtype}
         if args.model in ("mlp",) or args.model.startswith("resnet") or args.model.startswith("vit"):
@@ -440,7 +450,7 @@ def main(argv=None, devices=None):
                 bucket_bytes=bucket_bytes,
             )
 
-    with span("main_trainer"):
+    with span("main_trainer", cpu=True):
         train_loader = dpx.data.DeviceLoader(
             train_ds, global_batch, mesh=mesh, shuffle=True, seed=args.seed
         )
