@@ -75,8 +75,11 @@ def tied_head_logits(x, embedding, dtype) -> jax.Array:
 class MultiHeadAttention(nn.Module):
     """Self-attention with centralized kernel dispatch.
 
-    Layout is (batch, seq, heads, head_dim) end to end — the MXU/sequence-
-    sharding friendly layout (see ops/attention.py).
+    Layout is (batch, seq, heads, head_dim) — the MXU/sequence-sharding
+    friendly layout (see ops/attention.py) — except where the flash kernel
+    serves a training self-attention call: there the projections emit the
+    kernel's (batch, heads, seq, head_dim) directly
+    (``_fused_layout_attention``).
 
     ``seq_axis``: name of a mesh axis to run ring attention over (sequence/
     context parallelism). The active mesh comes from the enclosing
@@ -148,21 +151,27 @@ class MultiHeadAttention(nn.Module):
         # decision (shapes/dtype/platform), so a given model instance
         # always creates the same param tree; the `_DenseParams` modules
         # mirror nn.Dense's names/init exactly, keeping checkpoints
-        # interchangeable between the paths.
+        # interchangeable between the paths. q/k norms, RoPE, an output
+        # gate, a window or projections without biases are served
+        # head-major only where a head fills whole 128-lane tiles: at 64
+        # wide the v5e ran LFM2's normed rotary attention 1.9% slower per
+        # step head-major (its input norm's fusion +8.4 ms) where Trinity's
+        # at 128 ran 8.6% faster (PERF.md section 6), while the plain biased
+        # projections keep the layout at any width (GPT-2, BERT at 64).
+        plain = self.use_bias and not (
+            self.rope or self.qk_norm or self.output_gate
+            or self.window is not None
+        )
         fused = (
             not self.decode
-            and not self.rope
-            and not self.qk_norm
-            and not self.output_gate
-            and self.window is None
-            and self.use_bias
+            and (plain or self.head_dim % 128 == 0)
             and mask is None
             and kv_mask is None
             and self.seq_axis is None
             and fused_layout_eligible(
                 batch, seq, self.num_heads, kv_heads, self.head_dim,
                 jnp.dtype(self.dtype), causal=self.causal,
-                use_flash=self.use_flash,
+                use_flash=self.use_flash, window=self.window,
             )
         )
         if fused:
@@ -261,7 +270,10 @@ class MultiHeadAttention(nn.Module):
         epilogue around the transpose-free flash entry
         (ops/pallas/flash_attention.flash_attention_bnsh) — no standalone
         transpose op for XLA to schedule. A/B-measured worth ~2% of the
-        GPT-2 bench step (results/lm_mfu_analysis/bsnh_ab.json).
+        GPT-2 bench step (results/lm_mfu_analysis/bsnh_ab.json). The q/k
+        norms run over the last axis and RoPE along axis 2, the output gate
+        multiplies head by head: the same operations in the same precision
+        as the (B, S, N, H) path, under the same parameter names.
         """
         from distributed_pytorch_example_tpu.ops.pallas.flash_attention import (
             flash_attention_bnsh,
@@ -270,24 +282,51 @@ class MultiHeadAttention(nn.Module):
         n, kv_n, h = self.num_heads, kv_heads, self.head_dim
         in_dim = x.shape[-1]
         dt = self.dtype
-        kq, bq = _DenseParams(features, name="q")(in_dim)
-        kk, bk = _DenseParams(kv_features, name="k")(in_dim)
-        kv_w, bv = _DenseParams(kv_features, name="v")(in_dim)
-        ko, bo = _DenseParams(self.model_dim, name="o")(features)
+
+        def params(width, name, in_features=in_dim):
+            return _DenseParams(width, self.use_bias, name=name)(in_features)
+
+        kq, bq = params(features, "q")
+        kk, bk = params(kv_features, "k")
+        kv_w, bv = params(kv_features, "v")
+        ko, bo = params(self.model_dim, "o", features)
         xd = x.astype(dt)
 
         def project(w, b, heads):
-            return jnp.einsum(
+            out = jnp.einsum(
                 "bsd,dnh->bnsh", xd, w.reshape(in_dim, heads, h).astype(dt)
-            ) + b.reshape(heads, h).astype(dt)[None, :, None, :]
+            )
+            if b is None:
+                return out
+            return out + b.reshape(heads, h).astype(dt)[None, :, None, :]
 
         q = project(kq, bq, n)
         k = project(kk, bk, kv_n)
         v = project(kv_w, bv, kv_n)
-        out = flash_attention_bnsh(q, k, v, causal=self.causal)
+        if self.qk_norm:
+            from distributed_pytorch_example_tpu.models.llama import RMSNorm
+
+            q = RMSNorm(self.qk_norm_epsilon, dt, name="q_norm")(q)
+            k = RMSNorm(self.qk_norm_epsilon, dt, name="k_norm")(k)
+        if self.rope:
+            from distributed_pytorch_example_tpu.ops.rope import rope
+
+            q = rope(q, theta=self.rope_theta, seq_axis=2)
+            k = rope(k, theta=self.rope_theta, seq_axis=2)
+        out = flash_attention_bnsh(
+            q, k, v, causal=self.causal, window=self.window
+        )
+        if self.output_gate:
+            gate = project(*params(features, "gate"), n)
+            out = (
+                out.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
+            ).astype(dt)
         out = jnp.einsum(
             "bnsh,nhd->bsd", out, ko.reshape(n, h, self.model_dim).astype(dt)
-        ) + bo.astype(dt)
+        )
+        if bo is not None:
+            out = out + bo.astype(dt)
         if self.dropout_rate:
             out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
         return out

@@ -183,20 +183,26 @@ def _flash_lane_padded(q, k, v, kv_mask, causal, softmax_scale,
 def fused_layout_eligible(
     batch: int, seq: int, heads: int, kv_heads: int, head_dim: int, dtype,
     *, causal: bool, use_flash: Optional[bool],
-    v_head_dim: Optional[int] = None,
+    v_head_dim: Optional[int] = None, window: Optional[int] = None,
 ) -> bool:
     """True when the flash kernel would serve this self-attention AND the
     caller can use the head-major fused projection layout — project
     straight to (B, N, S, H) with einsum('bsd,dnh->bnsh') and skip the
     transpose sandwich (measured ~0.22 ms/layer at GPT-2 bench shapes,
     results/lm_mfu_analysis/bsnh_ab.json). The decision must be taken
-    BEFORE the projections run, hence this static probe; masks, decode,
-    RoPE, and sequence parallelism all disqualify (their paths are
-    (B, S, N, H)-shaped in ``MultiHeadAttention``; latent attention turns
-    its rotary parts head-major). ``v_head_dim``: the values' width where
-    it is not the queries' (latent attention: 192 / 128).
+    BEFORE the projections run, hence this static probe. What runs on the
+    last axis or along the sequence serves either layout (q/k norms, RoPE
+    with ``seq_axis=2``, an output gate, grouped kv heads); what is left to
+    the caller to refuse is what the (B, S, N, H) paths alone serve: masks,
+    decode, sequence parallelism (``MultiHeadAttention`` also keeps its
+    narrow-head forms sequence-major, where the chip ran them slower
+    head-major). ``v_head_dim``: the values' width where it
+    is not the queries' (latent attention: 192 / 128). ``window``: a sliding
+    window, which the band grid serves in causal self-attention only (square
+    blocks, which a self-attention call's default blocks are); without
+    ``causal`` the call is left to ``dot_product_attention`` to refuse.
     """
-    if use_flash is False:
+    if use_flash is False or (window is not None and not causal):
         return False
     q = jax.ShapeDtypeStruct((batch, seq, heads, head_dim), dtype)
     kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, head_dim), dtype)

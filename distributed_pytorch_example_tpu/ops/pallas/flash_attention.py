@@ -1671,6 +1671,7 @@ def flash_attention_bnsh(
     block_q: int = DEFAULT_BLOCK,
     block_k: int = DEFAULT_BLOCK,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention consuming/producing the kernel layout (B, N, S, H).
 
@@ -1680,16 +1681,19 @@ def flash_attention_bnsh(
     prologue + einsum('bnsh,nhd->bsd') epilogue). Measured A/B at GPT-2
     bench shapes: the transpose sandwich costs ~0.22 ms per layer fwd+bwd
     (results/lm_mfu_analysis/bsnh_ab.json) — ~2% of the whole step at 12
-    layers; a wash at BERT@512.
+    layers; a wash at BERT@512. ``window`` as :func:`flash_attention`
+    takes it.
     """
     if softmax_scale is None:
         softmax_scale = q.shape[-1] ** -0.5
+    seq_q, seq_k = q.shape[2], k.shape[2]
     block_q, block_k = _validate_flash_shapes(
-        q.shape[1], k.shape[1], q.shape[2], k.shape[2], block_q, block_k
+        q.shape[1], k.shape[1], seq_q, seq_k, block_q, block_k
     )
+    window = _band_window(window, causal, seq_q, seq_k, block_q, block_k)
     return _flash_over_mesh(
         q, k, v, None, causal, float(softmax_scale), block_q, block_k,
-        interpret,
+        interpret, window,
     )
 
 

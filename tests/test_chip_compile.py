@@ -462,6 +462,56 @@ WINDOW_NAMES = {
 }
 
 
+@pytest.mark.parametrize("layer", ["window", "full"])
+@pytest.mark.parametrize("layout", ["head-major", "sequence-major"])
+def test_gated_window_attention_calls_the_kernel_without_transposes(
+    one_chip, no_persistent_cache, monkeypatch, layout, layer
+):
+    """Trinity's attention at the ``trinity-mini`` cell's call (4 rows of 8192
+    tokens, 32 query / 4 key heads of 128, RMSNorm on q and k, the output
+    gate, no biases; the window layer with RoPE and a 2048-key band, the full
+    layer without positions), forward and gradient under ``jax.checkpoint`` as
+    the cell's remat runs it: where the flash kernel serves the call, q, k, v
+    and the gate are projected head-major and no instruction copies or
+    transposes them, the output or their gradients between (B, S, N, H) and
+    (B, N, S, H). The (B, S, N, H) path it replaces, forced here, compiles
+    eight such copies: q and the kernel's output in float32 (for ``q_norm``
+    and the gate's product), k in float32 (``k_norm``), the output's and
+    ``q_norm``'s gradients, k, v and their gradients. The band and causal
+    kernels keep the names their readers match. Nothing runs: a count of the
+    compiler's."""
+    from distributed_pytorch_example_tpu.models import transformer
+    from distributed_pytorch_example_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    if layout == "sequence-major":
+        monkeypatch.setattr(
+            transformer, "fused_layout_eligible", lambda *a, **k: False
+        )
+    batch, seq, dim = 4, 8192, 2048
+    window = layer == "window"
+    module = transformer.MultiHeadAttention(
+        num_heads=32, num_kv_heads=4, head_dim=128, model_dim=dim,
+        causal=True, dtype=jnp.bfloat16, rope=window, qk_norm=True,
+        use_bias=False, output_gate=True, window=2048 if window else None,
+    )
+    x = jax.ShapeDtypeStruct((batch, seq, dim), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(module.init, jax.random.key(0), x)["params"],
+    )
+    apply = jax.checkpoint(lambda p, x: module.apply({"params": p}, x))
+    loss = lambda p, x: apply(p, x).astype(jnp.float32).sum()
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    assert _program_names(compiled) == (
+        WINDOW_NAMES["bwd"] if window else MULTIBLOCK_NAMES["bwd"]
+    )
+    copies = sum(
+        _layout_copies(compiled, batch, seq, heads, (128,)) for heads in (32, 4)
+    )
+    assert copies == (0 if layout == "head-major" else 8), copies
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd", "split"])
 def test_flash_window_8k_compiles(
     one_chip, no_persistent_cache, monkeypatch, direction

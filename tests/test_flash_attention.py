@@ -785,6 +785,133 @@ def test_fused_layout_attention_matches_classic(monkeypatch):
     )
 
 
+# the forms the head-major layout serves beyond GPT-2's: (module fields,
+# sequence, kernel blocks). Trinity's window layer runs the band grid
+# (4 blocks of 128, 3 band steps, an edge block cut by the window);
+# its full layer has no positions; lfm2's form (no gate, no window) at the
+# 128 lanes it is served head-major at
+HEAD_MAJOR_FORMS = {
+    "trinity-window": (
+        dict(num_heads=4, num_kv_heads=2, head_dim=128, rope=True,
+             window=200, output_gate=True),
+        512, 128,
+    ),
+    "trinity-full": (
+        dict(num_heads=4, num_kv_heads=2, head_dim=128, output_gate=True),
+        256, 128,
+    ),
+    "lfm2-form": (
+        dict(num_heads=4, num_kv_heads=2, head_dim=128, rope=True,
+             rope_theta=1e6),
+        256, 128,
+    ),
+}
+
+
+@pytest.mark.parametrize("form", list(HEAD_MAJOR_FORMS))
+def test_head_major_attention_matches_sequence_major(form, monkeypatch):
+    """Grouped kv heads, q/k norms, RoPE, an output gate, a sliding window
+    and no biases through the head-major projections (einsum prologue ->
+    BNSH kernel -> einsum epilogue): the same parameter tree and values,
+    outputs and gradients as the (B, S, N, H) path. CPU drive: eligibility
+    forced, kernel in interpret mode, against the XLA attention."""
+    import functools
+
+    from distributed_pytorch_example_tpu.models import transformer as tf_mod
+    from distributed_pytorch_example_tpu.ops.pallas import (
+        flash_attention as fa_mod,
+    )
+
+    fields, seq, block = HEAD_MAJOR_FORMS[form]
+    mha = tf_mod.MultiHeadAttention(
+        model_dim=128, causal=True, qk_norm=True, use_bias=False, **fields
+    )
+    x = jnp.asarray(
+        np.random.default_rng(seq).standard_normal((1, seq, 128)),
+        jnp.float32,
+    )
+
+    def traced():
+        """(parameters, output, gradient of its sum of squares), each path
+        traced anew (one compiled program for the output and gradient)."""
+        params = jax.jit(lambda: mha.init(jax.random.key(3), x)["params"])()
+
+        def out_and_grad(p):
+            out, vjp = jax.vjp(lambda p: mha.apply({"params": p}, x), p)
+            return out, vjp(2 * out)[0]
+
+        return (params, *jax.jit(out_and_grad)(params))
+
+    params, classic, g_classic = traced()
+    assert "bias" not in str(jax.tree_util.tree_structure(params))
+    monkeypatch.setattr(tf_mod, "fused_layout_eligible", lambda *a, **k: True)
+    monkeypatch.setattr(
+        fa_mod, "flash_attention_bnsh",
+        functools.partial(
+            fa_mod.flash_attention_bnsh, interpret=True, block_q=block,
+            block_k=block,
+        ),
+    )
+    fused_params, fused, g_fused = traced()
+    # identical param tree and values between the two paths
+    assert (jax.tree_util.tree_structure(fused_params)
+            == jax.tree_util.tree_structure(params))
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)
+        ),
+        params, fused_params,
+    )
+    np.testing.assert_allclose(
+        np.asarray(fused), np.asarray(classic), atol=2e-5
+    )
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-4
+        ),
+        g_fused, g_classic,
+    )
+
+
+# (module fields, head-major): the plain biased projections at any width,
+# the other forms only where a head fills whole 128-lane tiles
+LAYOUT_BY_WIDTH = {
+    "gpt2-64": (dict(num_heads=4, head_dim=64), True),
+    "lfm2-64": (
+        dict(num_heads=4, num_kv_heads=2, head_dim=64, rope=True,
+             qk_norm=True, use_bias=False),
+        False,
+    ),
+    "trinity-128": (
+        dict(num_heads=4, num_kv_heads=2, head_dim=128, rope=True,
+             qk_norm=True, use_bias=False, output_gate=True, window=64),
+        True,
+    ),
+}
+
+
+@pytest.mark.parametrize("form", list(LAYOUT_BY_WIDTH))
+def test_head_major_layout_by_form_and_head_width(form, monkeypatch):
+    """Where the flash kernel serves the call, which layout a form takes:
+    what the head-major kernel entry is called with, traced for shapes."""
+    from distributed_pytorch_example_tpu.models import transformer as tf_mod
+    from distributed_pytorch_example_tpu.ops.pallas import (
+        flash_attention as fa_mod,
+    )
+
+    fields, head_major = LAYOUT_BY_WIDTH[form]
+    calls = []
+    monkeypatch.setattr(tf_mod, "fused_layout_eligible", lambda *a, **k: True)
+    monkeypatch.setattr(
+        fa_mod, "flash_attention_bnsh",
+        lambda q, k, v, **kw: calls.append(q.shape) or q,
+    )
+    mha = tf_mod.MultiHeadAttention(model_dim=128, causal=True, **fields)
+    jax.eval_shape(mha.init, jax.random.key(0), jnp.zeros((1, 256, 128)))
+    heads, width = fields["num_heads"], fields["head_dim"]
+    assert calls == ([(1, heads, 256, width)] if head_major else [])
+
+
 # ---------------------------------------------------------------------------
 # on a mesh: the kernel runs inside a fully-manual shard_map
 # ---------------------------------------------------------------------------
